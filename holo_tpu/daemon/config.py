@@ -10,13 +10,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from pathlib import Path
 
-try:
-    import tomllib  # Python >= 3.11
-except ImportError:  # pragma: no cover - interpreter-dependent
-    # tomli is the stdlib module's upstream: a drop-in loads() for
-    # pre-3.11 interpreters (a hand-rolled parser silently mis-handles
-    # real TOML — escaped quotes, commas inside array strings).
-    import tomli as tomllib
+import tomllib
 
 
 @dataclass
@@ -92,8 +86,8 @@ class TelemetryConfig:
     fanout_tick: float = 1.0
     # ROADMAP carry-over: when set AND a real TPU is attached, capture
     # one jax.profiler.trace() around a seeded SPF dispatch into this
-    # directory at boot.  Relay-probe-aware: without a TPU the daemon
-    # records an explicit `relay: not-used` row — never a failure.
+    # directory at boot.  Without a TPU the daemon records a
+    # `captured: false` row with the platform — never a failure.
     device_trace_dir: str | None = None
     # Dispatch observatory (ISSUE 12): streaming quantile sketches per
     # (site, stage, engine, shape-bucket, kind) fed from the profiling
@@ -107,17 +101,17 @@ class TelemetryConfig:
     # keeps the ledger in memory only.
     observatory_ledger: str | None = None
     # Roofline peak specs {flops=<per sec>, bytes=<per sec>, name=...};
-    # None = the honest CPU defaults ("relay: not-used") until the TPU
-    # relay returns with real specs.
+    # None = the attached device's published peaks, chosen by its
+    # device_kind (telemetry/observatory.py DEVICE_PEAKS); a device
+    # with no entry reports verdict "unknown".
     roofline_peaks: dict | None = None
     # SLO plane (ISSUE 20): error budgets + multi-window burn-rate
-    # sentinels graded from the convergence end-cut / pipeline shed /
-    # relay watch streams.  Objectives come from
-    # [[telemetry.slo-objectives]] tables (name, kind, source,
-    # quantile, threshold-ms, target); empty = the shipped default set
-    # (trigger-fib latency, canary, relay availability, background
-    # delivery).  Warn-only by contract; gated < 2% by bench.py
-    # slo_overhead.
+    # sentinels graded from the convergence end-cut / pipeline shed
+    # streams.  Objectives come from [[telemetry.slo-objectives]]
+    # tables (name, kind, source, quantile, threshold-ms, target);
+    # empty = the shipped default set (trigger-fib latency, canary,
+    # background delivery).  Warn-only by contract; gated < 2% by
+    # bench.py slo_overhead.
     slo: bool = False
     slo_objectives: tuple = ()
     slo_fast_window: float = 3600.0

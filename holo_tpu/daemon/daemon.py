@@ -192,9 +192,8 @@ class Daemon:
             )
         # Device-trace capture ([telemetry] device-trace-dir, ISSUE 11
         # carry-over): one real jax.profiler.trace() around a seeded
-        # SPF dispatch when a TPU is attached.  Relay-probe-aware — no
-        # TPU yields an explicit `relay: not-used` row and never blocks
-        # the boot.
+        # SPF dispatch when a TPU is attached.  Any other platform
+        # yields a `captured: false` row and never blocks the boot.
         self._device_trace = None
         if tcfg.device_trace_dir:
             from holo_tpu.telemetry import profiling
@@ -206,7 +205,6 @@ class Daemon:
                 log.info("device trace: %s", self._device_trace)
             except Exception as e:  # noqa: BLE001 — never a boot blocker
                 self._device_trace = {
-                    "relay": "not-used",
                     "captured": False,
                     "error": f"{type(e).__name__}: {e}",
                 }
@@ -221,8 +219,8 @@ class Daemon:
                 tcfg.convergence_events, clock=self.loop.clock.now
             )
         # SLO plane ([telemetry] slo, ISSUE 20): error budgets +
-        # burn-rate sentinels graded from the convergence / shed /
-        # relay streams the subsystems above produce.  The engine keeps
+        # burn-rate sentinels graded from the convergence / shed
+        # streams the subsystems above produce.  The engine keeps
         # its default profiling clock (burn windows are REAL-time
         # quantities even when the loop clock is virtual).
         if tcfg.slo:
@@ -664,6 +662,11 @@ def main(argv=None):
     args = ap.parse_args(argv)
     cfg = DaemonConfig.load(args.config)
     setup_logging(cfg)
+    # Persistent XLA compile cache, placed before the first dispatch:
+    # a restarted daemon must not recompile its 10k-vertex programs.
+    from holo_tpu.utils.compile_cache import configure_compile_cache
+
+    log.info("compile cache at %s", configure_compile_cache())
     # Dispatch-breaker knobs apply process-wide (protocol code builds
     # its SPF/FRR engines — and so their breakers — internally).  Set
     # at daemon BOOT only: merely constructing a Daemon object (tests,

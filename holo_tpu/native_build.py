@@ -1,14 +1,19 @@
 """Lazy build + ctypes loader for the C++ native components.
 
-The native pieces (scalar SPF baseline now; runtime core as it lands) are
-compiled on first use into ``native/build/`` with g++ — no pip/cmake
-dependency — and loaded via ctypes.  Rebuilds happen automatically when the
-source is newer than the shared object.
+The native pieces (scalar SPF baseline, runtime core) are compiled on
+first use into ``native/build/`` with g++ — no pip/cmake dependency —
+and loaded via ctypes.  The library's file name carries a hash of the
+source text, the compiler flags and the host CPU, so an edited source
+rebuilds and a ``native/build/`` copied from another machine (the
+objects are ``-march=native``) is never loaded.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import os
+import platform
 import subprocess
 from pathlib import Path
 
@@ -18,30 +23,46 @@ REPO = Path(__file__).resolve().parent.parent
 NATIVE = REPO / "native"
 BUILD = NATIVE / "build"
 
+_FLAGS = ["-O3", "-march=native", "-std=c++17", "-shared", "-fPIC"]
 
-def _ensure(so_name: str, sources: list[str], extra: list[str] | None = None) -> Path:
-    BUILD.mkdir(parents=True, exist_ok=True)
-    so = BUILD / so_name
+
+def _host_id() -> str:
+    """What ``-march=native`` resolves against: the CPU model and its
+    feature flags (first core), plus the machine name."""
+    ident = [platform.node(), platform.machine()]
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("model name", "flags")):
+                    ident.append(line.strip())
+                if line.startswith("flags"):
+                    break
+    except OSError:
+        pass
+    return "\n".join(ident)
+
+
+def _ensure(stem: str, sources: list[str]) -> Path:
     srcs = [NATIVE / s for s in sources]
-    if so.exists() and all(so.stat().st_mtime >= s.stat().st_mtime for s in srcs):
+    h = hashlib.sha256()
+    for s in srcs:
+        h.update(s.read_bytes())
+    h.update(" ".join(_FLAGS).encode())
+    h.update(_host_id().encode())
+    so = BUILD / f"{stem}-{h.hexdigest()[:16]}.so"
+    if so.exists():
         return so
-    cmd = [
-        "g++",
-        "-O3",
-        "-march=native",
-        "-std=c++17",
-        "-shared",
-        "-fPIC",
-        *(extra or []),
-        *[str(s) for s in srcs],
-        "-o",
-        str(so),
-    ]
+    BUILD.mkdir(parents=True, exist_ok=True)
+    # Build beside the target and rename: two processes racing on a
+    # cold tree must never load a half-written object.
+    tmp = so.with_suffix(f".{os.getpid()}.tmp")
+    cmd = ["g++", *_FLAGS, *[str(s) for s in srcs], "-o", str(tmp)]
     proc = subprocess.run(cmd, capture_output=True, text=True)
     if proc.returncode != 0:
         raise RuntimeError(
             f"native build failed ({' '.join(cmd)}):\n{proc.stderr}"
         )
+    os.replace(tmp, so)
     return so
 
 
@@ -51,7 +72,7 @@ _spf_lib = None
 def spf_baseline_lib() -> ctypes.CDLL:
     global _spf_lib
     if _spf_lib is None:
-        lib = ctypes.CDLL(str(_ensure("libspf_baseline.so", ["spf_baseline.cpp"])))
+        lib = ctypes.CDLL(str(_ensure("libspf_baseline", ["spf_baseline.cpp"])))
         i32p = np.ctypeslib.ndpointer(np.int32, flags="C")
         u8p = np.ctypeslib.ndpointer(np.uint8, flags="C")
         u64p = np.ctypeslib.ndpointer(np.uint64, flags="C")
@@ -104,7 +125,7 @@ def runtime_core_lib() -> ctypes.CDLL:
     """C++ runtime core: timer wheel, MPSC rings, epoll poller."""
     global _runtime_lib
     if _runtime_lib is None:
-        lib = ctypes.CDLL(str(_ensure("libruntime_core.so", ["runtime_core.cpp"])))
+        lib = ctypes.CDLL(str(_ensure("libruntime_core", ["runtime_core.cpp"])))
         i64p = np.ctypeslib.ndpointer(np.int64, flags="C")
         i32p = np.ctypeslib.ndpointer(np.int32, flags="C")
         u32p = np.ctypeslib.ndpointer(np.uint32, flags="C")

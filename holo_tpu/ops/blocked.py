@@ -18,7 +18,9 @@ every iteration, keeping sums exact in int32 (real distances must stay
 below 1<<27 — validated at marshal).  Outputs restore the canonical INF.
 
 The kernel compiles on TPU Mosaic (the "row" layout variant — per-u row
-extract + sublane broadcast); on CPU it runs in interpret mode for tests.
+extract + sublane broadcast).  Interpret mode is off unless a caller
+passes ``interpret=True`` by name (the CPU tests do); no code path
+selects it from the backend it finds.
 """
 
 from __future__ import annotations
@@ -184,11 +186,9 @@ def whatif_distances_blocked(
     failed_dst: np.ndarray,  # int32[B, F]
     failed_id: np.ndarray,
     max_iters: int | None = None,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """Batched what-if distances: int32[B, N] with canonical INF."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     npad = g.in_src.shape[0]
     B = failed_dst.shape[0]
     n_pairs = int(g.bsrc.shape[0])

@@ -512,11 +512,9 @@ def whatif_spf_blocked(
     failed_dst: jax.Array,  # int32[B, F] failed edges' dst (PERMUTED space)
     failed_id: jax.Array,  # int32[B, F] original edge ids (-1 pad)
     max_iters: int | None = None,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> BlockedSpfOut:
     """Batched full SPF on the blocked planes.  Root is permuted id 0."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     npad = g.in_src.shape[0]
     n = g.n_real  # may be traced under jit: used only in scalar arithmetic
     B, F = failed_dst.shape

@@ -1079,7 +1079,7 @@ def _guarded_launch(breaker, context: str, launch_fn) -> tuple:
     :func:`_guarded_finish` completes.
 
     Transient-retry taxonomy (ISSUE 19): a transient-classified device
-    error (:func:`overload.is_transient` — a relay blip, UNAVAILABLE, a
+    error (:func:`overload.is_transient` — a runtime blip, UNAVAILABLE, a
     timed-out collective) gets the policy's jittered-backoff retries
     BEFORE the breaker counts a strike; deterministic errors (a shape
     bug reproduces identically — retrying is pure added latency) go

@@ -16,7 +16,7 @@ should not own the admission semantics):
   rank.
 
 - **transient-vs-deterministic failure taxonomy** — the breaker FSM
-  counts every guarded exception as a strike, so a single relay blip
+  counts every guarded exception as a strike, so a single runtime blip
   (connection reset, UNAVAILABLE, a timed-out collective) burns 1/3 of
   the failure budget even though an immediate retry would have
   succeeded.  :func:`is_transient` splits the device-shaped errors the
@@ -54,9 +54,9 @@ _RETRIES = telemetry.counter(
 )
 
 
-#: lowercase substrings of device/relay error text the platform
+#: lowercase substrings of device error text the platform
 #: documents as retryable service conditions (gRPC-style status names
-#: the XLA relay surfaces, plus the socket-layer phrasings).
+#: the XLA runtime surfaces, plus the socket-layer phrasings).
 _TRANSIENT_MARKERS = (
     "unavailable",
     "deadline_exceeded",
@@ -77,7 +77,7 @@ def is_transient(exc: BaseException) -> bool:
     than a deterministic failure.
 
     OS-level transport errors (``ConnectionError``/``TimeoutError``/
-    other ``OSError``) are transient by type: they are how a relay blip
+    other ``OSError``) are transient by type: they are how a runtime blip
     presents at the socket boundary.  Everything else is classified by
     message against :data:`_TRANSIENT_MARKERS` — deliberately
     conservative, because a wrong "transient" verdict costs a wasted

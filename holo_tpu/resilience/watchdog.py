@@ -1,7 +1,7 @@
 """Hung-dispatch watchdog: budgeted walls for in-flight pipeline phases.
 
 The breaker FSM (``resilience/breaker.py``) counts *exceptions* — a
-device call that never returns (XLA compile stall, a wedged relay
+device call that never returns (XLA compile stall, a wedged runtime
 socket) produces no exception, so the single pipeline worker blocks
 forever inside launch/finish while bounded-queue backpressure walls the
 submitting protocol actors behind it.  This sentinel closes that gap:

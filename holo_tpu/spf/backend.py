@@ -321,21 +321,25 @@ class TpuSpfBackend(SpfBackend):
         partition_max_part: int = 4096,
     ):
         """``engine``: 'gather' (ELL gathers; handles any topology) or
-        'blocked' (block-sparse Pallas kernels; fastest on large LSDBs,
-        requires unique (src,dst) pairs and distances < 2**27 — falls back
-        to gather per topology when those preconditions fail).
+        'blocked' (block-sparse Pallas kernels for the single-path
+        planes; multipath_k > 1 always rides the ``mp`` kernels).
+        'blocked' requires unique (src,dst) pairs, distances < 2**27
+        and at most 4 failed edges per scenario: a topology or batch
+        outside that raises ``ValueError`` from the device arm, which
+        the breaker counts as a failed dispatch — it never quietly
+        runs the gather engine under the 'blocked' name.
 
         ``one_engine`` picks the gather-path fixpoint formulation
         ('fused' | 'packed' | 'seq' — see :func:`spf_one_fused`); all are
         bit-identical, differing only in TPU round/gather scheduling.
         'seq' is the default: it is the fastest measured formulation on
-        the only platform benchmarked so far (JAX-CPU; BENCH_r03) — flip
-        per-platform only once a TPU run shows another engine winning.
+        the only platform benchmarked so far (JAX-CPU) — flip only once
+        a run on the chip shows another engine winning.
 
         ``breaker`` guards every device dispatch: XLA exceptions and
         deadline overruns fall back to the scalar oracle (bit-identical
         by the parity contract), and repeated failures open the circuit
-        so a dead relay stops being retried on the SPF hot path.
+        so a dead device stops being retried on the SPF hot path.
 
         ``incremental`` arms the DeltaPath dispatch: topologies carrying
         delta lineage (``Topology.link_delta`` at the LSDB seam) are
@@ -1206,11 +1210,9 @@ class TpuSpfBackend(SpfBackend):
         if self.engine == "blocked" and kp == 1:
             # The blocked-Pallas experiment has no multipath planes;
             # kp > 1 rides the gather-path multipath kernel below.
-            res = self._whatif_blocked(
+            return self._whatif_blocked(
                 topo, self._full_mask(topo, edge_mask)[None, :]
-            )
-            if res is not None:
-                return res[0]
+            )[0]
         if edge_mask is None:
             res = self._try_incremental(topo, kp)
             if res is not None:
@@ -1409,7 +1411,8 @@ class TpuSpfBackend(SpfBackend):
         return res
 
     def prepare_blocked(self, topo: Topology):
-        """Marshal (and cache) the blocked planes; None if unsupported.
+        """Marshal (and cache) the blocked planes; ``ValueError`` when
+        the topology is outside the kernels' preconditions.
 
         The cache key includes the root: unlike the gather planes, the
         blocked planes bake the root in (BFS permutation + rootp).
@@ -1419,10 +1422,7 @@ class TpuSpfBackend(SpfBackend):
             return self._blocked_cache[key]
         from holo_tpu.ops.blocked_spf import marshal_block_spf
 
-        try:
-            g = marshal_block_spf(topo, n_atoms=max(self.n_atoms, topo.n_atoms()))
-        except ValueError:
-            g = None  # preconditions unmet: gather engine handles it
+        g = marshal_block_spf(topo, n_atoms=max(self.n_atoms, topo.n_atoms()))
         self._blocked_cache[key] = g
         while len(self._blocked_cache) > 4:
             self._blocked_cache.pop(next(iter(self._blocked_cache)))
@@ -1433,15 +1433,10 @@ class TpuSpfBackend(SpfBackend):
 
         with sanctioned_transfer("spf.blocked.marshal"):
             g = self.prepare_blocked(topo)
-            if g is None:
-                return None
-            try:
-                fdst, fid = failed_edges_perm(
-                    np.asarray(g.orig2perm), topo,
-                    np.asarray(edge_masks, bool),
-                )
-            except ValueError:
-                return None  # too many failed edges per scenario
+            fdst, fid = failed_edges_perm(
+                np.asarray(g.orig2perm), topo,
+                np.asarray(edge_masks, bool),
+            )
         if self._jit_blocked is None:
             from functools import partial
 
@@ -1500,9 +1495,7 @@ class TpuSpfBackend(SpfBackend):
             # The blocked-Pallas experiment marshals its own planes and
             # stays single-device; the mesh path rides the gather
             # engines (the headline since r02).
-            res = self._whatif_blocked(topo, edge_masks)
-            if res is not None:
-                return res
+            return self._whatif_blocked(topo, edge_masks)
         B = len(edge_masks)
         t0 = profiling.clock()
         engine, bucket = self._pick_engine("whatif", topo, B, kp=kp)

@@ -35,8 +35,6 @@ Tree shape (walks into one gNMI update per leaf under PROTO encoding):
       observatory/               # dispatch observatory (ISSUE 12; while
         sketches, observations,  #   armed): sketch population, sentinel
         sentinel/...             #   ledger + regressed keys, peak source
-      relay/                     # TPU relay watch (ISSUE 12): last probe
-        status, probes, ...      #   verdict, tally, last error
 """
 
 from __future__ import annotations
@@ -186,13 +184,6 @@ class TelemetryStateProvider(NbProvider):
                 r["entries"] for r in rs["planes"].values()
             ):
                 out["device-residency"] = rs
-        # TPU relay watch (ISSUE 12 satellite): probe verdicts become
-        # queryable state instead of a log file nobody reads in-process.
-        relm = sys.modules.get("holo_tpu.telemetry.relay")
-        if relm is not None:
-            rs = relm.stats()
-            if rs.get("probes") or rs.get("status") != "unknown":
-                out["relay"] = rs
         return {ROOT: out}
 
 

@@ -23,9 +23,6 @@ An :class:`Objective` declares WHAT is graded and HOW:
   good: the oracle delivered — the fallback fraction is reported
   separately); the target quantile is what the threshold is meant to
   hold at (``target`` = the good-fraction objective, e.g. 0.999).
-- ``kind="availability"`` — continuous up/down grading (the relay
-  watch: ``holo_relay_up`` flips via :func:`note_relay`).  The budget
-  is *down seconds over the window*: burn = down_s / (W · (1−target)).
 - ``kind="delivery"`` — per-ticket grading by dispatch priority class
   (:func:`note_served` / :func:`note_shed` from the pipeline's settle
   and shed paths): good = served, bad = shed.  The ``background``
@@ -34,7 +31,7 @@ An :class:`Objective` declares WHAT is graded and HOW:
   rate is the first-class "the queue is full" indicator.
 
 ``source`` scopes the stream: a trigger class (``lsa``/``bfd``/…), a
-priority class for delivery, ``relay`` for availability, or ``"*"``
+priority class for delivery, or ``"*"``
 (every trigger EXCEPT the canary's own — canary end-cuts ride the
 storm's virtual clock and would dilute the production objective with
 synthetic ≈0 walls; the canary grades through its own objective on
@@ -83,7 +80,7 @@ from holo_tpu.telemetry.observatory import DDSketch
 log = logging.getLogger("holo_tpu.telemetry")
 
 #: objective kinds (closed set)
-KINDS = ("latency", "availability", "delivery")
+KINDS = ("latency", "delivery")
 #: burn windows (names are the gauge label vocabulary)
 WINDOWS = ("fast", "slow")
 
@@ -113,8 +110,8 @@ class Objective:
     """One declared service-level objective (see module docstring)."""
 
     name: str
-    kind: str = "latency"  # latency | availability | delivery
-    source: str = "*"  # trigger class | priority class | relay | "*"
+    kind: str = "latency"  # latency | delivery
+    source: str = "*"  # trigger class | priority class | "*"
     quantile: float = 0.99
     threshold_s: float = 1.0
     target: float = 0.999
@@ -149,9 +146,9 @@ class Objective:
 
 
 def default_objectives() -> tuple[Objective, ...]:
-    """The three-objective default the acceptance criteria name (plus
-    the background delivery row that makes the canary's shed rate a
-    budget instead of a counter)."""
+    """The default set: production trigger→FIB latency, the canary,
+    and the background delivery row that makes the canary's shed rate
+    a budget instead of a counter."""
     return (
         # Production trigger→FIB latency: every convergence end-cut
         # (lsa/lsp/bfd/carrier/ifconfig) graded at p99.  The threshold
@@ -164,9 +161,6 @@ def default_objectives() -> tuple[Objective, ...]:
         # real (profiling-clock) trigger→FIB walls through the live
         # dispatch path, graded tighter than production.
         Objective("canary", "latency", "canary", 0.99, 0.25, 0.99),
-        # Relay availability: "MXU bets blocked on the relay" as
-        # budget arithmetic (budget = down seconds over the window).
-        Objective("relay", "availability", "relay", 0.99, 1.0, 0.999),
         # Background admission: probes/advisories shed first under
         # pressure — their shed rate is the saturation budget.
         Objective("background-delivery", "delivery", "background",
@@ -183,29 +177,24 @@ class _ObjState:
 
     __slots__ = (
         "obj", "buckets", "sketch", "fallbacks", "events",
-        "latched", "fires", "down_spans", "up", "since",
+        "latched", "fires",
     )
 
     def __init__(self, obj: Objective, alpha: float, max_bins: int):
         self.obj = obj
-        # bucket index -> [good, bad] (latency/delivery) or
-        # [up_s, down_s] (availability)
+        # bucket index -> [good, bad]
         self.buckets: dict[int, list] = {}
         self.sketch = DDSketch(alpha, max_bins)
         self.fallbacks = 0
         self.events = 0
         self.latched = {"fast": False, "slow": False}
         self.fires = {"fast": 0, "slow": 0}
-        # availability only: closed down spans + current state
-        self.down_spans: list = []  # [start, end] pairs
-        self.up: bool | None = None
-        self.since: float | None = None
 
 
 class SloEngine:
     """Process-wide SLO engine (module singleton via :func:`configure`).
     Hot path = the ``note_*`` methods, fed by the convergence hook, the
-    pipeline shed/settle seams, the relay watch, and the canary;
+    pipeline shed/settle seams, and the canary;
     everything else is cold reporting."""
 
     def __init__(
@@ -250,10 +239,6 @@ class SloEngine:
             if s.obj.kind == "latency" and s.obj.source != "*":
                 self._latency_by_src.setdefault(s.obj.source, ())
                 self._latency_by_src[s.obj.source] += (s,)
-        self._avail = tuple(
-            s for s in self._states.values()
-            if s.obj.kind == "availability"
-        )
         self._delivery_by_cls = {
             s.obj.source: s
             for s in self._states.values() if s.obj.kind == "delivery"
@@ -289,11 +274,6 @@ class SloEngine:
         floor = int((now - self.slow_window) // self.bucket_w)
         for i in [i for i in st.buckets if i < floor]:
             st.buckets.pop(i, None)
-        if st.down_spans:
-            t_floor = now - self.slow_window
-            st.down_spans = [
-                sp for sp in st.down_spans if sp[1] >= t_floor
-            ]
 
     def note_endcut(self, trigger: str, seconds: float, fallback: bool) -> None:
         """One trigger→FIB end-cut (the convergence tracker's close
@@ -340,42 +320,11 @@ class SloEngine:
         if st is not None:
             self._grade(st, False, self._clock())
 
-    def note_relay(self, up: bool) -> None:
-        """One relay probe verdict (the ``holo_relay_up`` flip)."""
-        now = self._clock()
-        for st in self._avail:
-            if st.up is None:
-                st.up, st.since = bool(up), now
-            elif st.up and not up:
-                st.up, st.since = False, now
-            elif not st.up and up:
-                st.down_spans.append([st.since, now])
-                st.up, st.since = True, now
-            st.events += 1
-            self._check(st, now)
-
     # -- burn math ------------------------------------------------------
-
-    def _down_seconds(self, st: _ObjState, now: float, window: float) -> float:
-        lo = now - window
-        down = 0.0
-        for a, b in st.down_spans:
-            down += max(0.0, min(b, now) - max(a, lo))
-        if st.up is False and st.since is not None:
-            down += max(0.0, now - max(st.since, lo))
-        return down
 
     def _bad_frac(self, st: _ObjState, now: float, window: float):
         """(bad_fraction, good, bad) over ``[now - window, now]``;
         ``None`` fraction when the window saw no events."""
-        if st.obj.kind == "availability":
-            if st.up is None:
-                return None, 0, 0
-            # Budget = down seconds over the FULL window (an objective
-            # younger than the window grades the unseen span as up —
-            # the conservative read for a fresh daemon).
-            down = self._down_seconds(st, now, window)
-            return min(down / window, 1.0), 0, 0
         lo = int((now - window) // self.bucket_w)
         good = bad = 0
         for i, b in list(st.buckets.items()):
@@ -506,16 +455,6 @@ class SloEngine:
                     ),
                     "p99": round((st.sketch.quantile(0.99) or 0.0) * 1e3, 3),
                 }
-        if o.kind == "availability":
-            row["down_s_fast"] = round(
-                self._down_seconds(st, now, self.fast_window), 3
-            )
-            row["down_s_slow"] = round(
-                self._down_seconds(st, now, self.slow_window), 3
-            )
-            row["state"] = (
-                "unknown" if st.up is None else ("up" if st.up else "down")
-            )
         return row
 
     def report(self) -> dict:
@@ -617,11 +556,3 @@ def note_shed(cls: str, reason: str) -> None:
     if sl is None:
         return
     sl.note_shed(cls, reason)
-
-
-def note_relay(up: bool) -> None:
-    """Relay probe verdict (no-op while disarmed)."""
-    sl = _SLO
-    if sl is None:
-        return
-    sl.note_relay(up)

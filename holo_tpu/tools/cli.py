@@ -302,15 +302,9 @@ def cmd_explain(args) -> int:
     sl = None
     prober = None
     if args.slo:
-        from holo_tpu.telemetry import relay, slo
+        from holo_tpu.telemetry import slo
 
         sl = slo.configure(check_every=16)
-        st = relay.status()
-        if st["status"] != "unknown":
-            # The relay availability objective grades real watch
-            # verdicts only — a process that never probed the relay
-            # reports the row as budget-unknown rather than faking one.
-            sl.note_relay(st["status"] == "up")
     tuner = tuner_mod.configure_engine_tuner()
     try:
         if args.storm:
@@ -365,10 +359,12 @@ def cmd_explain(args) -> int:
             print(json.dumps(doc, sort_keys=True, indent=2))
             return 0
         peaks = doc["peaks"]
+        ridge = peaks["ridge_flops_per_byte"]
         print(
             f"dispatch observatory — timing: {doc['timing']}, peaks: "
             f"{peaks['source']} "
-            f"(ridge {peaks['ridge_flops_per_byte']:g} flop/B)"
+            + ("(no peaks: verdicts unknown)" if ridge is None
+               else f"(ridge {ridge:g} flop/B)")
         )
         print(f"top {args.top} cost centers:")
         _print_table(

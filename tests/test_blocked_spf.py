@@ -96,7 +96,20 @@ def test_backend_blocked_engine_parity_and_fallback():
 
     topo = random_ospf_topology(n_routers=150, n_networks=30, seed=4)
     masks = whatif_link_failure_masks(topo, n_scenarios=4, seed=5)
-    be = TpuSpfBackend(engine="blocked")
+    from functools import partial
+
+    from holo_tpu import telemetry
+    from holo_tpu.ops.blocked_spf import whatif_spf_blocked
+
+    def interpreted(backend):
+        # Pallas interpret mode is asked for by name: no production
+        # path selects it from the backend it happens to find.
+        backend._jit_blocked = jax.jit(
+            partial(whatif_spf_blocked, interpret=True)
+        )
+        return backend
+
+    be = interpreted(TpuSpfBackend(engine="blocked"))
     scalar = ScalarSpfBackend().compute_whatif(topo, masks)
     for s, t in zip(scalar, be.compute_whatif(topo, masks)):
         np.testing.assert_array_equal(s.dist, t.dist)
@@ -107,7 +120,9 @@ def test_backend_blocked_engine_parity_and_fallback():
     np.testing.assert_array_equal(
         one.dist, ScalarSpfBackend().compute(topo).dist
     )
-    # parallel (src,dst) edges: blocked preconditions fail -> gather fallback
+    # parallel (src,dst) edges: the blocked preconditions fail LOUDLY —
+    # the device arm raises, the breaker serves the scalar oracle and
+    # counts it; the gather engine never runs under the blocked name.
     par = Topology(
         n_vertices=3,
         is_router=np.ones(3, bool),
@@ -116,6 +131,31 @@ def test_backend_blocked_engine_parity_and_fallback():
         edge_cost=np.array([1, 2, 1, 1, 1, 9], np.int32),
         root=0,
     )
-    assert TpuSpfBackend(engine="blocked").prepare_blocked(par) is None
-    got = TpuSpfBackend(engine="blocked").compute(par)
+    import pytest
+
+    with pytest.raises(ValueError, match="parallel"):
+        TpuSpfBackend(engine="blocked").prepare_blocked(par)
+
+    def fallbacks():
+        return sum(
+            telemetry.snapshot("holo_resilience_fallback_total").values()
+        )
+
+    before = fallbacks()
+    bad = interpreted(TpuSpfBackend(engine="blocked"))
+    got = bad.compute(par)
     np.testing.assert_array_equal(got.dist, ScalarSpfBackend().compute(par).dist)
+    assert fallbacks() == before + 1
+    assert "ValueError" in bad.breaker.last_error
+
+
+def test_interpret_mode_is_never_chosen_from_the_backend():
+    """Interpret mode is off unless a caller names it (ISSUE 21): the
+    default must not depend on ``jax.default_backend()``."""
+    import inspect
+
+    from holo_tpu.ops.blocked import whatif_distances_blocked
+
+    for fn in (whatif_spf_blocked, whatif_distances_blocked):
+        assert inspect.signature(fn).parameters["interpret"].default is False
+        assert "default_backend" not in inspect.getsource(fn)

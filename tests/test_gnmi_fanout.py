@@ -825,14 +825,13 @@ def test_provider_surfaces_fanout_stats_leaf():
     assert found and found[0]["breaker"] in ("closed", "open", "half-open")
 
 
-def test_capture_device_trace_without_tpu_is_explicit_not_used(tmp_path):
+def test_capture_device_trace_without_tpu_is_explicit_not_captured(tmp_path):
     from holo_tpu.telemetry import profiling
 
     row = profiling.capture_device_trace(tmp_path / "trace")
-    assert row["relay"] == "not-used"
     assert row["captured"] is False
-    assert row.get("platform", "cpu") != "tpu"
-    assert "reason" in row or "error" in row
+    assert row["platform"] == "cpu"
+    assert "no TPU attached" in row["reason"]
 
 
 def test_daemon_boot_with_device_trace_dir_never_fails(tmp_path):
@@ -844,7 +843,8 @@ def test_daemon_boot_with_device_trace_dir_never_fails(tmp_path):
     cfg.telemetry.device_trace_dir = str(tmp_path / "trace")
     d = Daemon(config=cfg, loop=EventLoop(clock=VirtualClock()), name="dtr")
     assert d._device_trace is not None
-    assert d._device_trace["relay"] == "not-used"
+    assert d._device_trace["captured"] is False
+    assert d._device_trace["platform"] == "cpu"
 
 
 def test_on_change_sessions_receive_deltas_at_the_base_tick():

@@ -124,7 +124,7 @@ def test_hl302_host_callback_fires():
     fired, findings = _rules_fired(entry)
     assert fired == {"HL302"}
     assert findings[0].severity == "error"
-    assert "debug_callback" in findings[0].message
+    assert "debug_print" in findings[0].message
 
 
 def test_hl302_pure_callback_fires():

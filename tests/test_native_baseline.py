@@ -37,3 +37,20 @@ def test_native_batch_masks():
     for i in range(masks.shape[0]):
         ref = ScalarSpfBackend().compute(topo, masks[i])
         np.testing.assert_array_equal(ref.dist, dists[i])
+
+
+def test_library_is_keyed_on_host_so_a_copied_build_is_not_loaded(
+    monkeypatch, tmp_path
+):
+    """ISSUE 21: the objects are -march=native, so a native/build/
+    copied from another machine must be rebuilt, never loaded."""
+    from holo_tpu import native_build as nb
+
+    here = nb._ensure("libspf_baseline", ["spf_baseline.cpp"])
+    copied = tmp_path / here.name
+    copied.write_bytes(b"built on another CPU")
+    monkeypatch.setattr(nb, "BUILD", tmp_path)
+    assert nb._ensure("libspf_baseline", ["spf_baseline.cpp"]) == copied
+    monkeypatch.setattr(nb, "_host_id", lambda: "another-host")
+    rebuilt = nb._ensure("libspf_baseline", ["spf_baseline.cpp"])
+    assert rebuilt != copied and rebuilt.stat().st_size > 1000

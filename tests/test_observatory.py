@@ -1,5 +1,5 @@
 """Dispatch observatory (ISSUE 12): sketch core bounds, roofline
-attribution, regression sentinel, explain CLI, relay watch.
+attribution, regression sentinel, explain CLI.
 
 Sketch contract tests pin the DDSketch guarantees the sentinel relies
 on (relative-error quantiles, merge associativity, byte-identical
@@ -25,7 +25,7 @@ from holo_tpu.pipeline.tuner import (
     reset_engine_tuner,
 )
 from holo_tpu.resilience import faults
-from holo_tpu.telemetry import flight, observatory, profiling, relay
+from holo_tpu.telemetry import flight, observatory, profiling
 from holo_tpu.telemetry.observatory import (
     DDSketch,
     DeterministicTimer,
@@ -304,8 +304,42 @@ def test_roofline_peaks_config_moves_the_ridge():
     obs.note_cost("s", "k", "e", ("b",), {"flops": 1e6, "bytes": 1e7})
     assert obs.roofline()[0]["verdict"] == "compute-bound"
     assert obs.peaks.source == "hbm"
-    # The default is the honest CPU guess, labeled for the dead relay.
-    assert "relay: not-used" in RooflinePeaks().source
+    # The default follows the attached device: the CPU guess here.
+    assert observatory.device_peaks() is observatory.CPU_GUESS
+    assert Observatory().peaks_source == "cpu-default"
+
+
+def test_default_peaks_follow_the_device_kind(monkeypatch):
+    """ISSUE 21: a v5e gets its published peaks with the source named;
+    any other accelerator gets NO peaks — verdict ``unknown`` and no
+    roofline fraction, never a CPU guess under a device's name."""
+    import jax
+
+    class _Dev:
+        def __init__(self, platform, kind):
+            self.platform, self.device_kind = platform, kind
+
+    monkeypatch.setattr(jax, "devices", lambda: [_Dev("tpu", "TPU v5 lite")])
+    v5e = observatory.device_peaks()
+    assert (v5e.flops_per_sec, v5e.bytes_per_sec) == (1.97e14, 8.19e11)
+    assert "v5e" in v5e.source and "documentation" in v5e.source
+    monkeypatch.setattr(jax, "devices", lambda: [_Dev("tpu", "TPU v9")])
+    assert observatory.device_peaks() is None
+    obs = Observatory(check_every=0)
+    obs.note_cost("s", "k", "e", ("b",), {"flops": 1e6, "bytes": 1e7})
+    for _ in range(4):
+        obs._sketches.setdefault(
+            ("s", "device", "e", ("b",), "k"), DDSketch()
+        ).observe(0.01)
+    row = obs.roofline()[0]
+    assert row["verdict"] == "unknown" and row["peaks"] == "unknown-device"
+    assert "achieved_bytes_per_sec" in row
+    assert "roofline_fraction" not in row
+    assert obs.report()["peaks"]["ridge_flops_per_byte"] is None
+    # [telemetry] roofline-peaks still overrides on any device.
+    assert Observatory(
+        peaks={"flops": 1e9, "bytes": 1e12, "name": "hbm"}
+    ).peaks.source == "hbm"
 
 
 def test_real_gather_dispatch_classified_memory_bound():
@@ -463,37 +497,17 @@ def test_delaypoint_disarmed_is_noop():
     assert not inj.injected
 
 
-# -- surfaces: provider leaf, relay watch, CLI, tuner ledger -------------
+# -- surfaces: provider leaf, CLI, tuner ledger --------------------------
 
 
-def test_provider_leaf_carries_observatory_and_relay():
+def test_provider_leaf_carries_observatory():
     from holo_tpu.telemetry.provider import TelemetryStateProvider
 
     obs = observatory.configure(check_every=0)
     obs._observe("spf.one", "device", "-", 0.01)
-    relay.note_probe(False, error="probe timeout after 150s")
     state = TelemetryStateProvider().get_state()["holo-telemetry"]
     assert state["observatory"]["sketches"] == 1
     assert state["observatory"]["sentinel"]["flags"] == 0
-    assert state["relay"]["status"] == "down"
-    assert "timeout" in state["relay"]["last_error"]
-    names = {m["name"].split("{")[0] for m in state["metric"]}
-    assert "holo_relay_up" in names
-    assert "holo_relay_probes_total" in names
-
-
-def test_relay_watch_gauge_and_summary():
-    relay.note_probe(True, took_s=1.2)
-    assert relay.status()["status"] == "up"
-    snap = telemetry.snapshot(prefix="holo_relay_up")
-    assert snap["holo_relay_up"] == 1.0
-    relay.note_probe(False, error="wedged")
-    snap = telemetry.snapshot(prefix="holo_relay_up")
-    assert snap["holo_relay_up"] == 0.0
-    s = relay.summary(False, [{"ok": False, "error": "wedged"}])
-    assert s == {"status": "down", "probes": 1, "last_error": "wedged"}
-    assert relay.not_used() == "not-used"
-    assert relay.not_used("forced mesh") == "not-used (forced mesh)"
 
 
 def test_explain_cli_json_byte_identical(capsys):
@@ -535,7 +549,7 @@ def test_explain_cli_text_render(capsys):
     assert "memory-bound" in out
     assert "engine tuner win/loss ledger" in out
     assert "sentinel:" in out
-    assert "relay: not-used" in out  # the honest CPU peak label
+    assert "cpu-default" in out  # the peak label names the platform
 
 
 def test_shared_table_renderer_and_top(capsys):
@@ -583,6 +597,6 @@ def test_observatory_stats_leaf_shape():
     obs._observe("spf.one", "device", "-", 0.01)
     s = obs.stats()
     assert s["sketches"] == 1 and s["observations"] == 1
-    assert "relay: not-used" in s["peaks-source"]
+    assert s["peaks-source"] == "cpu-default"
     snap = telemetry.snapshot(prefix="holo_observatory_sketches")
     assert snap["holo_observatory_sketches"] == 1.0

@@ -463,7 +463,7 @@ def test_transient_exhaustion_still_strikes_breaker():
 
     def down():
         calls.append(1)
-        raise OSError("UNAVAILABLE: relay endpoint down")
+        raise OSError("UNAVAILABLE: runtime endpoint down")
 
     verdict, _guard, _handle = _guarded_launch(br, "test.down", down)
     assert verdict == "fallback"

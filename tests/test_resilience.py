@@ -114,7 +114,7 @@ def test_breaker_deadline_overrun_counts_but_keeps_completed_result():
         return "late-device"
 
     # The result is already in hand and bit-identical by contract:
-    # return it, but count the failure so a degrading relay opens the
+    # return it, but count the failure so a degrading device opens the
     # circuit (and THEN dispatches go scalar up front).
     assert br.call(slow, lambda: "fb") == "late-device"
     assert br.consecutive_failures == 1 and br.state == "closed"

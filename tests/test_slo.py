@@ -176,33 +176,6 @@ def test_endcut_routes_by_trigger_source():
     assert eng.objective("all").fallbacks == 1
 
 
-def test_availability_down_span_arithmetic():
-    clk = _FakeClock(0.0)
-    eng = SloEngine(
-        objectives=(
-            Objective("relay", "availability", "relay", 0.99, 1.0, 0.9),
-        ),
-        clock=clk, fast_window=100.0, slow_window=1000.0, check_every=0,
-    )
-    st = eng.objective("relay")
-    eng.note_relay(True)
-    clk.t = 10.0
-    eng.note_relay(False)
-    clk.t = 30.0
-    eng.note_relay(True)  # closed span: 20 s down
-    clk.t = 100.0
-    assert eng._down_seconds(st, clk.t, 100.0) == pytest.approx(20.0)
-    # burn = (down/W) / (1-target) = 0.2 / 0.1
-    assert eng.burn(st, clk.t, 100.0) == pytest.approx(2.0)
-    # an OPEN down state accrues up to now; the closed [10, 30] span
-    # has slid out of the [50, 150] window entirely
-    eng.note_relay(False)
-    clk.t = 150.0
-    assert eng._down_seconds(st, clk.t, 100.0) == pytest.approx(50.0)
-    row = eng._objective_row(st, clk.t)
-    assert row["state"] == "down"
-
-
 def test_delivery_objective_grades_served_vs_shed():
     eng = SloEngine(clock=_FakeClock(77.0), check_every=0)
     for _ in range(5):
@@ -239,17 +212,6 @@ def test_fib_commit_feeds_trigger_fib_objective():
     assert st.events == 1
     assert st.sketch.count == 1
     assert eng._bad_frac(st, clk.t, eng.fast_window)[1] == 1  # good
-
-
-def test_relay_watch_feeds_availability_objective():
-    from holo_tpu.telemetry import relay
-
-    eng = slo.configure(check_every=0)
-    relay.note_probe(True, took_s=0.01)
-    relay.note_probe(False, error="boom")
-    st = eng.objective("relay")
-    assert st.events == 2
-    assert st.up is False
 
 
 def test_pipeline_serve_and_shed_feed_delivery_objective():
@@ -586,7 +548,6 @@ def test_disarmed_seams_are_one_global_check(monkeypatch):
     slo.note_probe(True, 0.01)
     slo.note_served("background")
     slo.note_shed("background", "expired")
-    slo.note_relay(True)
     # The convergence end-cut hook is uninstalled: fib_commit pays one
     # None check, never an SLO clock read.
     assert convergence._SLO_HOOK is None
