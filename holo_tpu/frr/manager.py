@@ -470,9 +470,8 @@ class FrrEngine:
             "frr.batch", "device"
         ):
             faults.delaypoint("frr.dispatch")
-            with profiling.annotation("frr.batch.device"):
-                if not profiling.device_stages("frr.batch", out):
-                    profiling.sync(out)
+            if not profiling.device_stages("frr.batch", out):
+                profiling.sync(out)
         nl = fin.n_links
         n = int(topo.n_vertices)
         if sharded:

@@ -95,7 +95,7 @@ from holo_tpu.protocols.ospf.spf_run import (
     link_spf_delta,
 )
 from holo_tpu.spf.backend import ScalarSpfBackend, SpfBackend
-from holo_tpu.telemetry import convergence
+from holo_tpu.telemetry import convergence, profiling
 from holo_tpu.utils.ip import ALL_DR_RTRS_V4, ALL_SPF_RTRS_V4, mask_of
 from holo_tpu.utils.netio import NetIo, NetRxPacket
 from holo_tpu.utils.runtime import Actor
@@ -2773,7 +2773,11 @@ class OspfInstance(Actor):
         # capture them, so the event rides through to the FIB commit.
         with convergence.spf_run(self._conv_pending, self.name):
             with telemetry.span("ospf.spf", instance=self.name):
-                self._run_spf_traced()
+                # Host stages (site ospf.spf): run holds the whole run,
+                # topology / link / derive / inter / publish its parts;
+                # the backend stages its dispatch itself (spf.one.*).
+                with profiling.stage("ospf.spf", "run"):
+                    self._run_spf_traced()
 
     def _run_spf_traced(self) -> None:
         now = self.loop.clock.now()
@@ -2816,46 +2820,52 @@ class OspfInstance(Actor):
                     a for a in self.areas.values() if int(a.area_id) == 0
                 ]
                 continue
-            iface_by_addr = {
-                i.addr_ip: i.name for i in area.interfaces.values() if i.addr_ip
-            }
-            iface_by_nbr = {}
-            p2p_nbr_addr = {}
-            for i in area.interfaces.values():
-                for nbr in i.neighbors.values():
-                    if nbr.state == NsmState.FULL:
-                        iface_by_nbr[nbr.router_id] = (i.name, nbr.src)
-                        p2p_nbr_addr[(i.name, nbr.router_id)] = nbr.src
-            iface_by_ifindex = {
-                i.ifindex: i.name
-                for i in area.interfaces.values()
-                if i.ifindex
-            }
-            vlink_nexthops = None
-            if int(area.area_id) == 0:
-                vlink_nexthops = self._vlink_nexthops(area, area_results, now)
-            # Interface fast-reroute SRLG config -> Topology.edge_srlg
-            # (the FRR srlg_disjoint policy input; ROADMAP carry-over).
-            from holo_tpu.protocols.ospf.spf_run import srlg_bits
+            with profiling.stage("ospf.spf", "topology"):
+                iface_by_addr = {
+                    i.addr_ip: i.name
+                    for i in area.interfaces.values()
+                    if i.addr_ip
+                }
+                iface_by_nbr = {}
+                p2p_nbr_addr = {}
+                for i in area.interfaces.values():
+                    for nbr in i.neighbors.values():
+                        if nbr.state == NsmState.FULL:
+                            iface_by_nbr[nbr.router_id] = (i.name, nbr.src)
+                            p2p_nbr_addr[(i.name, nbr.router_id)] = nbr.src
+                iface_by_ifindex = {
+                    i.ifindex: i.name
+                    for i in area.interfaces.values()
+                    if i.ifindex
+                }
+                vlink_nexthops = None
+                if int(area.area_id) == 0:
+                    vlink_nexthops = self._vlink_nexthops(
+                        area, area_results, now
+                    )
+                # Interface fast-reroute SRLG config -> Topology.edge_srlg
+                # (the FRR srlg_disjoint policy input; ROADMAP carry-over).
+                from holo_tpu.protocols.ospf.spf_run import srlg_bits
 
-            iface_srlg = {
-                i.name: srlg_bits(i.config.srlg)
-                for i in area.interfaces.values()
-                if i.config.srlg
-            }
-            st = build_topology(
-                area.lsdb, self.config.router_id, now, iface_by_addr,
-                iface_by_nbr, p2p_nbr_addr, iface_by_ifindex,
-                vlink_nexthops, iface_srlg=iface_srlg,
-                partition_of=self.spf_partition_of,
-            )
+                iface_srlg = {
+                    i.name: srlg_bits(i.config.srlg)
+                    for i in area.interfaces.values()
+                    if i.config.srlg
+                }
+                st = build_topology(
+                    area.lsdb, self.config.router_id, now, iface_by_addr,
+                    iface_by_nbr, p2p_nbr_addr, iface_by_ifindex,
+                    vlink_nexthops, iface_srlg=iface_srlg,
+                    partition_of=self.spf_partition_of,
+                )
             if st is None:
                 self._spf_delta_bases.pop(area.area_id, None)
                 continue
             # DeltaPath seam: diff against the previous run's marshaled
             # topology so the backend can update the device-resident
             # graph in place instead of re-marshaling the area LSDB.
-            link_spf_delta(self._spf_delta_bases.get(area.area_id), st)
+            with profiling.stage("ospf.spf", "link"):
+                link_spf_delta(self._spf_delta_bases.get(area.area_id), st)
             self._spf_delta_bases[area.area_id] = st
             res = self.backend.compute(
                 st.topo, multipath_k=self._multipath_k()
@@ -2867,21 +2877,22 @@ class OspfInstance(Actor):
             # area.state.routers, whose flags were captured at route
             # computation — NOT the live LSDB, which may have changed
             # since, e.g. right after a clear-database RPC).
-            from holo_tpu.ops.graph import INF as _INF
+            with profiling.stage("ospf.spf", "derive"):
+                from holo_tpu.ops.graph import INF as _INF
 
-            flags_now = {}
-            for key, e in area.lsdb.entries.items():
-                if key.type == LsaType.ROUTER and not e.lsa.is_maxage:
-                    flags_now[key.adv_rtr] = e.lsa.body.flags
-            self._area_reachable_routers[area.area_id] = {
-                rid: flags_now.get(rid, RouterFlags(0))
-                for rid, v in st.router_index.items()
-                if res.dist[v] < _INF
-            }
-            intra = derive_routes(
-                st, res, area.lsdb, now, area.area_id,
-                max_paths=self.config.max_paths,
-            )
+                flags_now = {}
+                for key, e in area.lsdb.entries.items():
+                    if key.type == LsaType.ROUTER and not e.lsa.is_maxage:
+                        flags_now[key.adv_rtr] = e.lsa.body.flags
+                self._area_reachable_routers[area.area_id] = {
+                    rid: flags_now.get(rid, RouterFlags(0))
+                    for rid, v in st.router_index.items()
+                    if res.dist[v] < _INF
+                }
+                intra = derive_routes(
+                    st, res, area.lsdb, now, area.area_id,
+                    max_paths=self.config.max_paths,
+                )
             area_intra[area.area_id] = intra
             for prefix, route in intra.items():
                 cur = all_routes.get(prefix)
@@ -2907,54 +2918,56 @@ class OspfInstance(Actor):
         # follow-up); enqueue-only — nothing here waits on them.
         self._enqueue_whatif_advisory(area_results)
 
-        # Inter-area routes (RFC 2328 §16.2): shared consumption stage
-        # (also used by the partial run with a prefix scope).
-        intra_prefixes = set(all_routes.keys())
-        inter_routes: dict = {}
-        self._derive_inter_area(
-            area_results, all_routes, inter_routes, intra_prefixes
-        )
+        with profiling.stage("ospf.spf", "inter"):
+            # Inter-area routes (RFC 2328 §16.2): shared consumption stage
+            # (also used by the partial run with a prefix scope).
+            intra_prefixes = set(all_routes.keys())
+            inter_routes: dict = {}
+            self._derive_inter_area(
+                area_results, all_routes, inter_routes, intra_prefixes
+            )
 
-        # ABR: (re-)originate Summary LSAs — each area's intra routes are
-        # advertised into every other attached area (loop-free: summaries
-        # are never derived from summaries).
-        # AS-external routes (lowest preference — only for unknown prefixes).
-        for prefix, route in self._external_routes(
-            area_results, set(all_routes.keys())
-        ).items():
-            all_routes[prefix] = route
+            # ABR: (re-)originate Summary LSAs — each area's intra routes
+            # are advertised into every other attached area (loop-free:
+            # summaries are never derived from summaries).
+            # AS-external routes (lowest preference — only for unknown
+            # prefixes).
+            for prefix, route in self._external_routes(
+                area_results, set(all_routes.keys())
+            ).items():
+                all_routes[prefix] = route
 
-        self._nssa_translate(area_results)
-        if self.is_abr:
-            self._originate_summaries(area_intra, inter_routes)
-            self._originate_asbr_summaries(area_results)
-        else:
-            # No longer (or never) an ABR: flush any self-originated
-            # summaries or neighbors would route into a dead hierarchy
-            # forever (refresh would keep them alive otherwise).
-            for area in self.areas.values():
-                for key in list(area.lsdb.entries):
-                    if (
-                        key.type == LsaType.SUMMARY_NETWORK
-                        and key.adv_rtr == self.config.router_id
-                        and not area.lsdb.entries[key].lsa.is_maxage
-                    ):
-                        self._flush_self_lsa(area, key)
+            self._nssa_translate(area_results)
+            if self.is_abr:
+                self._originate_summaries(area_intra, inter_routes)
+                self._originate_asbr_summaries(area_results)
+            else:
+                # No longer (or never) an ABR: flush any self-originated
+                # summaries or neighbors would route into a dead hierarchy
+                # forever (refresh would keep them alive otherwise).
+                for area in self.areas.values():
+                    for key in list(area.lsdb.entries):
+                        if (
+                            key.type == LsaType.SUMMARY_NETWORK
+                            and key.adv_rtr == self.config.router_id
+                            and not area.lsdb.entries[key].lsa.is_maxage
+                        ):
+                            self._flush_self_lsa(area, key)
 
-        # SPF log ring (32 entries, reference spf.rs:770-804).
-        self.spf_log.append(
-            {
-                "run": self.spf_run_count,
-                "type": "full",
-                "backend": self.backend.name,
-                "scheduled-at": scheduled_at,
-                "start-time": start_time,
-                "end-time": self.loop.clock.now(),
-                "trigger-count": triggers,
-                "route-count": len(all_routes),
-            }
-        )
-        del self.spf_log[:-32]
+            # SPF log ring (32 entries, reference spf.rs:770-804).
+            self.spf_log.append(
+                {
+                    "run": self.spf_run_count,
+                    "type": "full",
+                    "backend": self.backend.name,
+                    "scheduled-at": scheduled_at,
+                    "start-time": start_time,
+                    "end-time": self.loop.clock.now(),
+                    "trigger-count": triggers,
+                    "route-count": len(all_routes),
+                }
+            )
+            del self.spf_log[:-32]
 
         # Cache this run's products: a later summary/external-only change
         # reuses the per-area SPTs and rewrites only the affected table
@@ -2966,7 +2979,8 @@ class OspfInstance(Actor):
             "inter_routes": inter_routes,
         }
 
-        self._finish_spf(all_routes)
+        with profiling.stage("ospf.spf", "publish"):
+            self._finish_spf(all_routes)
 
     def _derive_inter_area(
         self,
@@ -3133,7 +3147,10 @@ class OspfInstance(Actor):
 
         cache["routes"] = routes
         cache["inter_routes"] = inter_routes
-        self._finish_spf(routes)
+        # A partial run books run and publish; the rest of run is its
+        # prefix-scoped inter-area / external work above.
+        with profiling.stage("ospf.spf", "publish"):
+            self._finish_spf(routes)
 
     def reoriginate_summaries(self) -> None:
         """Config-triggered summary refresh (ranges / totally-stubby /

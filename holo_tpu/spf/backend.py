@@ -1258,9 +1258,8 @@ class TpuSpfBackend(SpfBackend):
                 self._obs_cost("spf.one", "one", engine, obucket, entry)
             with profiling.stage("spf.one", "device"):
                 faults.delaypoint("spf.dispatch")
-                with profiling.annotation("spf.one.device"):
-                    if not profiling.device_stages("spf.one", out):
-                        profiling.sync(out)
+                if not profiling.device_stages("spf.one", out):
+                    profiling.sync(out)
             t1 = profiling.clock()
             with profiling.stage("spf.one", "readback"):
                 with sanctioned_transfer("spf.one.unmarshal"):
@@ -1381,9 +1380,8 @@ class TpuSpfBackend(SpfBackend):
                 # in the output set fails HERE, named, not as a generic
                 # deleted-array error inside the readback.
                 assert_live("spf.one.readback", out)
-                with profiling.annotation("spf.one.delta.device"):
-                    if not profiling.device_stages("spf.one", out):
-                        profiling.sync(out)
+                if not profiling.device_stages("spf.one", out):
+                    profiling.sync(out)
             t1 = profiling.clock()
             with profiling.stage("spf.one", "readback"):
                 with sanctioned_transfer("spf.one.unmarshal"):
@@ -1466,8 +1464,7 @@ class TpuSpfBackend(SpfBackend):
                     "spf.blocked", "blocked", "blocked", bl_bucket, entry
                 )
             with profiling.stage("spf.blocked", "device"):
-                with profiling.annotation("spf.blocked.device"):
-                    profiling.sync(out)
+                profiling.sync(out)
             t1 = profiling.clock()
             with profiling.stage("spf.blocked", "readback"):
                 with sanctioned_transfer("spf.blocked.unmarshal"):
@@ -1574,9 +1571,8 @@ class TpuSpfBackend(SpfBackend):
                 )
             with profiling.stage("spf.whatif", "device"):
                 faults.delaypoint("spf.dispatch")
-                with profiling.annotation("spf.whatif.device"):
-                    if not profiling.device_stages("spf.whatif", out):
-                        profiling.sync(out)
+                if not profiling.device_stages("spf.whatif", out):
+                    profiling.sync(out)
             t1 = profiling.clock()
             # One bulk device→host transfer per plane: per-scenario slicing
             # of device arrays would pay the host round-trip B×4 times.
@@ -1683,9 +1679,8 @@ class TpuSpfBackend(SpfBackend):
                     entry,
                 )
             with profiling.stage("spf.multiroot", "device"):
-                with profiling.annotation("spf.multiroot.device"):
-                    if not profiling.device_stages("spf.multiroot", out):
-                        profiling.sync(out)
+                if not profiling.device_stages("spf.multiroot", out):
+                    profiling.sync(out)
             t1 = profiling.clock()
             with profiling.stage("spf.multiroot", "readback"):
                 with sanctioned_transfer("spf.multiroot.unmarshal"):
@@ -1852,9 +1847,8 @@ class TpuSpfBackend(SpfBackend):
                 faults.delaypoint("spf.dispatch")
                 # Donation-guard force boundary (see _try_incremental).
                 assert_live("spf.one.readback", h.out)
-                with profiling.annotation("spf.one.device"):
-                    if not profiling.device_stages("spf.one", h.out):
-                        profiling.sync(h.out)
+                if not profiling.device_stages("spf.one", h.out):
+                    profiling.sync(h.out)
             t1 = profiling.clock()
             with profiling.stage("spf.one", "readback"):
                 with sanctioned_transfer("spf.one.unmarshal"):

@@ -7,10 +7,16 @@ phases that actually matter for the DeltaPath incremental-SPF work —
 
 - **marshal** — host graph/plane preparation + the (async) jit call;
 - **device** — device execution, measured by ``jax.block_until_ready``
-  bracketing (an optional
-  ``jax.profiler.TraceAnnotation`` path activates on a real TPU so the
-  phases also land in XLA's own profiler timeline);
+  bracketing;
 - **readback** — device→host materialization of the result planes.
+
+:func:`stage` is also the one host-span primitive.  The served OSPF
+path names its host work with it (site ``ospf.spf``: ``run`` /
+``topology`` / ``link`` / ``derive`` / ``inter`` / ``publish``) and the
+event loop names every delivery by its actor (site ``loop``).  Armed on
+a real TPU, every stage also sits inside a
+``jax.profiler.TraceAnnotation("<site>.<stage>")``, so a profiler
+capture shows the stages on the same clock as the device operations.
 
 Each phase records a nested trace sub-span AND a
 ``holo_profile_stage_seconds{site,stage,device}`` histogram observation
@@ -40,6 +46,7 @@ values or reduces arrays on the traced path (holo-lint HL101/HL105).
 from __future__ import annotations
 
 import logging
+import sys
 import threading
 import time
 from contextlib import contextmanager, nullcontext
@@ -50,7 +57,9 @@ log = logging.getLogger("holo_tpu.telemetry")
 
 _STAGE_SECONDS = telemetry.histogram(
     "holo_profile_stage_seconds",
-    "Per-dispatch sub-span time (marshal / device / readback); "
+    "Per-dispatch sub-span time (marshal / device / readback) and host "
+    "stages (site=ospf.spf: one SPF run's functions; site=loop: one "
+    "delivery, stage=<actor>); "
     "device=<id> rows are the per-device completion split of a "
     "mesh-sharded dispatch ('-' = host-side / whole-dispatch span)",
     ("site", "stage", "device"),
@@ -97,6 +106,20 @@ _timer_overridden = False
 _ctx_local = threading.local()
 _NULLCTX = nullcontext()
 
+# Host sites: stages of host functions, outside any dispatch.  They
+# have no dispatch context to key a sketch by, and a loop delivery is
+# bimodal by nature (a timer tick or a whole SPF run), which the
+# observatory's regression sentinel would flag for ever: they never
+# feed it (decided here, by the site: no switch).
+_HOST_SITES = frozenset({"ospf.spf", "loop"})
+
+# Profiler-annotation factory of an armed stage: ``factory(label)`` is a
+# context manager on the profiler's clock.  _UNRESOLVED until JAX is up
+# (at arming, else at the first armed stage after), then latched for the
+# process: ``jax.profiler.TraceAnnotation`` on a TPU, None elsewhere.
+_UNRESOLVED = object()
+_annotation = _UNRESOLVED
+
 # (site, shape signature) -> {"flops": float, "bytes": float}; one entry
 # per compiled shape bucket, exactly mirroring the backends' jit caches.
 _cost_lock = threading.Lock()
@@ -108,10 +131,49 @@ def set_device_profiling(on: bool) -> None:
     ``[telemetry] profile-device-time``; bench/tests flip it directly)."""
     global _enabled
     _enabled = bool(on)
+    if _enabled and _annotation is _UNRESOLVED:
+        _resolve_annotation()
+    # The event loop's per-delivery span (site "loop", stage = actor).
+    from holo_tpu.utils import runtime
+
+    runtime.set_delivery_stage(stage if _enabled else None)
 
 
 def device_profiling() -> bool:
     return _enabled
+
+
+def set_annotation_factory(factory=_UNRESOLVED) -> None:
+    """Tests: inject the armed stages' annotation factory
+    (``factory(label) -> context manager``; None = no annotation).  No
+    argument: back to unresolved, latched from the platform again."""
+    global _annotation
+    _annotation = factory
+
+
+def _resolve_annotation():
+    """Latch the annotation factory from the platform, once.  Never
+    brings JAX up itself (a daemon armed at boot, or one that serves
+    from the scalar backend, must not take the chip for a span): while
+    no backend is initialised the answer is None, unlatched."""
+    global _annotation
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        from jax._src.xla_bridge import backends_are_initialized
+
+        if not backends_are_initialized():
+            return None
+    except ImportError:  # the private seam moved: JAX is imported, ask it
+        pass
+    try:
+        tpu = jax.default_backend() == "tpu"
+        _annotation = jax.profiler.TraceAnnotation if tpu else None
+    except Exception:  # noqa: BLE001 — best-effort on exotic backends
+        log.debug("profiler annotation unavailable", exc_info=True)
+        _annotation = None
+    return _annotation
 
 
 def set_observer(fn) -> None:
@@ -202,8 +264,15 @@ def stage(site: str, name: str, device: str = "-"):
     measured wall is ALSO fed to its streaming sketches — including
     with device profiling off, so the observatory can stay always-on
     without the histogram/exemplar machinery; observations keep the
-    existing contract of recording only on clean exit."""
+    existing contract of recording only on clean exit.  Host sites
+    (``_HOST_SITES``) are not dispatches and never feed it.
+
+    Armed, the whole stage also sits inside the profiler annotation
+    ``<site>.<name>`` (see ``_annotation``): a host span in the same
+    capture as the device operations."""
     obs = _OBSERVER
+    if obs is not None and site in _HOST_SITES:
+        obs = None
     ph = _PHASE_HOOK
     if ph is not None:
         _phase_guarded(ph, site, name, device, "b")
@@ -217,9 +286,14 @@ def stage(site: str, name: str, device: str = "-"):
         if ph is not None:
             _phase_guarded(ph, site, name, device, "e")
         return
+    ann = _annotation
+    if ann is _UNRESOLVED:
+        ann = _resolve_annotation()
+    label = f"{site}.{name}"
     t0 = _timer()
-    with telemetry.span(f"{site}.{name}", stage=name, device=device) as sid:
-        yield sid
+    with _NULLCTX if ann is None else ann(label):
+        with telemetry.span(label, stage=name, device=device) as sid:
+            yield sid
     dt = _timer() - t0
     _STAGE_SECONDS.labels(site=site, stage=name, device=device).observe(
         dt, exemplar={"span_id": sid}
@@ -309,23 +383,6 @@ def sync(tree) -> None:
     except Exception:  # noqa: BLE001 — a profiler barrier must never
         # fail a dispatch the breaker would otherwise see succeed.
         log.debug("block_until_ready failed under profiling", exc_info=True)
-
-
-def annotation(name: str):
-    """``jax.profiler.TraceAnnotation`` on a real TPU (the phases then
-    appear in XLA's own profiler timeline), a null context elsewhere."""
-    from contextlib import nullcontext
-
-    if not _enabled:
-        return nullcontext()
-    try:
-        import jax
-
-        if jax.default_backend() == "tpu":
-            return jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001 — best-effort on exotic backends
-        log.debug("profiler annotation unavailable", exc_info=True)
-    return nullcontext()
 
 
 def record_cost(site: str, jitfn, *args, shape_sig: tuple = ()) -> dict | None:

@@ -23,6 +23,7 @@ import itertools
 import logging
 import time
 from collections import deque
+from contextlib import nullcontext
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
@@ -44,6 +45,22 @@ def set_delivery_context(fn) -> None:
     their own as long as they restore the previous value."""
     global _DELIVERY_CONTEXT
     _DELIVERY_CONTEXT = fn
+
+
+# Delivery-stage hook (the profiler's per-delivery host span): armed by
+# :func:`holo_tpu.telemetry.profiling.set_device_profiling`, which sets
+# it to ``profiling.stage``; every delivery then runs inside
+# ``stage("loop", <actor's registered name>)``.  Same shape as the seam
+# above: None (the default) costs one module-global check per delivery.
+_DELIVERY_STAGE = None
+_NO_CONTEXT = nullcontext()
+
+
+def set_delivery_stage(fn) -> None:
+    """Install/clear the delivery-stage hook (``fn(site, name) ->
+    context manager``)."""
+    global _DELIVERY_STAGE
+    _DELIVERY_STAGE = fn
 
 
 class RealClock:
@@ -371,7 +388,13 @@ class EventLoop:
                     raise InjectedCrash(msg.reason)
                 hook = _DELIVERY_CONTEXT
                 ctx = hook(msg) if hook is not None else None
-                if ctx is None:
+                stage = _DELIVERY_STAGE
+                if stage is not None:
+                    if ctx is None:
+                        ctx = _NO_CONTEXT
+                    with stage("loop", name), ctx:
+                        actor.handle(msg)
+                elif ctx is None:
                     actor.handle(msg)
                 else:
                     with ctx:

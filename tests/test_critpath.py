@@ -145,19 +145,28 @@ def test_per_key_ordering_stall_books_to_queue_wait():
     convergence.configure(256)
     cp = critpath.configure(check_every=0)
     pipe = DispatchPipeline(depth=2, name="cp-stall")
-    gate = threading.Event()
+    gate, queued = threading.Event(), threading.Event()
     try:
         e1 = convergence.begin("lsa")
         with convergence.activation((e1,)):
             t1 = pipe.submit(
                 "k", "spf",
-                launch=lambda: "h",
+                # e1 stays in launch until e2 is queued behind it, so the
+                # worker's next scan finds e2 with its key in flight.
+                launch=lambda: queued.wait(5.0) and "h",
                 finish=lambda h: gate.wait(5.0) and "v1",
             )
         e2 = convergence.begin("lsa")
         with convergence.activation((e2,)):
             t2 = pipe.submit("k", "spf", run=lambda: "v2")
-        time.sleep(0.15)  # worker: e1 in flight, e2 latched stalled
+        queued.set()
+        # Wait for the worker to latch e2 stalled (the ledger's open
+        # record shows it), then hold the gate: queue_wait >= 0.1 by
+        # construction, whatever the scheduler does under xdist.
+        deadline = time.monotonic() + 5.0
+        while cp._recs[e2].stalls < 1 and time.monotonic() < deadline:
+            time.sleep(0.005)
+        time.sleep(0.12)
         gate.set()
         assert t1.result(5.0) == "v1"
         assert t2.result(5.0) == "v2"
