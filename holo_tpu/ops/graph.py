@@ -21,6 +21,8 @@ from typing import NamedTuple
 
 import numpy as np
 
+from holo_tpu import telemetry
+
 # Distances are exact int32.  Valid path costs are bounded by
 # n_vertices * 65535 < 2**30 for any topology we accept, so INF is safe from
 # overflow as long as candidate sums are masked before the add (see sssp.py).
@@ -531,6 +533,80 @@ def bandwidth_permutation(
     return order[::-1].astype(np.int32)
 
 
+_DIFF_TOTAL = telemetry.counter(
+    "holo_spf_delta_diff_total",
+    "diff_topologies calls by the path that answered: the pure-weight "
+    "fast path, the general edge multiset diff, or a refusal (None)",
+    ("path",),
+)
+
+
+def _pack_i32_pairs(hi: np.ndarray, lo: np.ndarray) -> np.ndarray:
+    """One int64 key per (hi, lo) int32 pair whose signed order is the
+    lexicographic signed order of the pairs: exact for every int32
+    (``lo`` is biased into [0, 2**32), so atom -1 sorts before 0)."""
+    return (hi.astype(np.int64) << 32) + (lo.astype(np.int64) + (1 << 31))
+
+
+def _unpack_i32_pairs(key: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """Inverse of :func:`_pack_i32_pairs`."""
+    hi = (key >> 32).astype(np.int32)
+    lo = ((key & 0xFFFFFFFF) - (1 << 31)).astype(np.int32)
+    return hi, lo
+
+
+def _edge_multiset_diff(
+    base: Topology, new: Topology
+) -> tuple[tuple[np.ndarray, ...], tuple[np.ndarray, ...]]:
+    """``(removed, added)`` rows ``(src, dst, cost, atom)`` of the
+    multiset difference of two edge lists, each side in lexicographic
+    row order with multiplicities repeated.
+
+    1-D sorts over packed keys (ISSUE 26), never a comparison sort of
+    16-byte rows: ``k1 = (src, dst)`` and ``k2 = (cost, atom)`` as
+    int64.  One stable argsort by ``k1`` puts a row of ``base`` next to
+    its unchanged twin in ``new`` (LSDB builders emit both lists in the
+    same src-grouped order, so the sort is close to a merge); such
+    adjacent base/new pairs with equal keys cancel — removing one equal
+    element from each multiset leaves their difference as it was.  The
+    few rows left are lex-sorted on ``(k1, k2)`` and signed-counted per
+    distinct row.
+    """
+    nb = base.n_edges
+    k1 = np.concatenate([
+        _pack_i32_pairs(base.edge_src, base.edge_dst),
+        _pack_i32_pairs(new.edge_src, new.edge_dst),
+    ])
+    k2 = np.concatenate([
+        _pack_i32_pairs(base.edge_cost, base.edge_direct_atom),
+        _pack_i32_pairs(new.edge_cost, new.edge_direct_atom),
+    ])
+    order = np.argsort(k1, kind="stable")
+    s1, s2, is_new = k1[order], k2[order], order >= nb
+    twin = (
+        ~is_new[:-1] & is_new[1:]
+        & (s1[:-1] == s1[1:]) & (s2[:-1] == s2[1:])
+    )
+    unpaired = np.ones(order.shape[0], bool)
+    unpaired[:-1] &= ~twin
+    unpaired[1:] &= ~twin
+    left = np.flatnonzero(unpaired)
+    s1, s2, is_new = s1[left], s2[left], is_new[left]
+    order = np.lexsort((s2, s1))
+    s1, s2 = s1[order], s2[order]
+    sign = np.where(is_new[order], -1, 1)
+    first = np.ones(order.shape[0], bool)
+    first[1:] = (s1[1:] != s1[:-1]) | (s2[1:] != s2[:-1])
+    starts = np.flatnonzero(first)
+    count = np.add.reduceat(sign, starts)
+
+    def rows(times: np.ndarray) -> tuple[np.ndarray, ...]:
+        at = np.repeat(starts, times)
+        return (*_unpack_i32_pairs(s1[at]), *_unpack_i32_pairs(s2[at]))
+
+    return rows(np.maximum(count, 0)), rows(np.maximum(-count, 0))
+
+
 def diff_topologies(
     base: Topology, new: Topology, max_ops: int = 512
 ) -> TopologyDelta | None:
@@ -544,7 +620,25 @@ def diff_topologies(
     router/network index maps) and the same next-hop atom table —
     :func:`holo_tpu.protocols.ospf.spf_run.link_spf_delta` checks that
     before calling here.
+
+    Every call lands once in ``holo_spf_delta_diff_total{path}``:
+    ``weights`` (identical edge list, costs differ), ``edges`` (the
+    general multiset diff) or ``refused`` (None returned).
     """
+    delta = _diff_topologies(base, new, max_ops)
+    # ids_stable is set by the fast path and by no other.
+    path = (
+        "refused" if delta is None
+        else "weights" if delta.ids_stable else "edges"
+    )
+    _DIFF_TOTAL.labels(path=path).inc()
+    return delta
+
+
+def _diff_topologies(
+    base: Topology, new: Topology, max_ops: int
+) -> TopologyDelta | None:
+    """:func:`diff_topologies` without the counter."""
     if (
         base.n_vertices != new.n_vertices
         or base.root != new.root
@@ -585,33 +679,16 @@ def diff_topologies(
     # lower bound on the op count.
     if abs(base.n_edges - new.n_edges) > max_ops:
         return None
-
-    def rows(t: Topology) -> np.ndarray:
-        out = np.empty((t.n_edges, 4), np.int32)
-        out[:, 0] = t.edge_src
-        out[:, 1] = t.edge_dst
-        out[:, 2] = t.edge_cost
-        out[:, 3] = t.edge_direct_atom
-        return out
-
-    # Vectorized multiset diff (this runs on the per-SPF hot path for
-    # exactly the large topologies DeltaPath targets — no Python loop
-    # over E): signed-count the lex-sorted union of both edge lists.
-    both = np.concatenate([rows(base), rows(new)], axis=0)
-    uniq, inv = np.unique(both, axis=0, return_inverse=True)
-    count = np.zeros(uniq.shape[0], np.int64)
-    np.add.at(count, inv[: base.n_edges], 1)
-    np.add.at(count, inv[base.n_edges:], -1)
-    rem_mask = count > 0
-    add_mask = count < 0
-    n_ops = int(count[rem_mask].sum() - count[add_mask].sum())
-    if n_ops > max_ops:
+    # Vectorized (this runs on the per-SPF hot path for exactly the
+    # large topologies DeltaPath targets — no Python loop over E), and
+    # in the row order ``np.unique(axis=0)`` gave before ISSUE 26:
+    # _lower_delta hands out ELL slots in the order additions arrive.
+    r, a = _edge_multiset_diff(base, new)
+    if r[0].shape[0] + a[0].shape[0] > max_ops:
         return None
-    r = np.repeat(uniq[rem_mask], count[rem_mask], axis=0)
-    a = np.repeat(uniq[add_mask], -count[add_mask], axis=0)
     return TopologyDelta(
         base_key=base.cache_key,
-        r_src=r[:, 0], r_dst=r[:, 1], r_cost=r[:, 2], r_atom=r[:, 3],
-        a_src=a[:, 0], a_dst=a[:, 1], a_cost=a[:, 2], a_atom=a[:, 3],
+        r_src=r[0], r_dst=r[1], r_cost=r[2], r_atom=r[3],
+        a_src=a[0], a_dst=a[1], a_cost=a[2], a_atom=a[3],
         ids_stable=False,
     )

@@ -364,9 +364,13 @@ def link_spf_delta(
     by a small edge-level change over the SAME vertex model and
     next-hop atom table.  The device-graph cache then updates the
     resident EllGraph in place and the TPU backend recomputes
-    incrementally instead of re-marshaling the whole LSDB (ROADMAP
-    item 1).  Returns whether lineage was attached; False always means
-    the full-rebuild path, never an error."""
+    incrementally instead of re-marshaling the whole LSDB (DeltaPath,
+    ISSUE 7).  The equality guards below make vertex and atom ids
+    mean the same thing in both topologies, which is all
+    ``diff_topologies`` (a packed-key 1-D diff since ISSUE 26) compares;
+    the ``ospf.spf.link`` stage times this whole function.  Returns whether
+    lineage was attached; False always means the full-rebuild path,
+    never an error."""
     if prev is None:
         return False
     if (
