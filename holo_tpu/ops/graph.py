@@ -204,17 +204,19 @@ def build_ell(
     k_pad: int | None = None,
     n_atoms: int = 64,
     k_multiple: int = 8,
+    k_min: int = 0,
 ) -> EllGraph:
     """Pack a :class:`Topology` into the ELL in-edge layout.
 
     ``k_pad`` defaults to max in-degree rounded up to ``k_multiple`` (shape
-    bucketing keeps XLA recompiles rare under LSA churn).
+    bucketing keeps XLA recompiles rare under LSA churn) and to no less
+    than ``k_min`` (the width of the resident a re-marshal replaces).
     """
     n = topo.n_vertices
     counts = np.bincount(topo.edge_dst, minlength=n)
     kmax = int(counts.max()) if topo.n_edges else 1
     if k_pad is None:
-        k_pad = max(_round_up(max(kmax, 1), k_multiple), k_multiple)
+        k_pad = max(_round_up(max(kmax, 1), k_multiple), k_multiple, k_min)
     elif kmax > k_pad:
         raise ValueError(f"k_pad={k_pad} < max in-degree {kmax}")
     if topo.n_atoms() > n_atoms:
@@ -533,11 +535,27 @@ def bandwidth_permutation(
     return order[::-1].astype(np.int32)
 
 
+#: The most edge operations one :class:`TopologyDelta` carries: a larger
+#: change is refused (a full re-marshal is the cheaper path anyway).
+#: The delta scatter and the incremental kernel's seed rows are padded
+#: to exactly this many rows (``ops/spf_engine.py _DELTA_PAD_FLOOR``),
+#: so every delta this module can return rides ONE compiled program
+#: pair per graph shape.
+DELTA_MAX_OPS = 512
+
 _DIFF_TOTAL = telemetry.counter(
     "holo_spf_delta_diff_total",
     "diff_topologies calls by the path that answered: the pure-weight "
     "fast path, the general edge multiset diff, or a refusal (None)",
     ("path",),
+)
+_DIFF_OPS = telemetry.histogram(
+    "holo_spf_delta_ops",
+    "edge operations of each delta diff_topologies returned or refused "
+    "for its size (the early edge-count refusal observes the gap, a "
+    "lower bound; a refusal for another vertex model has no count and "
+    "is not observed)",
+    buckets=(0, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 4096),
 )
 
 
@@ -608,7 +626,7 @@ def _edge_multiset_diff(
 
 
 def diff_topologies(
-    base: Topology, new: Topology, max_ops: int = 512
+    base: Topology, new: Topology, max_ops: int = DELTA_MAX_OPS
 ) -> TopologyDelta | None:
     """Compute a :class:`TopologyDelta` taking ``base`` to ``new``, or
     None when the change is not delta-representable (different vertex
@@ -623,9 +641,12 @@ def diff_topologies(
 
     Every call lands once in ``holo_spf_delta_diff_total{path}``:
     ``weights`` (identical edge list, costs differ), ``edges`` (the
-    general multiset diff) or ``refused`` (None returned).
+    general multiset diff) or ``refused`` (None returned), and its
+    operation count once in the histogram ``holo_spf_delta_ops``.
     """
-    delta = _diff_topologies(base, new, max_ops)
+    delta, n_ops = _diff_topologies(base, new, max_ops)
+    if n_ops is not None:
+        _DIFF_OPS.observe(n_ops)
     # ids_stable is set by the fast path and by no other.
     path = (
         "refused" if delta is None
@@ -637,14 +658,15 @@ def diff_topologies(
 
 def _diff_topologies(
     base: Topology, new: Topology, max_ops: int
-) -> TopologyDelta | None:
-    """:func:`diff_topologies` without the counter."""
+) -> tuple[TopologyDelta | None, int | None]:
+    """:func:`diff_topologies` without the counters: ``(delta or None,
+    the operations counted, None where nothing was counted)``."""
     if (
         base.n_vertices != new.n_vertices
         or base.root != new.root
         or not np.array_equal(base.is_router, new.is_router)
     ):
-        return None
+        return None, None
     # A changed native partition hint changes the cut geometry the
     # partitioned-SPF resident was planned over (ISSUE 15) — not
     # delta-representable; re-marshal.
@@ -652,7 +674,7 @@ def _diff_topologies(
     if (bh is None) != (nh is None) or (
         bh is not None and not np.array_equal(bh, nh)
     ):
-        return None
+        return None, None
     if base.n_edges == new.n_edges and (
         np.array_equal(base.edge_src, new.edge_src)
         and np.array_equal(base.edge_dst, new.edge_dst)
@@ -661,8 +683,9 @@ def _diff_topologies(
         # Fast path: identical edge list (and ordering) — a pure weight
         # delta, edge indices remain valid for mask consumers.
         changed = np.nonzero(base.edge_cost != new.edge_cost)[0]
-        if changed.shape[0] > max_ops:
-            return None
+        n_ops = int(changed.shape[0])
+        if n_ops > max_ops:
+            return None, n_ops
         return TopologyDelta(
             base_key=base.cache_key,
             w_src=base.edge_src[changed].copy(),
@@ -671,24 +694,26 @@ def _diff_topologies(
             w_new=new.edge_cost[changed].copy(),
             w_atom=base.edge_direct_atom[changed].copy(),
             ids_stable=True,
-        )
+        ), n_ops
     # General path: multiset difference over (src, dst, cost, atom)
     # rows.  A moved/re-costed edge shows up as one removal plus one
     # addition — the slot machinery frees then reuses the ELL slot.
     # Cheap early-out before the O(E) work: the edge-count gap is a
     # lower bound on the op count.
-    if abs(base.n_edges - new.n_edges) > max_ops:
-        return None
+    gap = abs(base.n_edges - new.n_edges)
+    if gap > max_ops:
+        return None, gap
     # Vectorized (this runs on the per-SPF hot path for exactly the
     # large topologies DeltaPath targets — no Python loop over E), and
     # in the row order ``np.unique(axis=0)`` gave before ISSUE 26:
     # _lower_delta hands out ELL slots in the order additions arrive.
     r, a = _edge_multiset_diff(base, new)
-    if r[0].shape[0] + a[0].shape[0] > max_ops:
-        return None
+    n_ops = int(r[0].shape[0] + a[0].shape[0])
+    if n_ops > max_ops:
+        return None, n_ops
     return TopologyDelta(
         base_key=base.cache_key,
         r_src=r[0], r_dst=r[1], r_cost=r[2], r_atom=r[3],
         a_src=a[0], a_dst=a[1], a_cost=a[2], a_atom=a[3],
         ids_stable=False,
-    )
+    ), n_ops

@@ -35,7 +35,13 @@ import numpy as np
 
 from holo_tpu import telemetry
 from holo_tpu.analysis.runtime import note_donated
-from holo_tpu.ops.graph import INF, MP_SAT, EllGraph, TopologyDelta
+from holo_tpu.ops.graph import (
+    DELTA_MAX_OPS,
+    INF,
+    MP_SAT,
+    EllGraph,
+    TopologyDelta,
+)
 
 # Host-side marshal metrics: every DeviceGraph build reports how long
 # the ELL expansion took and how much of the padded slot space is real
@@ -305,12 +311,18 @@ def _apply_tiles_for(mesh) -> object:
     return fn
 
 
-#: One fixed scatter/seed bucket for the common case: every delta pads
-#: to this many rows (out-of-range sentinels drop), so a process
-#: compiles the apply + incremental-kernel pair ONCE per graph shape —
-#: bucket churn would otherwise put one XLA compile spike per novel
-#: delta size into the storm tail the p95 acceptance gate watches.
-_DELTA_PAD_FLOOR = 256
+#: THE scatter/seed bucket: every delta pads to this many rows
+#: (out-of-range sentinels drop).  It equals ``DELTA_MAX_OPS``, the
+#: most operations ``diff_topologies`` puts into one delta (a delta of
+#: n operations touches at most n slots and seeds at most n rows), so
+#: a process compiles the apply + incremental-kernel pair ONCE per
+#: graph shape for every delta the LSDB seam can link — a failed
+#: degree-96 router (192 edge operations) coalesced with a fibre cut
+#: rides the program a one-link flap compiled, where a 256-row floor
+#: put a second pair's XLA compile into the middle of the storm.
+#: Larger inputs (a caller's own ``max_ops``, an IS-IS overload strike
+#: seeding many rows) still double from here.
+_DELTA_PAD_FLOOR = DELTA_MAX_OPS
 
 
 def _pad_pow2(n: int, floor: int = _DELTA_PAD_FLOOR) -> int:
@@ -419,6 +431,18 @@ class DeviceGraphCache:
     structurally-updated entry (stale edge ids) all fall back to the
     full-rebuild path (``holo_spf_delta_total{kind,path}``).
 
+    Compile shapes under churn (ISSUE 27).  Every delta
+    ``diff_topologies`` returns pads to ONE bucket of ``DELTA_MAX_OPS``
+    rows (``_DELTA_PAD_FLOOR``), so the apply + incremental pair is
+    compiled once per graph shape.  A full re-marshal keeps the shapes
+    of the resident it replaces: the ELL width of a (vertex count,
+    root, atoms, mesh) only ever grows (``_k_pad_floor``), because
+    ``build_ell`` alone would take it from the largest in-degree of
+    the moment, which a failed hub lowers — a narrower resident is a
+    new set of programs.  And the mask-free full-SPF program
+    (``TpuSpfBackend._device_compute``) is keyed on the resident's
+    shapes alone, not on the edge count, which moves with every flap.
+
     Thread-shared under ``[runtime] isolation=threaded`` (instance
     threads dispatch concurrently): lookups and inserts run under an
     owning lock; the expensive ELL expansion runs outside it, so two
@@ -448,6 +472,9 @@ class DeviceGraphCache:
         self._cache: dict[tuple, _CacheEntry] = {}
         self._evictions = 0
         self._deltas_applied = 0
+        # Widest ELL built per (n_vertices, root, n_atoms, mesh): the
+        # floor of the next full marshal's width (class docstring).
+        self._k_pad_floor: dict[tuple, int] = {}
         # Partitioned-SPF residents (ISSUE 15): stacked per-partition
         # plane sets (ops/partition.PartResident) ride the SAME shared
         # cache — one lock discipline, one LRU/eviction surface — in a
@@ -560,9 +587,7 @@ class DeviceGraphCache:
                 _MARSHAL_CACHE.labels(result="delta").inc()
                 return g, "delta"
         _MARSHAL_CACHE.labels(result="miss").inc()
-        from holo_tpu.ops.graph import build_ell
-
-        ell = build_ell(topo, n_atoms=n_atoms)
+        ell = self._build_ell(topo, int(n_atoms), mkey)
         g = device_graph_from_ell(ell)
         if mesh is not None:
             from holo_tpu.parallel.mesh import shard_graph
@@ -582,6 +607,24 @@ class DeviceGraphCache:
             self._cache[key] = entry
             self._evict_locked()
         return g, "miss"
+
+    def _build_ell(self, topo, n_atoms: int, mkey) -> EllGraph:
+        """``build_ell`` at no less than the widest ELL this cache has
+        built for the same vertex count, root, atoms and mesh: the
+        resident a mid-chain re-marshal builds has the shapes of the
+        one it replaces whenever the graph fits them."""
+        from holo_tpu.ops.graph import build_ell
+
+        fkey = (topo.n_vertices, int(topo.root), n_atoms, mkey)
+        with self._lock:
+            floor = self._k_pad_floor.get(fkey, 0)
+        ell = build_ell(topo, n_atoms=n_atoms, k_min=floor)
+        with self._lock:
+            self._k_pad_floor.pop(fkey, None)  # newest last
+            self._k_pad_floor[fkey] = ell.k_pad
+            while len(self._k_pad_floor) > 4 * self.capacity:
+                self._k_pad_floor.pop(next(iter(self._k_pad_floor)))
+        return ell
 
     def _try_delta(
         self, topo, n_atoms: int, need_edge_ids: bool
@@ -821,6 +864,7 @@ class DeviceGraphCache:
         with self._lock:
             self._cache.clear()
             self._part.clear()
+            self._k_pad_floor.clear()
 
 
 _SHARED_GRAPH_CACHE = DeviceGraphCache()
@@ -834,8 +878,10 @@ def shared_graph_cache() -> DeviceGraphCache:
 def _slot_mask(g: DeviceGraph, edge_mask: jax.Array | None) -> jax.Array:
     """bool[N,K]: usable in-edge slots under the scenario's edge mask."""
     ok = g.in_valid
-    # Skip the gather for edgeless graphs (shape is static under trace);
-    # every slot is already invalid in that case.
+    # An empty mask is "no mask" (shape is static under trace): no
+    # gather through in_edge_id.  The mask-free full SPF passes it so
+    # that its program does not depend on the edge count (ISSUE 27);
+    # on an edgeless graph every slot is already invalid.
     if edge_mask is not None and edge_mask.shape[0] > 0:
         ok = ok & edge_mask[g.in_edge_id]
     return ok

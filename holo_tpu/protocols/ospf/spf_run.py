@@ -19,7 +19,7 @@ from ipaddress import IPv4Address, IPv4Network
 
 import numpy as np
 
-from holo_tpu.ops.graph import INF, Topology
+from holo_tpu.ops.graph import DELTA_MAX_OPS, INF, Topology
 from holo_tpu.protocols.ospf.lsdb import Lsdb
 from holo_tpu.protocols.ospf.packet import (
     LsaNetwork,
@@ -357,7 +357,8 @@ def build_topology(
 
 
 def link_spf_delta(
-    prev: SpfTopology | None, new: SpfTopology, max_ops: int = 512
+    prev: SpfTopology | None, new: SpfTopology,
+    max_ops: int = DELTA_MAX_OPS,
 ) -> bool:
     """DeltaPath construction at the LSDB seam: attach delta lineage to
     ``new`` when it differs from the previous run's marshaled topology

@@ -52,6 +52,8 @@ from holo_tpu.ops.tropical import (
 
 #: engine names that dispatch through the tropical tile planes
 _TROPICAL_ENGINES = ("tropical", "mp_tropical")
+#: "no scenario mask" as a jit operand: see ``TpuSpfBackend._one_mask``
+_NO_MASK = np.zeros(0, bool)
 from holo_tpu.spf.scalar import spf_multipath_reference, spf_reference
 from holo_tpu.telemetry import convergence, profiling
 
@@ -961,6 +963,19 @@ class TpuSpfBackend(SpfBackend):
             return np.ones(topo.n_edges, bool)
         return np.asarray(edge_mask, bool)
 
+    @staticmethod
+    def _one_mask(edge_mask) -> np.ndarray:
+        """The mask operand of one full single-SPF dispatch.  A real
+        scenario mask is ``bool[E]`` and keys its program on E.  The
+        mask-free call (the protocol instance's) passes the empty mask,
+        which the engines read as "every valid slot" with no gather
+        (``_slot_mask``): its program is keyed on the resident's shapes
+        alone, so a full re-marshal under churn — E moves with every
+        flap — reuses the program the first SPF compiled (ROADMAP S2)."""
+        if edge_mask is None:
+            return _NO_MASK
+        return np.asarray(edge_mask, bool)
+
     # Public entry points run under the circuit breaker: an XLA failure
     # or deadline overrun transparently re-runs the batch on the scalar
     # oracle (RIB output unchanged by construction — the parity suites
@@ -1235,7 +1250,7 @@ class TpuSpfBackend(SpfBackend):
                         topo, need_edge_ids=edge_mask is not None
                     )
                     remarshal = self._last_prepare_how == "miss"
-                    mask = self._full_mask(topo, edge_mask)
+                    mask = self._one_mask(edge_mask)
                     tt = rr = None
                     if engine in _TROPICAL_ENGINES:
                         tt, rr = self._trop_operands(topo, g, edge_mask)
@@ -1244,7 +1259,7 @@ class TpuSpfBackend(SpfBackend):
                     )
                     sig = (
                         g.in_src.shape, g.direct_nh_words.shape[2],
-                        topo.n_edges, _mesh_key(), engine, kp,
+                        mask.shape[0], _mesh_key(), engine, kp,
                         None if tt is None else tt.tiles.shape,
                         None if rr is None else rr.shape,
                     )
@@ -1739,7 +1754,7 @@ class TpuSpfBackend(SpfBackend):
                         topo, need_edge_ids=edge_mask is not None
                     )
                     remarshal = self._last_prepare_how == "miss"
-                    mask = self._full_mask(topo, edge_mask)
+                    mask = self._one_mask(edge_mask)
                     tt = rr = None
                     if engine in _TROPICAL_ENGINES:
                         tt, rr = self._trop_operands(topo, g, edge_mask)
@@ -1748,7 +1763,7 @@ class TpuSpfBackend(SpfBackend):
                     )
                     sig = (
                         g.in_src.shape, g.direct_nh_words.shape[2],
-                        topo.n_edges, _mesh_key(), engine, kp,
+                        mask.shape[0], _mesh_key(), engine, kp,
                         None if tt is None else tt.tiles.shape,
                         None if rr is None else rr.shape,
                     )
