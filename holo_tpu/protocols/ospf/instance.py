@@ -93,6 +93,7 @@ from holo_tpu.protocols.ospf.spf_run import (
     build_topology,
     derive_routes,
     link_spf_delta,
+    reachable_router_flags,
 )
 from holo_tpu.spf.backend import ScalarSpfBackend, SpfBackend
 from holo_tpu.telemetry import convergence, profiling
@@ -2878,17 +2879,9 @@ class OspfInstance(Actor):
             # computation — NOT the live LSDB, which may have changed
             # since, e.g. right after a clear-database RPC).
             with profiling.stage("ospf.spf", "derive"):
-                from holo_tpu.ops.graph import INF as _INF
-
-                flags_now = {}
-                for key, e in area.lsdb.entries.items():
-                    if key.type == LsaType.ROUTER and not e.lsa.is_maxage:
-                        flags_now[key.adv_rtr] = e.lsa.body.flags
-                self._area_reachable_routers[area.area_id] = {
-                    rid: flags_now.get(rid, RouterFlags(0))
-                    for rid, v in st.router_index.items()
-                    if res.dist[v] < _INF
-                }
+                self._area_reachable_routers[area.area_id] = (
+                    reachable_router_flags(st, res, area.lsdb)
+                )
                 intra = derive_routes(
                     st, res, area.lsdb, now, area.area_id,
                     max_paths=self.config.max_paths,

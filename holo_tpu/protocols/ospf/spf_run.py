@@ -19,12 +19,14 @@ from ipaddress import IPv4Address, IPv4Network
 
 import numpy as np
 
+from holo_tpu import telemetry
 from holo_tpu.ops.graph import DELTA_MAX_OPS, INF, Topology
 from holo_tpu.protocols.ospf.lsdb import Lsdb
 from holo_tpu.protocols.ospf.packet import (
     LsaNetwork,
     LsaRouter,
     LsaType,
+    RouterFlags,
     RouterLinkType,
 )
 from holo_tpu.spf.backend import SpfResult
@@ -418,13 +420,29 @@ class IntraRoute:
     nh_weights: dict | None = None
 
 
+_DERIVE_NEXTHOPS = telemetry.counter(
+    "holo_ospf_derive_nexthops_total",
+    "derive_routes' prefix offers by how the offering vertex's next-hop "
+    "set was had: decoded from its bitmask row (once per distinct row "
+    "and call), or reused from a row already decoded in that call",
+    ("path",),
+)
+
+
 def atom_bits(words: np.ndarray, n_atoms: int) -> list[int]:
-    """Indices of set bits in an ECMP atom bitmask (uint32 words)."""
-    return [
-        a
-        for a in range(n_atoms)
-        if words[a // 32] & (np.uint32(1) << np.uint32(a % 32))
-    ]
+    """Indices of set bits in an ECMP atom bitmask (uint32 words).
+
+    The words fold into one Python int (word ``i`` holds atoms
+    ``32 i .. 32 i + 31``) and its set bits are walked lowest first: the
+    cost follows the bits that are set, not the number of atoms."""
+    bits = int.from_bytes(np.asarray(words, "<u4").tobytes(), "little")
+    bits &= (1 << n_atoms) - 1
+    out = []
+    while bits:
+        low = bits & -bits
+        out.append(low.bit_length() - 1)
+        bits ^= low
+    return out
 
 
 def _atoms_of(words: np.ndarray, atoms: list[NexthopAtom]) -> frozenset[RouteNexthop]:
@@ -491,6 +509,27 @@ def clamp_multipath(routes: dict, max_paths: int | None) -> int:
     return clamped
 
 
+def reachable_router_flags(
+    st: SpfTopology, res: SpfResult, lsdb: Lsdb
+) -> dict[IPv4Address, RouterFlags]:
+    """Routers this SPF run reached, each with the flags of its
+    Router-LSA as of the run (``RouterFlags(0)`` without a live one):
+    operational state counts ABRs and ASBRs from these, not from the
+    live LSDB (reference area.rs:164-182)."""
+    flags = {
+        key.adv_rtr: e.lsa.body.flags
+        for key, e in lsdb.entries.items()
+        if key.type == LsaType.ROUTER and not e.lsa.is_maxage
+    }
+    dist = res.dist.tolist()
+    no_flags = RouterFlags(0)
+    return {
+        rid: flags.get(rid, no_flags)
+        for rid, v in st.router_index.items()
+        if dist[v] < INF
+    }
+
+
 def derive_routes(
     st: SpfTopology,
     res: SpfResult,
@@ -548,33 +587,53 @@ def derive_routes(
     # Per-vertex UCMP weights ride the multipath planes when the
     # dispatch carried them (max-paths > 1 → multipath kernel).
     nhw = getattr(res, "nh_weights", None)
-    n = st.topo.n_vertices
-    for v in range(n):
-        if res.dist[v] >= INF:
+    # The planes as Python values once.  A 10,000-vertex area holds a
+    # handful of distinct bitmask rows (the root has few atoms), so a
+    # next-hop set is decoded once per distinct row, keyed by the row's
+    # bytes, and only when a vertex that offers a prefix asks for it.
+    # The shared frozenset is immutable: offer's union and the clamp
+    # rebind, they never mutate.
+    dist = res.dist.tolist()
+    words = res.nexthop_words
+    stride = words.shape[1] * words.itemsize
+    rows = words.tobytes()  # C order, whatever the plane's layout
+    decoded: dict[bytes, frozenset] = {}
+    offers = 0
+    for v in range(st.topo.n_vertices):
+        if dist[v] >= INF:
             continue
-        nhs = _atoms_of(res.nexthop_words[v], st.atoms)
-        weights = (
-            _atom_weights_of(res.nexthop_words[v], nhw[v], st.atoms)
-            if nhw is not None
-            else None
-        )
-        if v in inv_net:
-            body = nlsa.get(inv_net[v])
+        net = inv_net.get(v)
+        if net is not None:
+            body = nlsa.get(net)
             if body is None:
                 continue
-            prefix = apply_mask(inv_net[v], body.mask)
-            offer(prefix, int(res.dist[v]), nhs, vertex=v, weights=weights)
+            offered = [(apply_mask(net, body.mask), dist[v])]
         else:
             body = rlsa.get(inv_rtr[v])
             if body is None:
                 continue
-            for link in body.links:
-                if link.link_type == RouterLinkType.STUB_NETWORK:
-                    prefix = apply_mask(link.id, link.data)
-                    offer(
-                        prefix, int(res.dist[v]) + link.metric, nhs,
-                        vertex=v, weights=weights,
-                    )
+            offered = [
+                (apply_mask(link.id, link.data), dist[v] + link.metric)
+                for link in body.links
+                if link.link_type == RouterLinkType.STUB_NETWORK
+            ]
+        if not offered:
+            continue
+        row = rows[v * stride:(v + 1) * stride]
+        nhs = decoded.get(row)
+        if nhs is None:
+            nhs = decoded[row] = _atoms_of(words[v], st.atoms)
+        # A vertex's weights are its own nhw row's: not shared by mask.
+        weights = (
+            _atom_weights_of(words[v], nhw[v], st.atoms)
+            if nhw is not None
+            else None
+        )
+        for prefix, cost in offered:
+            offer(prefix, cost, nhs, vertex=v, weights=weights)
+        offers += len(offered)
+    _DERIVE_NEXTHOPS.labels(path="decoded").inc(len(decoded))
+    _DERIVE_NEXTHOPS.labels(path="reused").inc(offers - len(decoded))
     clamp_multipath(routes, max_paths)
     return routes
 
