@@ -32,6 +32,12 @@ import json
 import sys
 import time
 
+from benchmark.witness import (
+    FallbackWitness as BenchWitness,
+    SetupClock,
+    device_info,
+)
+
 SEED = 9
 
 
@@ -42,17 +48,6 @@ class SmokeFailure(AssertionError):
 def _require(cond, msg: str) -> None:
     if not cond:
         raise SmokeFailure(msg)
-
-
-def device_info() -> dict:
-    import jax
-
-    devs = jax.devices()
-    return {
-        "platform": devs[0].platform,
-        "kind": devs[0].device_kind,
-        "count": len(devs),
-    }
 
 
 def _versions() -> dict:
@@ -79,66 +74,21 @@ def _compiles() -> int:
     return _total("holo_spf_jit_compiles_total")
 
 
-def _fallbacks() -> int:
-    return _total("holo_resilience_fallback_total")
-
-
-class SetupClock:
-    """Seconds XLA spent compiling — or JAX spent fetching the program
-    from the persistent cache instead — since construction: the run's
-    set-up time, and how much of it the cache served.  (Trace and lower
-    events nest inside one another and would count twice.)"""
-
-    def __init__(self):
-        import jax.monitoring
-
-        self.seconds = 0.0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event: str, seconds: float, **_kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += seconds
-
-    def _event(self, event: str, **_kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-
-class FallbackWitness:
-    """Turns the dispatch breaker from a mask into a witness.
-
-    Snapshots the fallback counter and the live breakers at
-    construction (both empty in a fresh process); :meth:`check` fails
-    if any dispatch since was served by the scalar oracle.  The counter
-    is the authority: a failed device dispatch moves it even where the
-    storm report's split still reads ``device``.
+class FallbackWitness(BenchWitness):
+    """The benchmark's witness with a verdict that raises: :meth:`check`
+    fails the smoke if any dispatch since construction was served by
+    the scalar oracle.  The counter is the authority: a failed device
+    dispatch moves it even where the storm report's split still reads
+    ``device``.
     """
 
-    def __init__(self):
-        from holo_tpu.resilience.breaker import breakers
-
-        self._base = _fallbacks()
-        self._old = set(breakers())
-
     def check(self, stage: str, report: dict | None = None) -> dict:
-        from holo_tpu.resilience.breaker import breakers
-
-        live = {
-            n: b.snapshot() for n, b in breakers().items()
-            if n not in self._old
-        }
-        bad = {
-            n: s for n, s in live.items()
-            if s["state"] != "closed" or s["consecutive-failures"]
-        }
-        fell = _fallbacks() - self._base
-        errors = {n: s["last-error"] for n, s in live.items() if s["last-error"]}
+        seen = super().check()
         _require(
-            fell == 0 and not bad,
-            f"{stage}: {fell} dispatch(es) served by the scalar fallback, "
-            f"breakers not clean: {bad or errors}",
+            seen["clean"],
+            f"{stage}: {seen['fallbacks']} dispatch(es) served by the "
+            f"scalar fallback, breakers not clean: "
+            f"{seen['unclean'] or seen['errors']}",
         )
         if report is not None:
             trig = report["triggers"]
@@ -150,7 +100,7 @@ class FallbackWitness:
                     f"{stage}: trigger {t!r} has no device split: "
                     f"{sorted(trig.get(t, {}))}",
                 )
-        return {"fallbacks": fell, "breakers": len(live)}
+        return {"fallbacks": seen["fallbacks"], "breakers": seen["breakers"]}
 
 
 def stage_daemon() -> dict:
@@ -244,7 +194,6 @@ def stage_storm(n_routers: int, events: int, seed: int = SEED) -> dict:
         "converged": converged,
         "spf_runs": report["spf-runs"],
         "incremental": inc,
-        "dispatch_wall_lsa_s": report["dispatch-wall"].get("lsa"),
         "wall_tpu_arm_s": round(wall_tpu, 3),
         "wall_scalar_arm_s": round(wall_scalar, 3),
     }

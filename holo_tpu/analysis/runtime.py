@@ -29,7 +29,7 @@ import contextlib
 import os
 
 # Observability for the sanctioned windows: how often the dispatch
-# boundary opens tells the bench whether marshal traffic is growing.
+# boundary opens tells a test whether marshal traffic is growing.
 _SANCTIONED: dict[str, int] = {}
 
 

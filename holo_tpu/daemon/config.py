@@ -66,13 +66,17 @@ class TelemetryConfig:
     # Per-dispatch device-time breakdown (marshal / device / readback
     # sub-spans + compile-time FLOP/bytes cost capture).  Off by
     # default: the enabled path adds a block_until_ready barrier per
-    # dispatch (gated < 2% by bench.py profiling_overhead).
+    # dispatch; disarmed, a stage reads no clock
+    # (tests/test_host_stages.py::
+    # test_disarmed_stage_calls_no_factory_and_reads_no_clock), and the
+    # armed cost was read on the chip by PR 25 (PERF.md section 6).
     profile_device_time: bool = False
     # Convergence observatory (ISSUE 6): > 0 arms the causal
     # event→FIB tracker with that many open-event/timeline slots —
     # holo_convergence_seconds{trigger,phase} histograms, causal ids on
     # ibus envelopes, per-event timelines into the flight ring.  Off by
-    # default (gated < 2% by bench.py convergence_overhead).
+    # default; disarmed, every seam is a no-op
+    # (tests/test_convergence.py::test_disarmed_is_noop).
     convergence_events: int = 0
     # Shared-delta gNMI fan-out (ISSUE 11): SAMPLE/ON_CHANGE streams
     # ride ONE per-tick state snapshot + change-set rendered once and
@@ -94,10 +98,11 @@ class TelemetryConfig:
     # sub-span path, roofline attribution against the compile-time
     # cost model, and the warn-only regression sentinel.  Arming it
     # also arms profile-device-time (the observatory feeds off the
-    # sub-span walls).  Gated < 2% by bench.py observatory_overhead.
+    # sub-span walls).  Disarmed it costs one module-global check
+    # (tests/test_observatory.py::test_disarmed_path_is_one_global_check).
     observatory: bool = False
-    # Persisted sentinel baseline (the BENCH_baseline.json discipline:
-    # seed unseen keys, flag >10% drift, ratchet improvements).  None
+    # Persisted sentinel baseline (seed unseen keys, flag >10% drift,
+    # ratchet improvements).  None
     # keeps the ledger in memory only.
     observatory_ledger: str | None = None
     # Roofline peak specs {flops=<per sec>, bytes=<per sec>, name=...};
@@ -110,8 +115,9 @@ class TelemetryConfig:
     # streams.  Objectives come from [[telemetry.slo-objectives]]
     # tables (name, kind, source, quantile, threshold-ms, target);
     # empty = the shipped default set (trigger-fib latency, canary,
-    # background delivery).  Warn-only by contract; gated < 2% by
-    # bench.py slo_overhead.
+    # background delivery).  Warn-only by contract; disarmed, every
+    # seam is one global check
+    # (tests/test_slo.py::test_disarmed_seams_are_one_global_check).
     slo: bool = False
     slo_objectives: tuple = ()
     slo_fast_window: float = 3600.0
@@ -156,7 +162,8 @@ class ParallelConfig:
     # dispatch sharded over it (parallel/mesh.py layout contract).
     # Default: enabled, all devices on the batch axis (what-if batches
     # scale embarrassingly) — a 1-device host degenerates to the
-    # single-device program at <2% overhead (bench sharding_overhead).
+    # single-device program with the same bits
+    # (tests/test_shard_spf.py::test_one_device_mesh_matches_plain_path).
     enabled: bool = True
     # Axis sizes; None = derive (both None -> all devices on batch;
     # one set -> the other is devices/that).  batch*node must equal the

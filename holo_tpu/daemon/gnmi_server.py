@@ -76,7 +76,8 @@ class _SubSampler:
     (one snapshot + one render per tick epoch, shared across every due
     subscriber) and only run these samplers when the engine is disabled
     or its breaker opened.  The semantics here are the byte-identical
-    contract the engine is graded against (``bench.py gnmi_fanout``).
+    contract the engine is graded against (``tests/test_gnmi_fanout.py::
+    test_engine_output_byte_identical_to_legacy_walk_path``).
 
     - ``SAMPLE``: push the subscribed subtree's scalar leaves every
       ``sample_interval`` (ns).  With ``suppress_redundant`` only leaves
@@ -186,7 +187,7 @@ class GnmiService:
         self._next_sub = 0
         self._bursts: dict[int, int] = {}  # ordinal -> burst depth
         # Injectable notification timestamp source: the byte-identity
-        # bench arm pins it so the shared-render and walk paths stamp
+        # test pins it so the shared-render and walk paths stamp
         # identically.
         self._clock_ns = lambda: int(time.time() * 1e9)
         # Shared-delta fan-out engine (ISSUE 11): one state snapshot +

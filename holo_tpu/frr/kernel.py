@@ -37,8 +37,8 @@ are < 2**30, so a single sum cannot wrap).  Every table is bit-compared
 against :mod:`holo_tpu.frr.scalar` in tests/test_frr_parity.py.
 
 Memory note: the LFA stage materializes [L, A, N] bool intermediates and
-``D`` is [N, N] int32 — size the batch like the what-if bench, not the
-50k single-SPF path.
+``D`` is [N, N] int32 — size the batch like the 10k what-if batch, not
+the 50k single-SPF path.
 """
 
 from __future__ import annotations

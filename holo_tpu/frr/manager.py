@@ -314,7 +314,7 @@ class FrrEngine:
             lsr = np.concatenate([lsr, np.zeros(pad, lsr.dtype)])
         if mesh.size == 1:
             # Nothing to shard: the jit commits host arrays itself
-            # (see mesh.shard_scenarios — the sharding_overhead gate).
+            # (see mesh.shard_scenarios).
             return (
                 lf, lc, lv, em,
                 fin.adj_nbr, fin.adj_cost, fin.adj_link, fin.adj_valid,

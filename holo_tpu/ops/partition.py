@@ -829,7 +829,7 @@ class PartResident:
     # planes then no longer serve mask consumers (what-if) — same
     # contract as DeviceGraphCache.ids_stale.
     ids_stale: bool = False
-    # Per-phase walls of the last solve/delta (bench splits).
+    # Per-phase walls of the last solve/delta.
     timings: dict = field(default_factory=dict)
 
     def stats(self) -> dict:

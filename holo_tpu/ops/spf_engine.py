@@ -263,7 +263,7 @@ def _process_mesh_state():
     Lazy import: parallel/mesh.py imports this module at top level, so
     the dependency must stay one-way at import time.  After the first
     call this is a sys.modules dict hit — nanoseconds on the dispatch
-    path (the incremental_overhead/sharding_overhead gates cover it).
+    path.
     """
     from holo_tpu.parallel import mesh as _pm
 
@@ -1437,7 +1437,7 @@ def spf_one_multipath(
     multipath can never change single-path routing state.
 
     Memory note: the packed state carries ``A = W*32`` weight lanes —
-    size batches like the what-if bench, not the 50k single-SPF path.
+    size batches like the 10k what-if batch, not the 50k single-SPF path.
     """
     n, k = g.in_src.shape
     w = g.direct_nh_words.shape[2]

@@ -13,7 +13,7 @@ the node axis; GSPMD turns each round's row-block update into a node-axis
 all-gather, which rides ICI on real hardware.
 
 Since ISSUE 8 this module also owns the PROCESS MESH: the daemon (or a
-bench/test harness) installs one ``(batch, node)`` mesh at startup via
+test harness) installs one ``(batch, node)`` mesh at startup via
 :func:`configure_process_mesh` (``[parallel]`` in holod.toml; default
 all-devices-on-batch per :func:`make_spf_mesh`), and the real dispatch
 path — ``TpuSpfBackend``, ``FrrEngine``, and the shared
@@ -72,7 +72,7 @@ def configure_process_mesh(
     n_node: int | None = None,
     devices: list | None = None,
 ) -> Mesh:
-    """Install the process-wide dispatch mesh (daemon boot; bench/tests).
+    """Install the process-wide dispatch mesh (daemon boot; tests).
 
     From here on every ``TpuSpfBackend``/``FrrEngine`` dispatch and every
     ``DeviceGraphCache`` marshal runs mesh-sharded per the layout
@@ -106,8 +106,8 @@ def mesh_cache_key(mesh: Mesh | None = None) -> tuple | None:
     """Hashable identity of a mesh for cache/jit-bucket keys.
 
     Two meshes with the same shape over the same device ids key
-    identically, so toggling the SAME mesh on/off (the
-    ``sharding_overhead`` bench discipline) re-hits warm entries."""
+    identically, so toggling the SAME mesh on/off re-hits warm
+    entries."""
     m = mesh if mesh is not None else _PROCESS_MESH
     if m is None:
         return None
@@ -152,8 +152,8 @@ def shard_graph(g: DeviceGraph, mesh: Mesh) -> DeviceGraph:
     if mesh.size == 1:
         # Degenerate mesh, degenerate placement: a plain single-device
         # put — NamedSharding-committed arrays take a measurably slower
-        # jax dispatch path, and the sharding_overhead gate holds the
-        # 1-device mesh to <2% of the plain path.
+        # jax dispatch path, and a 1-device mesh must stay the plain path
+        # (tests/test_shard_spf.py::test_one_device_mesh_matches_plain_path).
         return jax.device_put(g, mesh.devices.flat[0])
     n_node = mesh.shape["node"]
     n = g.in_src.shape[0]
@@ -249,8 +249,8 @@ def shard_scenarios(mesh: Mesh, edge_masks: np.ndarray) -> jax.Array:
     if mesh.size == 1:
         # Nothing to shard: let the jit commit the host array itself —
         # an explicit NamedSharding put costs ~0.3ms of pure dispatch
-        # machinery, which is exactly what the sharding_overhead <2%
-        # 1-device-mesh gate exists to keep off this path.
+        # machinery (a JAX-CPU figure), which the 1-device mesh must not
+        # pay.
         return masks
     return jax.device_put(masks, NamedSharding(mesh, P("batch", None)))
 
@@ -271,8 +271,8 @@ def constrain_batch(mesh: Mesh, out):
     """Pin a result pytree's leading axis to the batch sharding (the
     annotation GSPMD propagates the whole program from).  On a
     1-device mesh the constraint is semantically a no-op — skip it so
-    the degenerate program is bit-for-bit the single-device one (the
-    sharding_overhead gate's contract)."""
+    the degenerate program is bit-for-bit the single-device one
+    (tests/test_shard_spf.py::test_one_device_mesh_matches_plain_path)."""
     if mesh.size == 1:
         return out
     spec = NamedSharding(mesh, P("batch"))

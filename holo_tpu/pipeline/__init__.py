@@ -15,7 +15,7 @@ The execution layer between the protocol actors and the device:
   respawn (``Supervisor.watch_worker``).
 - :mod:`holo_tpu.pipeline.tuner` — measured per-(V, E, batch, mesh)
   shape-bucket engine selection from compile-time ``cost_analysis()``
-  priors + dispatch-wall medians, persisted to a versioned table
+  priors + dispatch wall medians, persisted to a versioned table
   (``[pipeline] tuner-cache``) so restarts don't re-learn; the same
   table carries the auto-tuned DeltaPath ``max_delta_depth`` per
   bucket.

@@ -1142,7 +1142,7 @@ class AsyncSpfBackend:
     time.  ``compute_whatif_async`` adds the advisory-batch semantics
     (coalescing + breaker-open skip); the plain ``compute_whatif`` /
     ``compute_multiroot`` stay synchronous delegates — their callers
-    (CLI, bench) want blocking results.
+    (the CLI) want blocking results.
     """
 
     #: retained chain-root entries (one live dispatch chain per entry)
@@ -1378,7 +1378,7 @@ def configure_process_pipeline(
     advisory_deadline: float | None = None,
 ) -> DispatchPipeline:
     """Install the process-wide dispatch pipeline (daemon boot from
-    ``[pipeline]``; bench/tests call directly).  Closes any previous
+    ``[pipeline]``; tests call directly).  Closes any previous
     pipeline first so its worker cannot race the replacement."""
     global _PIPELINE
     with _PIPELINE_LOCK:
@@ -1396,7 +1396,7 @@ def process_pipeline() -> DispatchPipeline | None:
 
 
 def reset_process_pipeline() -> None:
-    """Close + uninstall (tests / bench teardown)."""
+    """Close + uninstall (test teardown)."""
     global _PIPELINE
     with _PIPELINE_LOCK:
         if _PIPELINE is not None:

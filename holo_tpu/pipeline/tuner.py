@@ -1,10 +1,9 @@
 """Per-shape engine auto-tuner (ISSUE 9 tentpole, part b).
 
-The bench sweep history shows the winning gather-path fixpoint engine
-flips with topology size — ``seq`` 1481 vs ``fused`` 464 vs ``hybrid``
-892 runs/s on small jaxcpu graphs while ``gather``-family engines at
-big V behave differently again on TPU (BENCH r02-r04) — yet the engine
-has been a static config knob (``TpuSpfBackend(one_engine=...)``).
+On JAX-CPU the winning gather-path fixpoint engine flipped with
+topology size, and on the chip no engine but ``seq`` has been measured
+(ROADMAP S3) — yet the engine has been a static config knob
+(``TpuSpfBackend(one_engine=...)``).
 This module turns it into a measured decision per **shape bucket**:
 
     bucket = (pow2(V), pow2(E), pow2(batch), mesh identity)
@@ -363,7 +362,7 @@ class EngineTuner:
         """median(monolithic winner wall) / median(partitioned wall)
         for one shape bucket — >1 means the partitioned path is
         measured faster at this shape.  None until both arms have
-        samples (bench/operators read this; the backend's
+        samples (operators read this; the backend's
         ``partition_threshold`` is deliberately not auto-flipped by
         it)."""
         with self._lock:
@@ -630,7 +629,7 @@ class EngineTuner:
         return f"{winner} beat {', '.join(named)} on {basis}"
 
     def stats(self) -> dict:
-        """holo-telemetry state-leaf / bench view."""
+        """holo-telemetry state-leaf view."""
         with self._lock:
             winners = {}
             for key, st in self._table.items():
@@ -659,7 +658,7 @@ def configure_engine_tuner(
     path: str | Path | None = None, **kw
 ) -> EngineTuner:
     """Install the process-wide tuner (daemon boot from ``[pipeline]``;
-    bench/tests call directly).  Replaces any previous tuner."""
+    tests call directly).  Replaces any previous tuner."""
     global _TUNER
     with _TUNER_LOCK:
         _TUNER = EngineTuner(path=path, **kw)
@@ -673,7 +672,7 @@ def active_tuner() -> EngineTuner | None:
 
 
 def reset_engine_tuner() -> None:
-    """Uninstall (tests / bench teardown)."""
+    """Uninstall (test teardown)."""
     global _TUNER
     with _TUNER_LOCK:
         _TUNER = None

@@ -64,7 +64,7 @@ _FALLBACKS = telemetry.counter(
 )
 
 # Live breakers for the health leaf; weak values so short-lived backend
-# instances (tests, bench) do not accumulate forever.  The lock guards
+# instances (tests) do not accumulate forever.  The lock guards
 # the name-uniquify + insert pair: instance threads construct engines
 # (and so breakers) concurrently under [runtime] isolation=threaded.
 _REGISTRY: "weakref.WeakValueDictionary[str, CircuitBreaker]" = (
@@ -130,8 +130,11 @@ class CircuitBreaker:
         """``clock`` is injectable so virtual-clock tests drive recovery
         deterministically (pass ``loop.clock.now``).  ``deadline`` is a
         per-dispatch wall budget in clock units (None = no budget).
-        ``enabled=False`` bypasses the breaker entirely (the bench's
-        control arm for the healthy-path overhead gate).  Parameters
+        ``enabled=False`` bypasses the breaker entirely; closed and
+        enabled it costs a healthy dispatch two clock reads
+        (tests/test_spf_parity.py::
+        test_closed_breaker_costs_a_healthy_dispatch_two_clock_reads_and_no_oracle).
+        Parameters
         left unset fall back to the process-wide :data:`DEFAULTS`."""
         # Unique registry/metric identity: several protocol instances
         # each build a default-named backend breaker ("spf-dispatch");

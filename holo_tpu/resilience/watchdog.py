@@ -32,7 +32,8 @@ machinery as the pipeline worker it guards.
 Chaos seam: ``FaultPlan.dispatch_hang`` wedges the worker inside the
 ``pipeline.launch`` / ``pipeline.finish`` hangpoints; the acceptance
 contract is byte-identical correctness FIB digests versus the
-unfaulted control (tests/test_overload.py, bench.py overload_storm).
+unfaulted control (tests/test_overload.py::
+test_watchdog_hang_mid_storm_fib_parity).
 """
 
 from __future__ import annotations
@@ -236,7 +237,7 @@ _WATCHDOG: DispatchWatchdog | None = None
 
 def configure_process_watchdog(pipeline, **kw) -> DispatchWatchdog:
     """Arm the process-wide watchdog over ``pipeline`` (daemon boot;
-    bench/tests call directly).  Stops any previous sentinel first."""
+    tests call directly).  Stops any previous sentinel first."""
     global _WATCHDOG
     if _WATCHDOG is not None:
         _WATCHDOG.stop()
@@ -249,7 +250,7 @@ def process_watchdog() -> DispatchWatchdog | None:
 
 
 def reset_process_watchdog() -> None:
-    """Stop + uninstall (tests / bench teardown)."""
+    """Stop + uninstall (test teardown)."""
     global _WATCHDOG
     if _WATCHDOG is not None:
         _WATCHDOG.stop()

@@ -116,7 +116,7 @@ class MockKernel(Kernel):
         self.log: list[tuple[str, IpNetwork]] = []
 
     def install(self, prefix, nexthops, proto, backups=None, weights=None):
-        # Cumulative multipath surface (storm/bench assertions must not
+        # Cumulative multipath surface (storm assertions must not
         # depend on whether the run ENDS mid-failure with repairs
         # holding single-survivor sets).
         if len(nexthops) > 1:

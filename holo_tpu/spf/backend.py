@@ -120,8 +120,8 @@ class SpfResult:
 
     The multipath planes (ISSUE 10) are present iff the dispatch asked
     for them (``multipath_k > 1``); ``None`` otherwise — the k=1 path
-    is byte-for-byte the single-parent dispatch (the
-    ``multipath_overhead`` gate's contract)."""
+    is byte-for-byte the single-parent dispatch (``tests/test_multipath.py::
+    test_multipath_k1_is_the_unchanged_single_parent_dispatch``)."""
 
     dist: np.ndarray  # int32[N]
     parent: np.ndarray  # int32[N]
@@ -334,9 +334,10 @@ class TpuSpfBackend(SpfBackend):
         ``one_engine`` picks the gather-path fixpoint formulation
         ('fused' | 'packed' | 'seq' — see :func:`spf_one_fused`); all are
         bit-identical, differing only in TPU round/gather scheduling.
-        'seq' is the default: it is the fastest measured formulation on
-        the only platform benchmarked so far (JAX-CPU) — flip only once
-        a run on the chip shows another engine winning.
+        'seq' is the default, and the only engine the ledger has read on
+        the chip (all four cells); none has been A/B'd against it there
+        (ROADMAP S3) — flip only once a run on the chip shows another
+        engine winning.
 
         ``breaker`` guards every device dispatch: XLA exceptions and
         deadline overruns fall back to the scalar oracle (bit-identical
@@ -347,8 +348,8 @@ class TpuSpfBackend(SpfBackend):
         delta lineage (``Topology.link_delta`` at the LSDB seam) are
         served by an in-place device-graph update plus the seeded
         incremental kernel instead of a full re-marshal + full-batch
-        recompute.  False forces the full-rebuild path everywhere (the
-        bench's comparison arm).  ``prev_capacity`` bounds the retained
+        recompute.  False forces the full-rebuild path everywhere.
+        ``prev_capacity`` bounds the retained
         previous-tensor entries — one live (topology, root) chain per
         entry, so size it >= the number of areas/MTs the instance
         computes per SPF cycle or their chains silently degrade to
@@ -396,7 +397,8 @@ class TpuSpfBackend(SpfBackend):
         # Multipath (ISSUE 10) jits, one per pow2 parent-set width kp:
         # the widened kernel is dispatched ONLY when a dispatch asks
         # for multipath_k > 1 — the k=1 path rides the unchanged
-        # single-parent programs (the multipath_overhead contract).
+        # single-parent programs (tests/test_multipath.py::
+        # test_multipath_k1_is_the_unchanged_single_parent_dispatch).
         self._mp_jits: dict[int, object] = {}
         self._mp_batch_jits: dict[int, object] = {}
         self._mp_incr_jits: dict[int, object] = {}
@@ -691,7 +693,7 @@ class TpuSpfBackend(SpfBackend):
         """(engine, shape bucket | None) for this dispatch: the
         process engine tuner's per-shape choice when one is armed, else
         the pinned ``one_engine``.  Lazy import keeps the unarmed path
-        at a sys.modules hit (pipeline_overhead gate).
+        at a sys.modules hit.
 
         Multipath dispatches (``kp > 1``) choose between the packed
         row-gather kernel (``mp``) and its tropical DAG-tile variant
@@ -920,7 +922,8 @@ class TpuSpfBackend(SpfBackend):
         generation, root) produces bit-identical tensors, so the
         already-stored set stays — the no-delta steady state then holds
         one buffer set instead of churning a fresh one per dispatch
-        (the incremental_overhead <2% gate measures exactly this).
+        (tests/test_delta_spf.py::
+        test_topology_without_lineage_never_enters_the_delta_path).
 
         ``kp`` joins the key: a multipath chain seeds from multipath
         tensors ((SpfTensors, MultipathTensors) pairs) and a k=1 chain
@@ -1072,7 +1075,7 @@ class TpuSpfBackend(SpfBackend):
         return (self._part_ns, int(topo.root), int(n_atoms), _mesh_key())
 
     def partition_residents(self) -> list:
-        """This backend's live partitioned residents (tests/bench)."""
+        """This backend's live partitioned residents (tests)."""
         from holo_tpu.ops.spf_engine import shared_graph_cache
 
         return list(
@@ -1195,7 +1198,7 @@ class TpuSpfBackend(SpfBackend):
         return out
 
     def partition_stats(self) -> dict:
-        """Resident summaries for the telemetry leaf / bench rows."""
+        """Resident summaries for the telemetry leaf."""
         from holo_tpu.ops.spf_engine import shared_graph_cache
 
         return {
@@ -1305,8 +1308,7 @@ class TpuSpfBackend(SpfBackend):
             self._tuner_depth_observe(topo, "full", t2 - t0, kp)
         if edge_mask is None and self.incremental:
             # Disarmed backends skip retention: they could never
-            # consume the tensors, and the incremental_overhead gate
-            # compares exactly this armed-vs-disarmed difference.
+            # consume the tensors.
             self._remember(
                 topo, max(self.n_atoms, topo.n_atoms()), out, kp
             )

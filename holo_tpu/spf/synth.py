@@ -26,8 +26,7 @@ def clone_topology(
     cost: dict | None = None,
 ) -> Topology:
     """Fresh-identity copy of ``topo`` with optional edge mutations —
-    the shared mutation helper for DeltaPath tests, fuzzing, and the
-    bench chains.  ``keep``: bool[E] edge filter; ``extra``: rows of
+    the shared mutation helper for DeltaPath tests and fuzzing.  ``keep``: bool[E] edge filter; ``extra``: rows of
     (src, dst, cost, atom) to append; ``cost``: {edge index: new cost}
     over the (post-filter) edge array.  The result has its own
     uid/generation
@@ -258,78 +257,6 @@ def grid_topology(rows: int, cols: int, max_cost: int = 10, seed: int = 0) -> To
         edge_dst=np.array(dst, np.int32),
         edge_cost=np.array(cost, np.int32),
         root=0,
-    )
-    assign_direct_atoms(topo)
-    return topo
-
-
-def multiarea_topology(
-    n_areas: int,
-    rows: int,
-    cols: int,
-    gateways: int = 4,
-    max_cost: int = 10,
-    inter_cost: int = 5,
-    seed: int = 0,
-    hint: bool = True,
-) -> Topology:
-    """Hub-and-spoke multi-area synth (ISSUE 15): ``n_areas`` grid
-    areas of ``rows x cols`` routers, area 0 the backbone, every other
-    area joined to it through ``gateways`` gateway-router pairs — the
-    OSPF area-0 shape the hierarchical partitioned SPF is designed for
-    (small per-area boundary sets, cut edges only at gateways).
-
-    Vertex ids are area-major (area a owns [a*rows*cols, (a+1)*rows*
-    cols)), so the flat BFS/greedy cut re-discovers the areas when the
-    native hint is withheld (``hint=False`` — the "flat" bench arm).
-    Fully vectorized: usable at 100k+ vertices.  Root is backbone
-    vertex 0; direct next-hop atoms assigned as usual."""
-    rng = np.random.default_rng(seed)
-    per = rows * cols
-    n = n_areas * per
-    vid = np.arange(per).reshape(rows, cols)
-    h_src = vid[:, :-1].ravel()
-    h_dst = vid[:, 1:].ravel()
-    v_src = vid[:-1, :].ravel()
-    v_dst = vid[1:, :].ravel()
-    a_src = np.concatenate([h_src, h_dst, v_src, v_dst])
-    a_dst = np.concatenate([h_dst, h_src, v_dst, v_src])
-    e_per = a_src.shape[0]
-    src = (
-        a_src[None, :] + (np.arange(n_areas) * per)[:, None]
-    ).ravel()
-    dst = (
-        a_dst[None, :] + (np.arange(n_areas) * per)[:, None]
-    ).ravel()
-    cost = rng.integers(1, max_cost + 1, src.shape[0])
-    # Gateways: area a>0 vertex g*cols (left-edge spread) <-> backbone
-    # vertex g*cols + a (distinct backbone attach points per area).
-    g = np.arange(min(gateways, rows))
-    gs, gd, gc = [src], [dst], [cost]
-    for a in range(1, n_areas):
-        leaf = a * per + g * cols
-        hub = (g * cols + a) % per
-        gs.append(np.concatenate([leaf, hub]))
-        gd.append(np.concatenate([hub, leaf]))
-        gc.append(
-            rng.integers(1, inter_cost + 1, 2 * g.shape[0])
-        )
-    src = np.concatenate(gs).astype(np.int32)
-    dst = np.concatenate(gd).astype(np.int32)
-    cost = np.concatenate(gc).astype(np.int32)
-    del e_per
-    topo = Topology(
-        n_vertices=n,
-        is_router=np.ones(n, bool),
-        edge_src=src,
-        edge_dst=dst,
-        edge_cost=cost,
-        root=0,
-        partition_hint=(
-            np.repeat(np.arange(n_areas, dtype=np.int32), per)
-            if hint
-            else None
-        ),
     )
     assign_direct_atoms(topo)
     return topo

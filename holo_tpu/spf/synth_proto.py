@@ -5,7 +5,7 @@ directly), these builders populate REAL protocol instances — an OSPFv3
 multi-area LSDB of ``LsaRouterV3``/Intra-Area-Prefix LSAs, and IS-IS
 L1/L2 LSP databases — and extract the benchmark topologies through each
 protocol's own SPF marshaling path (``OspfV3Instance._area_spf``,
-``IsisInstance.run_spf``).  What the bench then times on the shared
+``IsisInstance.run_spf``).  What a caller then runs on the shared
 engine is exactly what the protocols dispatch in production
 (reference parity: the per-protocol graph/vertex-ordering rules live in
 the marshal, not the engine).

@@ -35,7 +35,6 @@ from __future__ import annotations
 
 import hashlib
 import json
-import time
 from dataclasses import dataclass
 from ipaddress import IPv4Address, IPv4Network
 
@@ -403,41 +402,6 @@ def storm_report(timelines: list[dict]) -> dict:
     return report
 
 
-def _instrument_dispatch_wall(net: StormNet):
-    """Wrap the DUT backend's ``compute`` to attribute REAL (wall-clock)
-    SPF dispatch seconds to the active causal triggers.
-
-    The storm's event-to-FIB latencies are virtual-clock quantities —
-    deterministic, but blind to how long the device work actually takes
-    (the virtual clock does not advance while Python computes).  This
-    sink is the DeltaPath headline instrument: the per-trigger
-    dispatch-wall distribution is what the incremental path must shrink
-    while the virtual timelines (and FIB digests) stay byte-identical.
-
-    Returns ``(sink, restore)``; the harness calls ``restore`` when the
-    storm ends so a caller-supplied backend leaves unwrapped (backends
-    are parameters — reuse across storms must not nest timers).
-    """
-    sink: dict[str, list[float]] = {}
-    backend = net.inst.backend
-    inner = backend.compute
-
-    def timed(topo, edge_mask=None, multipath_k: int = 1):
-        t0 = time.perf_counter()
-        res = inner(topo, edge_mask, multipath_k=multipath_k)
-        dt = time.perf_counter() - t0
-        for trig in set(convergence.active_triggers()) or {"untracked"}:
-            sink.setdefault(trig, []).append(dt)
-        return res
-
-    backend.compute = timed
-
-    def restore():
-        backend.compute = inner
-
-    return sink, restore
-
-
 def storm_digest(timelines: list[dict]) -> str:
     """Canonical digest of the causal timelines for the determinism
     gate (same seed → same digest).  Trace span ids are stripped: the
@@ -477,8 +441,8 @@ def run_convergence_storm(
 
     ``event_hook(net, index, now)`` — optional observer called after
     each event's inter-event gap has elapsed (and once more after the
-    settle window, with ``index == events``).  The gNMI fan-out bench
-    rides this seam: a subscriber fleet joins/leaves and the shared
+    settle window, with ``index == events``).  The gNMI fan-out churn
+    test rides this seam: a subscriber fleet joins/leaves and the shared
     delta engine ticks at these deterministic virtual times.  The hook
     only READS daemon state — the storm's causal timelines and FIB
     digests are unaffected by its presence."""
@@ -491,7 +455,6 @@ def run_convergence_storm(
     tracker = convergence.configure(
         tracker_capacity, clock=net.loop.clock.now
     )
-    dispatch_wall, restore_dispatch = _instrument_dispatch_wall(net)
     try:
         mix_rng = inj._rng("storm.mix")
         loss_rng = inj._rng("storm.loss")
@@ -542,14 +505,6 @@ def run_convergence_storm(
             net.kernel, "multipath_installs", 0
         )
         report["fib-weighted"] = getattr(net.kernel, "weighted_installs", 0)
-        # REAL per-trigger dispatch seconds (never in the digest: wall
-        # time is nondeterministic by nature; the determinism gate is
-        # the virtual timelines + FIB digest above).
-        report["dispatch-wall"] = {
-            trig: _percentiles(vals)
-            for trig, vals in sorted(dispatch_wall.items())
-        }
         return report, storm_digest(timelines), net
     finally:
-        restore_dispatch()
         convergence.configure(0)

@@ -24,8 +24,9 @@ section):
 Everything here is stdlib-only and import-light: instrumented hot paths
 (SPF dispatch, RIB churn, packet rx/tx) pay a dict hit and a locked
 float add per event, and :func:`set_enabled` (False) turns every update
-into an early return — the ``telemetry_overhead`` bench scenario keeps
-the instrumented SPF path within noise of a disabled registry.
+into an early return: a dispatch then writes no metric and opens no span
+(``tests/test_telemetry.py::
+test_disabled_registry_dispatch_writes_no_metric_and_opens_no_span``).
 """
 
 from __future__ import annotations
@@ -52,8 +53,8 @@ _tracer = SpanTracer()
 
 def set_enabled(on: bool) -> None:
     """Global kill switch for BOTH the metrics registry and the default
-    span tracer — the overhead bench's control arm must shed every
-    instrumentation cost, spans included."""
+    span tracer — a disabled process sheds every instrumentation
+    cost, spans included."""
     _registry_mod.set_enabled(on)
     _tracer.enabled = bool(on)
 
@@ -99,12 +100,12 @@ def current_instance():
 
 
 def snapshot(prefix: str | None = None) -> dict:
-    """Flat metrics view for bench rows / debugging."""
+    """Flat metrics view for the benchmark, tests and debugging."""
     return _registry.snapshot(prefix)
 
 
-# Optional env-triggered span dump on process exit: any run (bench
-# stage, test, daemon) gets a perfetto-loadable trace with no code
+# Optional env-triggered span dump on process exit: any run (test,
+# daemon, benchmark cell) gets a perfetto-loadable trace with no code
 # change.  Registered once, at first package import.
 _dump_path = os.environ.get("HOLO_TPU_TRACE_DUMP")
 if _dump_path:  # pragma: no cover — exercised via subprocess in tests

@@ -27,8 +27,9 @@ Probe contract
   The delta forces a real SPF and a real route-metric change, so every
   healthy probe ends in a kernel install; the canary kernel matches
   the install back to the probe's event id (``unattributed`` counts
-  installs that arrived with no matching causal id — the <1% bench
-  gate on attribution quality).
+  installs that arrived with no matching causal id — attribution
+  quality, held under 1% by ``tests/test_slo.py::
+  test_storm_fib_digest_identical_with_canary_riding``).
 - The canary's SPF dispatch rides the process pipeline as a
   ``background``-class ticket (site ``canary.probe``) when one is
   armed: probes are shed FIRST under pressure and can never displace
@@ -46,7 +47,7 @@ Probe contract
   the canary's own objective.
 
 Arming: the daemon boots one prober from ``[telemetry] canary``;
-bench/test storms arm one on the storm loop via their event hooks.
+test storms arm one on the storm loop via their event hooks.
 Disarmed, nothing here exists — the module seams in dispatch/slo are
 the only residue, each one global check.
 """
@@ -75,8 +76,8 @@ _LEAF_PREFIX = IPv4Network("198.51.100.0/24")
 
 
 def fib_digest(fib: dict) -> str:
-    """Canonical digest of a kernel FIB (the bench identity gate —
-    same spelling as the overload-storm stages)."""
+    """Canonical digest of a kernel FIB: what the storm tests and
+    ``chip_smoke.py`` compare across arms."""
     text = json.dumps(sorted((str(k), str(v)) for k, v in fib.items()))
     return hashlib.sha256(text.encode()).hexdigest()
 
@@ -150,7 +151,7 @@ class _ProbeBackend:
         inner = self.inner
 
         def run():
-            # Breach seam: bench injects FaultPlan.dispatch_delay here
+            # Breach seam: tests inject FaultPlan.dispatch_delay here
             # to slow ONLY the canary's dispatch (a real time.sleep —
             # visible to the probe's profiling-clock wall, invisible to
             # the storm's virtual end-cuts).
@@ -371,7 +372,7 @@ class CanaryProber:
         self._open: dict[int, float] = {}  # probe eid -> profiling t0
         self._timer = None
         self._stopped = False
-        # verdict tallies (stats/bench surface)
+        # verdict tallies (stats surface)
         self.probes = 0
         self.completed = 0
         self.attributed = 0
@@ -428,8 +429,8 @@ class CanaryProber:
         self._sweep_overdue()
 
     def beat(self) -> None:
-        """Manual heartbeat (tests/bench hooks that want probes at
-        exact storm indices instead of timer cadence)."""
+        """Manual heartbeat (storm hooks that want probes at exact
+        storm indices instead of timer cadence)."""
         self._beat()
 
     # -- probe close paths ----------------------------------------------
@@ -438,7 +439,7 @@ class CanaryProber:
         """Canary-kernel install: close every open probe whose causal
         id is active at commit; an install with no matching id closes
         the oldest probe as ``unattributed`` (attribution quality is a
-        bench gate, so miscounting must be visible, not silent)."""
+        test contract, so miscounting must be visible, not silent)."""
         t1 = profiling.clock()
         hit = False
         for e in eids:
@@ -488,13 +489,13 @@ class CanaryProber:
 
     def unattributed_fraction(self) -> float:
         """Installs closed without a matching causal id, as a fraction
-        of completed probes (the <1% bench gate)."""
+        of completed probes (tests/test_slo.py holds it under 1%)."""
         if not self.completed:
             return 0.0
         return self.unattributed / self.completed
 
     def stats(self) -> dict:
-        """holo-telemetry/slo canary sub-leaf + bench row."""
+        """holo-telemetry/slo canary sub-leaf."""
         return {
             "probes": self.probes,
             "completed": self.completed,

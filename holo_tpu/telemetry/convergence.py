@@ -29,11 +29,11 @@ Dispatch attribution: the SPF/FRR backends call :func:`note_dispatch`
 with the mode that actually served the computation (``device`` /
 ``scalar`` / ``fallback``).  An event served by the breaker's scalar
 fallback closes with ``phase="fallback"`` instead of ``"fib"`` — the
-storm bench splits its distributions on exactly this.
+storm report splits its distributions on exactly this.
 
 Everything is **off by default**: the hot-path cost while disarmed is
 one module-global ``None`` check per seam (``[telemetry]
-convergence-events`` arms it in the daemon; bench/tests call
+convergence-events`` arms it in the daemon; the benchmark and tests call
 :func:`configure` directly with the loop clock, which makes every
 timeline and latency deterministic under the virtual clock).
 """
@@ -205,11 +205,6 @@ class ConvergenceTracker:
         with self._lock:
             return [ev for e in eids if (ev := self._open.get(e)) is not None]
 
-    def active_triggers(self) -> tuple[str, ...]:
-        """Trigger names of the currently-active causal events (storm
-        harness: attribute real dispatch wall time to its trigger)."""
-        return tuple(ev.trigger for ev in self._events(self.current()))
-
     def _entry(self, ev: _Event, step: str, attrs: dict) -> None:
         """Append one timeline entry (caller holds no lock)."""
         t = round(self._clock() - ev.t0, 9)
@@ -338,7 +333,7 @@ class ConvergenceTracker:
     # -- queries
 
     def timelines(self) -> list[dict]:
-        """Completed event records, oldest first (bench/test surface)."""
+        """Completed event records, oldest first (storm/test surface)."""
         with self._lock:
             return [dict(r) for r in self._done]
 
@@ -377,7 +372,7 @@ def configure(
 ) -> ConvergenceTracker | None:
     """Arm (``capacity`` > 0) or disarm (0) the process-wide tracker and
     (un)install the runtime delivery-context hook.  The daemon calls
-    this at boot from ``[telemetry] convergence-events``; bench and
+    this at boot from ``[telemetry] convergence-events``; storms and
     tests pass the loop clock for deterministic timelines."""
     global _TRACKER
     from holo_tpu.utils import runtime as _runtime
@@ -410,11 +405,6 @@ def begin(trigger: str, **attrs) -> int | None:
 def current() -> tuple[int, ...]:
     t = _TRACKER
     return t.current() if t is not None else ()
-
-
-def active_triggers() -> tuple[str, ...]:
-    t = _TRACKER
-    return t.active_triggers() if t is not None else ()
 
 
 def activation(eids):

@@ -47,8 +47,9 @@ a forced breaker trip must show up there, an injected
 ``queue_wait`` — wrong-phase attribution is a test failure).  The
 residual that no stamp explains is *reported*, never hidden: the
 ``unattributed`` phase is the closing segment past the last stamp — an
-event with NO stamps at all books its whole wall there — gated <1% of
-the wall at p50 by ``bench.py critical_path``.
+event with NO stamps at all books its whole wall there — held under 1%
+of the wall at p50 by ``tests/test_critpath.py::
+test_storm_delay_inflates_device_phase_digest_identical``.
 
 Aggregation + sentinel
 ----------------------
@@ -66,9 +67,8 @@ regressions latch, flag, and ratchet with the same machinery and the
 same ledger file as stage-level ones.
 
 Armed/disarmed contract: off by default; every seam costs one
-module-global ``None`` check while disarmed; armed overhead is gated
-<2% by ``bench.py critpath_overhead`` (paired interleaved min-of-N,
-same harness as ``convergence_overhead``); no locks are taken on the
+module-global ``None`` check while disarmed (``tests/test_critpath.py::
+test_disarmed_seams_are_one_global_check``); no locks are taken on the
 dispatch thread — records are plain dicts mutated under the GIL (the
 DDSketch lock-free contract, see observatory.py).
 """
@@ -181,7 +181,7 @@ def _decompose(rec: _Rec, t_done: float, fallback: bool) -> dict:
         ("fib_commit", rec.t_end),
         # The closing segment past the last stamp: an event that
         # converged with NO stamps books its whole wall here — the
-        # honest "no stamp explains this" residual the bench gates.
+        # honest "no stamp explains this" residual the storm test bounds.
         ("unattributed", t_done),
     )
     prev = rec.t0
@@ -448,7 +448,7 @@ class CritPathLedger:
         _SKETCHES_G.set(len(self._sketches))
 
     def checkpoint(self) -> None:
-        """Force one sentinel pass NOW (bench/explain bracket their
+        """Force one sentinel pass NOW (the explain CLI brackets its
         runs with it, same discipline as ``Observatory.checkpoint``)."""
         self._sentinel_pass()
 
@@ -488,7 +488,8 @@ class CritPathLedger:
 
     def unattributed_frac_p50(self) -> float | None:
         """unattributed p50 as a fraction of the wall p50 — the
-        gap-free gate (< 1% at p50 in ``bench.py critical_path``)."""
+        gap-free figure the storm test in ``tests/test_critpath.py``
+        holds under 1%."""
         q = self.phase_quantiles()
         wall = q.get("wall")
         if not wall or wall["p50"] <= 0.0:

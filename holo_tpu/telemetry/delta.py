@@ -27,7 +27,7 @@ Epoch / versioning contract
   intermediate epochs between a slow bucket's fires is resent with its
   (correct, current) value where the legacy value diff would have
   stayed silent — gNMI suppress_redundant is best-effort, and a bucket
-  firing at every epoch (the bench identity arm) is provably
+  firing at every epoch (the byte-identity test's arm) is provably
   value-exact.
 - The registry's write-time leaf stamps
   (:func:`holo_tpu.telemetry.registry.write_stamp`) short-circuit idle
@@ -260,7 +260,7 @@ class FanoutEngine:
     ``clock``/``clock_ns``
                       -> bucket timers / notification timestamps
                          (injectable: virtual-clock storms and the
-                         byte-identity bench arm pin both).
+                         byte-identity test pin both).
     """
 
     def __init__(
@@ -284,7 +284,7 @@ class FanoutEngine:
         self._clock_ns = clock_ns or (lambda: int(time.time() * 1e9))
         self._lock = threading.Lock()
         # One tick at a time: the ticker thread and any manual
-        # tick_now() driver (bench, tests) serialize here, so the
+        # tick_now() driver (tests) serialize here, so the
         # store/diff path stays single-writer.
         self._tick_lock = threading.Lock()
         self._buckets: dict[tuple, _Bucket] = {}
@@ -451,7 +451,7 @@ class FanoutEngine:
     def tick_now(self, now: float | None = None, state=None) -> dict:
         """One coalesced tick: advance every due bucket against ONE
         state snapshot/epoch, render per bucket (shared cache), fan out
-        to member queues.  Manual drivers (bench/tests) may inject
+        to member queues.  Manual drivers (tests) may inject
         ``now`` and a pre-fetched ``state``."""
         with self._tick_lock:
             return self._tick_locked(now, state)
@@ -482,8 +482,8 @@ class FanoutEngine:
         t0 = time.perf_counter()
         walked = False
         if state is not None:
-            # An injected snapshot is authoritative (bench/test drivers
-            # pin the exact state both arms see): never skip it.
+            # An injected snapshot is authoritative (test drivers pin
+            # the exact state both arms see): never skip it.
             self._refresh(state)
             walked = True
         elif not self._can_skip_walk():
@@ -584,8 +584,10 @@ class FanoutEngine:
             "dropped": dropped,
             "tick_seconds": dt,
             # The O(1)-in-subscribers portion (snapshot+diff+render)
-            # vs the O(subscribers) bounded-queue delivery floor — the
-            # split the gnmi_fanout bench gates on.
+            # vs the O(subscribers) bounded-queue delivery floor (the
+            # render share is what tests/test_gnmi_fanout.py::
+            # test_bucket_shares_one_render_across_hundreds_of_cursors
+            # holds constant in the subscriber count).
             "render_seconds": t_walked + t_render,
             "deliver_seconds": max(dt - t_walked - t_render, 0.0),
         }

@@ -263,7 +263,7 @@ def configure(
     """Arm (``entries`` > 0) or disarm (0) the process-wide recorder and
     (un)install the tracer completion tap.  The daemon calls this at
     boot from ``[telemetry] flight-buffer-entries`` / ``postmortem-dir``
-    with its loop clock; bench and tests flip it directly.
+    with its loop clock; tests flip it directly.
 
     Arming also swaps the default tracer onto the same clock (epoch
     reset), so the span entries and the journal/event stamps inside one

@@ -3,8 +3,7 @@ quantile sketches, and an online perf-regression sentinel (ISSUE 12).
 
 The next kernel arc (tropical min-plus SPF, hierarchical partitioning —
 ROADMAP items 1-2) is graded observationally: "cost_analysis() shows
-the flops moving from gather bytes to contraction flops".  Until now
-that evidence existed only as one-shot ``bench.py`` rows.  This module
+the flops moving from gather bytes to contraction flops".  This module
 is the always-on instrument every subsequent kernel PR reports through:
 
 - **Streaming quantile sketches** — DDSketch-style relative-error
@@ -40,8 +39,7 @@ is the always-on instrument every subsequent kernel PR reports through:
 
 - **Online regression sentinel** — every ``check_every`` observations
   of a key, its sketch p50/p99 are compared against a persisted
-  runtime baseline with the exact ``BENCH_baseline.json`` ledger
-  discipline: unseen keys are SEEDED from the current run, >10% drift
+  runtime baseline: unseen keys are SEEDED from the current run, >10% drift
   (plus a small absolute floor) flags a regression — a warn-only
   flight-ring event (``observatory-regression``) plus
   ``holo_observatory_regressions_total{bucket,quantile}`` — and >5%
@@ -53,8 +51,8 @@ is the always-on instrument every subsequent kernel PR reports through:
 Surfaces: ``holo-tpu-tools explain`` (top-k cost centers + roofline
 fractions + the tuner's win/loss ledger), the
 ``holo-telemetry/observatory`` gNMI leaf
-(:mod:`holo_tpu.telemetry.provider`), the Prometheus families above,
-and ``bench.py explain_spf`` / ``observatory_overhead``.
+(:mod:`holo_tpu.telemetry.provider`) and the Prometheus families
+above; ``tests/test_observatory.py`` holds the contracts.
 
 Determinism: :class:`DeterministicTimer` swaps the profiling stage
 timer for a counter clock (each read advances a fixed quantum), so a
@@ -83,16 +81,14 @@ log = logging.getLogger("holo_tpu.telemetry")
 #: only happens under a deterministic timer that was never advanced)
 MIN_TRACKABLE = 1e-9
 
-#: sentinel drift thresholds — the BENCH_baseline.json discipline:
-#: >10% worse flags, >5% better ratchets, plus an absolute floor (the
-#: same role as the ledger's +0.25 slack on percent gates).  The floor
+#: sentinel drift thresholds: >10% worse flags, >5% better ratchets,
+#: plus an absolute floor.  The floor
 #: is 5ms: below it live the async-launch overlap artifacts (a device
 #: sub-span measures time-until-ready, so host work between launch and
 #: sync makes small walls bimodal — 0.2ms vs 2.5ms on the same kernel)
-#: and scheduler noise, both owned by the <2% paired-median bench
-#: gates; the regressions the always-on sentinel exists for — injected
-#: stalls, platform slowdowns, accidental recompile storms — move
-#: dispatch-wall-scale quantiles by far more.
+#: and scheduler noise; the regressions the always-on sentinel exists
+#: for — injected stalls, platform slowdowns, accidental recompile
+#: storms — move quantiles on the scale of a dispatch wall by far more.
 DRIFT_FLAG = 0.10
 DRIFT_RATCHET = 0.05
 DRIFT_FLOOR_S = 5e-3
@@ -486,9 +482,10 @@ class Observatory:
     def checkpoint(self) -> dict:
         """Force one sentinel pass over every populated sketch — seed
         and compare NOW instead of at each key's next ``check_every``
-        boundary.  The bench stages bracket their clean/regressed
-        phases with it (a key whose count never crosses the modulo
-        must still get a pre-regression baseline), and the daemon's
+        boundary.  The explain CLI and the sentinel tests bracket
+        their clean/regressed phases with it (a key whose count never
+        crosses the modulo must still get a pre-regression baseline),
+        and the daemon's
         stop path closes its final window the same way.  Returns
         :meth:`sentinel`."""
         for key, sk in list(self._sketches.items()):
@@ -538,7 +535,7 @@ class Observatory:
             return False
 
     # Seeds/ratchets only MARK the ledger dirty — the actual JSON
-    # write happens at checkpoint boundaries (bench phase brackets,
+    # write happens at checkpoint boundaries (explain brackets,
     # daemon stop, explicit save_ledger), never as a synchronous disk
     # write on the dispatch thread that happened to seed a new key.
 
@@ -746,7 +743,7 @@ def configure(
     """Arm (install the profiling stage observer) or disarm the
     process-wide observatory.  The daemon calls this at boot from
     ``[telemetry] observatory`` / ``observatory-ledger`` /
-    ``roofline-peaks``; bench, the explain CLI, and tests flip it
+    ``roofline-peaks``; the explain CLI and tests flip it
     directly.  Disarming restores the one-global-check stage path."""
     global _ACTIVE
     with _CONFIG_LOCK:

@@ -37,8 +37,10 @@ Everything is **off by default** (``[telemetry] profile-device-time``
 in holod.toml, :func:`set_device_profiling` programmatically): when
 disabled, :func:`stage` costs one module-global bool check and
 :func:`sync` is a no-op — no extra device synchronization is added to
-the dispatch path, which is what the ``bench.py profiling_overhead``
-gate (<2%) holds the enabled arm to as well.  Metric updates here are
+the dispatch path (``tests/test_host_stages.py::
+test_disarmed_stage_calls_no_factory_and_reads_no_clock``; the armed
+cost was read on the chip by PR 25, PERF.md section 6).  Metric updates
+here are
 O(1) (a float and a small exemplar tuple) — nothing reads device
 values or reduces arrays on the traced path (holo-lint HL101/HL105).
 """
@@ -128,7 +130,8 @@ _cost_table: dict[tuple, dict] = {}
 
 def set_device_profiling(on: bool) -> None:
     """Arm/disarm the per-dispatch breakdown (daemon boot reads
-    ``[telemetry] profile-device-time``; bench/tests flip it directly)."""
+    ``[telemetry] profile-device-time``; the benchmark's ``--trace 1``
+    and tests flip it directly)."""
     global _enabled
     _enabled = bool(on)
     if _enabled and _annotation is _UNRESOLVED:
@@ -433,7 +436,7 @@ def stage_median(
     (holo_tpu/pipeline/tuner.py): its per-shape-bucket decisions use
     the dispatch walls the backends feed it directly, but a
     fresh bucket with no samples can still consult the process-wide
-    stage distribution, and the bench's tuner rows report both."""
+    stage distribution."""
     child = _STAGE_SECONDS.labels(site=site, stage=stage, device=device)
     total = child.count
     if not total:

@@ -5,8 +5,9 @@ instrumentation, shaped for the TPU hot paths: every metric is a named
 family with optional label dimensions; children are created lazily per
 label-value tuple and updated under a per-child lock (increments are a
 couple of dict hits + a float add, cheap enough for the dispatch path —
-gated by :func:`holo_tpu.telemetry.set_enabled` so the overhead bench
-can A/B a disabled registry).
+gated by :func:`holo_tpu.telemetry.set_enabled`, under which a dispatch
+writes nothing: ``tests/test_telemetry.py::
+test_disabled_registry_dispatch_writes_no_metric_and_opens_no_span``).
 
 Naming convention (documented in COMPONENTS.md):
 
@@ -77,8 +78,8 @@ def _bump_stamp() -> int:
 
 
 def set_enabled(on: bool) -> None:
-    """Global kill switch: disabled metrics become no-ops (the overhead
-    bench's control arm).  Collection still works — values just freeze."""
+    """Global kill switch: disabled metrics become no-ops.  Collection
+    still works — values just freeze."""
     global _enabled
     _enabled = bool(on)
 
@@ -163,9 +164,9 @@ class Gauge:
 
     @property
     def value(self) -> float:
-        # The kill switch covers callback-backed gauges too: the
-        # overhead bench's disabled arm must not run deferred O(N)
-        # sampling closures at collect time.
+        # The kill switch covers callback-backed gauges too: a disabled
+        # registry must not run deferred O(N) sampling closures at
+        # collect time.
         if self._fn is not None and _enabled:
             try:
                 return float(self._fn())
@@ -445,7 +446,8 @@ class MetricsRegistry:
 
     def snapshot(self, prefix: str | None = None) -> dict:
         """Flat JSON-able view: counters/gauges -> number, histograms ->
-        {count, sum} — what bench stages attach to their emitted rows."""
+        {count, sum} — what the benchmark, the smoke and the tests read
+        counters through."""
         out: dict = {}
         for fam in self.families():
             if prefix is not None and not fam.name.startswith(prefix):

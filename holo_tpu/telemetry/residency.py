@@ -161,8 +161,8 @@ del _p
 
 
 def snapshot() -> dict:
-    """The ``holo-telemetry/device-residency`` gNMI leaf payload (and
-    the bench's residency rows): per-plane bytes/entries + the total."""
+    """The ``holo-telemetry/device-residency`` gNMI leaf payload:
+    per-plane bytes/entries + the total."""
     rows = _rows()
     return {
         "total-bytes": sum(r["bytes"] for r in rows.values()),

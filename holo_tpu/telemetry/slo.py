@@ -62,8 +62,10 @@ machinery and file as stage- and phase-level ones.
 
 Armed/disarmed contract: off by default; every seam costs one
 module-global ``None`` check while disarmed (poisoned-clock tests in
-``tests/test_slo.py`` prove no clock read); armed overhead is gated
-<2% by ``bench.py slo_overhead``.  No locks on the feeding threads —
+``tests/test_slo.py`` prove no clock read:
+``test_disarmed_seams_are_one_global_check``,
+``test_disarmed_pipeline_path_never_reads_slo_clock``).  No locks on the
+feeding threads —
 bucket dicts mutate under the GIL (the DDSketch lock-free contract,
 see observatory.py).
 """
@@ -391,7 +393,7 @@ class SloEngine:
         """Force one sentinel pass over every objective NOW, trim the
         bucket tails, and seed latency ``slo.<objective>`` rows through
         the dispatch observatory's baseline machinery (when armed) —
-        the bench/explain bracket, same discipline as
+        the explain CLI's bracket, same discipline as
         ``Observatory.checkpoint``."""
         from holo_tpu.telemetry import observatory
 
@@ -501,7 +503,7 @@ class SloEngine:
         return out
 
     def objective(self, name: str) -> _ObjState | None:
-        """Test/bench surface: the state for one objective."""
+        """Test/CLI surface: the state for one objective."""
         return self._states.get(name)
 
 

@@ -8,8 +8,8 @@ bounded — a long-running daemon keeps the most recent ``capacity``
 spans, never unbounded memory.
 
 ``HOLO_TPU_TRACE_DUMP=<path>`` (checked at package import) registers an
-atexit dump of the default tracer, so any run — bench stage, test,
-daemon — can be traced without code changes.
+atexit dump of the default tracer, so any run — test, daemon,
+benchmark cell — can be traced without code changes.
 """
 
 from __future__ import annotations

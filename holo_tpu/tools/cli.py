@@ -859,7 +859,7 @@ def cmd_lint(args) -> int:
     if args.json:
         doc = {
             # Bump schema_version whenever a field is added/renamed so
-            # the sentinel ledger (BENCH observatory) can gate its
+            # a reader of this document can gate its
             # parser instead of silently misreading lint telemetry.
             # v3: adds the "audit" block (HL3xx jaxpr kernel audit).
             "schema_version": 3,

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
 Called once, before the first dispatch, by every process that
-dispatches to the device (``daemon.main``, ``bench.py`` stage children,
+dispatches to the device (``daemon.main``, ``benchmark.run``,
 ``chip_smoke.main``, ``__graft_entry__``) — never by the test suite.
 
 ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads that variable itself and

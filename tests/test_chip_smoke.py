@@ -103,32 +103,3 @@ def test_compile_cache_default_is_one_fixed_checkout_path():
     for proc in runs:
         assert proc.returncode == 0, proc.stderr
         assert proc.stdout.split() == [str(REPO / ".jax_cache")] * 2
-
-
-def test_bench_stage_refuses_without_tpu_and_parent_stays_off_jax():
-    no_tpu = _python("bench.py", "--stage", "cpu100", "--small")
-    assert no_tpu.returncode != 0 and "not 'tpu'" in no_tpu.stderr
-    on_cpu = _python("bench.py", "--stage", "cpu100", "--small", "--cpu")
-    assert on_cpu.returncode == 0, on_cpu.stderr
-    parent = _python(
-        "-c", "import sys, bench; assert 'jax' not in sys.modules"
-    )
-    assert parent.returncode == 0, parent.stderr
-    src = (REPO / "bench.py").read_text()
-    for gone in ("_cpufallback", "_DOWN", "_probe_once",
-                 "_device_responsive"):
-        assert gone not in src
-
-
-def test_bench_exits_nonzero_when_its_headline_failed(monkeypatch, capsys):
-    import bench
-
-    monkeypatch.setattr(
-        bench, "_run_stage", lambda *a, **k: {"ok": False, "error": "no chip"}
-    )
-    monkeypatch.setattr(bench, "_apply_bench_ledger", lambda *a, **k: {})
-    monkeypatch.setattr(sys, "argv", ["bench.py", "--small"])
-    with pytest.raises(SystemExit) as exc:
-        bench.main()
-    assert exc.value.code == 1
-    assert "_FAILED" in capsys.readouterr().out

@@ -196,6 +196,28 @@ def test_overload_strike_delta():
     assert count(after, "incremental") > count(before, "incremental")
 
 
+def test_topology_without_lineage_never_enters_the_delta_path():
+    """The no-delta steady state: a topology that carries no
+    ``link_delta`` lineage books no DeltaPath disposition at all (not
+    even a ``full-*`` fallback), and repeated dispatches of it keep ONE
+    retained seed set — the same object, not a fresh one per dispatch.
+    A backend with ``incremental=False`` retains none."""
+    topo = random_ospf_topology(n_routers=12, n_networks=2, seed=4)
+    assert topo.delta_base is None
+    be = TpuSpfBackend(N_ATOMS)
+    snap0 = delta_snapshot()
+    first = be.compute(topo)
+    [seed] = be._prev_one.values()
+    for _ in range(3):
+        assert_results_equal(first, be.compute(topo))
+    assert delta_snapshot() == snap0
+    [kept] = be._prev_one.values()
+    assert kept is seed
+    off = TpuSpfBackend(N_ATOMS, incremental=False)
+    assert_results_equal(first, off.compute(topo))
+    assert not off._prev_one and delta_snapshot() == snap0
+
+
 def test_empty_delta_reuses_resident_graph_without_marshal():
     """A content-identical rebuild (LSA refresh with no topology change)
     produces an empty delta: the resident graph is aliased under the

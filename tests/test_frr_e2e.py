@@ -17,7 +17,7 @@ from holo_tpu.testing import no_implicit_transfers
 def _transfer_sanitizer():
     """E2E repair paths run under jax.transfer_guard('disallow') too —
     a protocol-layer change that smuggles a device sync outside the
-    sanctioned FRR/SPF boundaries must fail here, not on a bench."""
+    sanctioned FRR/SPF boundaries must fail here, not on the chip."""
     with no_implicit_transfers():
         yield
 from holo_tpu.protocols.ospf.instance import (

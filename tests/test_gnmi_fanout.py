@@ -212,8 +212,7 @@ def test_late_joiner_first_notification_is_full_sync():
 def test_engine_output_byte_identical_to_legacy_walk_path():
     """The shared-render path and the legacy ``_SubSampler`` walk path
     stepped over the SAME state sequence at the SAME times produce
-    byte-identical notification streams (the fallback contract the
-    bench gnmi_fanout stage gates end to end)."""
+    byte-identical notification streams (the fallback contract)."""
     svc = gs.GnmiService(daemon=None, shared_fanout=False)
     svc._clock_ns = lambda: 777_000
     for suppress, heartbeat_ns in (

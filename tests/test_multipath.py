@@ -87,7 +87,7 @@ def test_multipath_device_bit_identical_to_oracle():
 
 def test_multipath_k1_is_the_unchanged_single_parent_dispatch():
     """multipath off (k=1): no planes, and byte-identical output to the
-    pre-change call shape — the multipath_overhead gate's contract."""
+    pre-change call shape."""
     with no_implicit_transfers():
         tpu = TpuSpfBackend()
         topo = tied(9)

@@ -6,8 +6,7 @@ on (relative-error quantiles, merge associativity, byte-identical
 serialization); the integration tests drive the REAL dispatch path —
 ``TpuSpfBackend`` / ``FrrEngine`` under the armed observer — including
 the fault-injected dispatch delay the sentinel must flag within one
-storm, and the structural "disarmed path is one global check" gate the
-``bench.py observatory_overhead`` stage's <2% paired-median rides on.
+storm, and the structural "disarmed path is one global check" gate.
 """
 
 from __future__ import annotations
@@ -198,8 +197,7 @@ def test_observe_skips_per_device_skew_rows():
 
 def test_disarmed_path_is_one_global_check():
     # Disarmed + unprofiled, stage() must return before its first
-    # timer read — the structural form of the observatory_overhead
-    # gate's "disarmed cost is one global check per observe".
+    # timer read: the disarmed cost is one global check per observe.
     assert observatory.active() is None
     assert not profiling.observing()
 

@@ -127,7 +127,7 @@ def test_partitioned_solve_bit_identical_across_random_cuts():
 def test_partitioned_backend_matches_monolithic_and_oracle():
     """Backend-level: a partition-armed backend, the monolithic device
     backend, and the scalar oracle agree bit-for-bit (the digest-parity
-    contract bench gates on)."""
+    contract)."""
     mono = TpuSpfBackend()
     part = TpuSpfBackend(partition_threshold=1, partition_max_part=12)
     oracle = ScalarSpfBackend()

@@ -346,12 +346,11 @@ def test_pipeline_dispatch_crashpoint_mid_storm_bit_identical_fibs():
 
 
 def test_async_storm_digest_matches_sync_and_scalar():
-    """Clean storm tri-parity (the bench pipeline_spf gate at test
-    scale): the async-pipelined arm's causal timeline digest is
-    byte-identical to the synchronous device arm's — pipelining must
-    not reorder, drop, or re-attribute a single causal step — and the
-    final FIBs of all THREE arms (async / sync / all-scalar) are
-    identical.  (The scalar arm's causal digest legitimately differs:
+    """Clean storm tri-parity: the async-pipelined arm's causal
+    timeline digest is byte-identical to the synchronous device arm's —
+    pipelining must not reorder, drop, or re-attribute a single causal
+    step — and the final FIBs of all THREE arms (async / sync /
+    all-scalar) are identical.  (The scalar arm's causal digest legitimately differs:
     its dispatch entries record mode=scalar, which is the point of the
     attribution.)"""
     from holo_tpu.spf.synth_storm import run_convergence_storm

@@ -346,7 +346,7 @@ def test_breaker_open_mid_storm_tags_fallback_latencies():
             any(step == "fallback" for step, _t, _a in r["timeline"])
             for r in fallbacks
         )
-        # The histogram split the storm bench reports on.
+        # The histogram split the storm report reads.
         hist = telemetry_registry_hist()
         assert hist.labels(trigger="lsa", phase="fallback").count > 0
     finally:

@@ -147,8 +147,8 @@ def test_sharded_multiroot_parity():
 
 
 def test_one_device_mesh_matches_plain_path():
-    """The sharding_overhead gate's configuration: a 1-device mesh runs
-    the mesh-aware code path and must produce the plain path's bits."""
+    """A 1-device mesh (what a one-chip host boots with) runs the
+    mesh-aware code path and must produce the plain path's bits."""
     import jax
 
     topo = _topo(seed=5)
@@ -159,6 +159,36 @@ def test_one_device_mesh_matches_plain_path():
             got = TpuSpfBackend().compute_whatif(topo, masks)
     for p, s in zip(plain, got):
         assert_spf_equal(p, s)
+
+
+def test_without_a_mesh_no_sharded_program_is_built_and_the_shard_seam_is_skipped():
+    """The default daemon has no ``[parallel]`` mesh: every dispatch
+    kind then takes the single-device branch — no sharded jit is built,
+    no shard dispatch is counted, and the ``spf.shard`` chaos seam is
+    never reached (an armed failure there stays unconsumed)."""
+    from holo_tpu.resilience.faults import FaultPlan, inject
+
+    assert process_mesh() is None
+    topo = _topo(seed=7)
+    masks = whatif_link_failure_masks(topo, n_scenarios=4, seed=2)
+    roots = np.arange(4, dtype=np.int32)
+    be, oracle = TpuSpfBackend(), ScalarSpfBackend()
+    counts = {k: shard_count(k) for k in ("one", "whatif", "multiroot")}
+    with no_implicit_transfers():
+        with inject(FaultPlan(dispatch_fail={"spf.shard": 1})) as inj:
+            one = be.compute(topo)
+            batch = be.compute_whatif(topo, masks)
+            multi = be.compute_multiroot(topo, roots)
+    assert "spf.shard" not in inj.injected
+    assert be._shard_jits == {}
+    assert {k: shard_count(k) for k in counts} == counts
+    assert be.breaker.consecutive_failures == 0
+    assert_spf_equal(oracle.compute(topo), one)
+    for ref, got in zip(oracle.compute_whatif(topo, masks), batch):
+        assert_spf_equal(ref, got)
+    np.testing.assert_array_equal(
+        oracle.compute_multiroot(topo, roots).dist, multi.dist
+    )
 
 
 # -- DeltaPath composes with sharding ------------------------------------

@@ -173,6 +173,57 @@ def test_forced_dispatch_failure_scalar_fallback_bit_identical():
     assert be.breaker.consecutive_failures == 0
 
 
+def test_closed_breaker_costs_a_healthy_dispatch_two_clock_reads_and_no_oracle():
+    """The healthy path of the guard: over N device dispatches a closed
+    breaker reads its clock twice per dispatch (the deadline window)
+    and nothing else — the scalar oracle is never called, no transition
+    or failure is emitted, and the circuit ends closed with no streak.
+    Bypassed (``enabled=False``) it reads no clock at all."""
+    from holo_tpu import telemetry
+    from holo_tpu.resilience import CircuitBreaker
+
+    reads = []
+
+    def clock():
+        reads.append(1)
+        return 0.0
+
+    def poisoned(*_a, **_k):
+        raise AssertionError("scalar oracle called on the healthy path")
+
+    def breaker_series():
+        return {
+            k: v
+            for fam in ("holo_resilience_breaker_transitions_total",
+                        "holo_resilience_breaker_failures_total",
+                        "holo_resilience_fallback_total")
+            for k, v in telemetry.snapshot(prefix=fam).items()
+        }
+
+    topo = random_ospf_topology(n_routers=14, n_networks=4, seed=5)
+    ref = ScalarSpfBackend(N_ATOMS).compute(topo)
+    be = TpuSpfBackend(
+        N_ATOMS, breaker=CircuitBreaker("spf-parity-healthy", clock=clock)
+    )
+    be._oracle.compute = poisoned
+    before = breaker_series()
+    for _ in range(5):
+        assert_parity(topo, ref, be.compute(topo))
+    assert len(reads) == 2 * 5
+    assert breaker_series() == before
+    assert be.breaker.state == "closed"
+    assert be.breaker.consecutive_failures == 0
+    assert be.breaker.last_error is None
+
+    bypass = TpuSpfBackend(
+        N_ATOMS,
+        breaker=CircuitBreaker(
+            "spf-parity-bypass", clock=poisoned, enabled=False
+        ),
+    )
+    assert_parity(topo, ref, bypass.compute(topo))
+
+
 def test_multiroot_matches_per_root():
     topo = random_ospf_topology(n_routers=12, n_networks=3, seed=7)
     roots = np.array(

@@ -1,4 +1,4 @@
-"""Protocol-marshaled bench topologies (BASELINE configs 2+3): the
+"""Protocol-marshaled topologies (BASELINE configs 2+3): the
 builders go through the real instance marshal paths and the engine
 reproduces the scalar result bit-identically."""
 

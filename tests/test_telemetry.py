@@ -185,6 +185,39 @@ def test_spf_dispatch_recompile_counter_flat():
     )
 
 
+def test_disabled_registry_dispatch_writes_no_metric_and_opens_no_span():
+    """The kill switch on the real dispatch: a warm SPF through
+    ``TpuSpfBackend`` with telemetry disabled stamps no registry write,
+    moves no ``holo_spf_*`` value, records no span and never reads the
+    tracer's clock — with the same bits as the enabled dispatch."""
+    from holo_tpu.spf.backend import TpuSpfBackend
+    from holo_tpu.spf.synth import grid_topology
+
+    def poisoned():
+        raise AssertionError("span clock read with telemetry disabled")
+
+    topo = grid_topology(4, 4, seed=2)
+    backend = TpuSpfBackend()
+    ref = backend.compute(topo)  # the compile, and its counters, land here
+    tracer = telemetry.tracer()
+    saved_clock = tracer.clock
+    telemetry.set_enabled(False)
+    tracer.clock = poisoned
+    try:
+        stamp = telemetry.write_stamp()
+        values = telemetry.snapshot(prefix="holo_spf")
+        spans = len(tracer.spans())
+        got = backend.compute(topo)
+        assert telemetry.write_stamp() == stamp
+        assert telemetry.snapshot(prefix="holo_spf") == values
+        assert len(tracer.spans()) == spans
+    finally:
+        tracer.clock = saved_clock
+        telemetry.set_enabled(True)
+    assert np.array_equal(got.dist, ref.dist)
+    assert np.array_equal(got.nexthop_words, ref.nexthop_words)
+
+
 # -- RIB churn + FRR flip counters
 
 
