@@ -186,13 +186,31 @@ class EllGraph(NamedTuple):
         return self.in_src.shape[1]
 
 
+def lookup_sorted(keys: np.ndarray, wanted: np.ndarray):
+    """``dict.get`` on arrays: per element of ``wanted`` its index in the
+    ascending ``keys`` (of equal keys the last, as a dict comprehension
+    over them leaves it) and whether it is there at all.  The search
+    runs over ``wanted`` in ascending order too: a binary search per
+    random element is mostly mispredicted branches, and sorting 26,000
+    int64 first takes a third of the time it saves."""
+    if not len(keys):
+        return np.zeros(len(wanted), np.int64), np.zeros(len(wanted), bool)
+    order = np.argsort(wanted)
+    at = np.empty(len(wanted), np.int64)
+    at[order] = np.searchsorted(keys, wanted[order], side="right") - 1
+    return at, (at >= 0) & (keys[at] == wanted)
+
+
 def mutual_keep_mask(edge_src, edge_dst) -> np.ndarray:
     """bool[E]: edge has a reverse edge (the single bidirectionality rule
-    shared by every protocol's marshaling path)."""
+    shared by every protocol's marshaling path).  Vertex indices are
+    int32, as :class:`Topology` holds them."""
     src = np.asarray(edge_src)
     dst = np.asarray(edge_dst)
-    fwd = set(zip(src.tolist(), dst.tolist()))
-    return np.array([(d, s) in fwd for s, d in zip(src, dst)], dtype=bool)
+    # One int64 key per (src, dst) pair: an edge stays when the key of
+    # its reverse pair is among the forward keys.
+    fwd = np.sort(_pack_i32_pairs(src, dst))
+    return lookup_sorted(fwd, _pack_i32_pairs(dst, src))[1]
 
 
 def _round_up(x: int, m: int) -> int:

@@ -90,7 +90,7 @@ from holo_tpu.protocols.ospf.packet import (
     RouterLinkType,
 )
 from holo_tpu.protocols.ospf.spf_run import (
-    build_topology,
+    LoweredLsdb,
     derive_routes,
     link_spf_delta,
     reachable_router_flags,
@@ -382,6 +382,9 @@ class OspfInstance(Actor):
         # DeltaPath: the previous full run's marshaled SpfTopology per
         # area — the diff base for incremental device-graph updates.
         self._spf_delta_bases: dict = {}
+        # Beside it, the area's LSDB as build_topology lowered it last
+        # (spf_run.LoweredLsdb): the next run lowers only what changed.
+        self._spf_lowerings: dict = {}
         # Hierarchical partition hint (ISSUE 15): router-id -> group
         # label, stamped onto Topology.partition_hint at marshal time
         # (spf_run.apply_partition_hint) so the partitioned-SPF path
@@ -2853,7 +2856,14 @@ class OspfInstance(Actor):
                     for i in area.interfaces.values()
                     if i.config.srlg
                 }
-                st = build_topology(
+                # The area's lowered LSDB lives across runs: only the
+                # LSAs installed since the last run are lowered again.
+                lowering = self._spf_lowerings.get(area.area_id)
+                if lowering is None:
+                    lowering = self._spf_lowerings[area.area_id] = (
+                        LoweredLsdb()
+                    )
+                st = lowering.build_topology(
                     area.lsdb, self.config.router_id, now, iface_by_addr,
                     iface_by_nbr, p2p_nbr_addr, iface_by_ifindex,
                     vlink_nexthops, iface_srlg=iface_srlg,
@@ -2861,6 +2871,7 @@ class OspfInstance(Actor):
                 )
             if st is None:
                 self._spf_delta_bases.pop(area.area_id, None)
+                self._spf_lowerings.pop(area.area_id, None)
                 continue
             # DeltaPath seam: diff against the previous run's marshaled
             # topology so the backend can update the device-resident

@@ -6,7 +6,9 @@ Bridges the protocol LSDB to the tensor/scalar SPF backends:
   :class:`~holo_tpu.ops.graph.Topology` (vertex model of RFC 2328 §16.1,
   ordering contract of holo_tpu.ops.graph), assigning next-hop atoms for
   exactly the parent-hops==0 cases (reference calc_nexthops,
-  holo-ospf/src/ospfv2/spf.rs:172-…).
+  holo-ospf/src/ospfv2/spf.rs:172-…).  :class:`LoweredLsdb` is the
+  lowering an area keeps between SPF runs, so that a run lowers only
+  the LSAs installed since the last one.
 - :func:`derive_routes` turns backend results (distances + ECMP atom
   bitmasks) into per-prefix intra-area routes (reference
   route::update_rib_full, holo-ospf/src/route.rs:146-197).
@@ -14,16 +16,23 @@ Bridges the protocol LSDB to the tensor/scalar SPF backends:
 
 from __future__ import annotations
 
+import operator
 from dataclasses import dataclass
 from ipaddress import IPv4Address, IPv4Network
 
 import numpy as np
 
 from holo_tpu import telemetry
-from holo_tpu.ops.graph import DELTA_MAX_OPS, INF, Topology
+from holo_tpu.ops.graph import (
+    DELTA_MAX_OPS,
+    INF,
+    Topology,
+    lookup_sorted,
+    mutual_keep_mask,
+)
 from holo_tpu.protocols.ospf.lsdb import Lsdb
 from holo_tpu.protocols.ospf.packet import (
-    LsaNetwork,
+    MAX_AGE,
     LsaRouter,
     LsaType,
     RouterFlags,
@@ -64,9 +73,9 @@ def apply_interface_srlg(
     if not srlg_of_ifname:
         return
     srlg = np.zeros(topo.n_edges, np.uint32)
-    for e in range(topo.n_edges):
+    for e in np.flatnonzero(topo.edge_direct_atom >= 0).tolist():
         a = int(topo.edge_direct_atom[e])
-        if a < 0 or a >= len(atom_ifnames):
+        if a >= len(atom_ifnames):
             continue
         ifn = atom_ifnames[a]
         if ifn is not None:
@@ -130,6 +139,404 @@ class SpfTopology:
     network_index: dict[IPv4Address, int]
 
 
+_TOPOLOGY_LSAS = telemetry.counter(
+    "holo_ospf_topology_lsas_total",
+    "build_topology's LSDB entries by how their lowered segment was had: "
+    "lowered from the LSA (an entry the kept lowering had not seen at "
+    "that place), or reused from the previous call",
+    ("path",),
+)
+
+# What a lowered entry is, and what a lowered link's neighbour id names:
+# a router id (p2p, virtual link, a network-LSA's attached router) or a
+# DR interface address (a router-LSA's transit link).
+_OTHER, _ROUTER, _NETWORK = 0, 1, 2
+_P2P, _VLINK, _TRANSIT, _ATTACHED = 0, 1, 2, 3
+
+
+def _spliced(old: np.ndarray, runs, parts) -> np.ndarray:
+    """``old`` with rows ``[lo, hi)`` replaced by ``part``, for each
+    ``(lo, hi)`` of ``runs`` (ascending, disjoint) and its ``part``."""
+    out, at = [], 0
+    for (lo, hi), part in zip(runs, parts):
+        out += (old[at:lo], part)
+        at = hi
+    out.append(old[at:])
+    return np.concatenate(out)
+
+
+class _VertexIds:
+    """The live LSAs of one type by vertex id, as a dict keyed by id
+    holds them after a walk in LSDB order.  All index ``ids``."""
+
+    def __init__(self, ids: np.ndarray) -> None:
+        self.ids = ids  # LSDB order
+        # Vertex order: ascending id, equal ids in LSDB order.
+        self.order = np.argsort(ids, kind="stable")
+        by_id = ids[self.order]
+        first = np.ones(len(ids), bool)
+        first[1:] = by_id[1:] != by_id[:-1]
+        # Of equal ids the dict keeps the LAST LSA's body, at the place
+        # the id was FIRST inserted: the segments to emit, in order ...
+        lasts = self.order[np.roll(first, -1)]
+        self.emit = lasts[np.argsort(self.order[first])]
+        # ... and the body behind every vertex.
+        self.body = lasts[np.cumsum(first) - 1]
+
+
+@dataclass
+class _VertexModel:
+    """Everything that follows from the live vertex ids alone: kept
+    while they stay what they were."""
+
+    rtr: _VertexIds
+    net: _VertexIds
+    routers: list[IPv4Address]
+    networks: list[IPv4Address]  # keyed by DR interface address (lsid)
+    router_index: dict[IPv4Address, int]
+    network_index: dict[IPv4Address, int]
+    # Both index dicts as one sorted array: key 2 id + (1 for a router),
+    # and the vertex behind each key.
+    keys: np.ndarray
+    vertex_at: np.ndarray
+    emit_vertex: np.ndarray  # source vertex per emitted segment
+
+
+class LoweredLsdb:
+    """One area's LSDB lowered to flat arrays, kept between SPF runs.
+
+    Per LSDB entry, in the LSDB's iteration order: what it is, its
+    vertex id as an integer (a router-LSA's advertising router, a
+    network-LSA's link-state id), the two terms of its age, and its
+    segment of link rows ``(kind, neighbour id, metric, link data)``,
+    one per link that can become an edge.  Segments hold ids, not
+    vertex indices, so they outlive a change of the vertex set.
+
+    ``Lsdb.install`` builds a new ``LsaEntry`` per install and nothing
+    edits one in place, so what changed since the last call is found by
+    identity: :meth:`build_topology` walks ``lsdb.entries`` beside
+    ``entries`` and lowers only the entries that are not the kept
+    object at their place (all from the first difference on, where the
+    length changed).  No journal and no hook in the LSDB: any way of
+    writing ``lsdb.entries`` is seen.
+
+    ``router_bodies`` is the live router-LSA bodies in vertex order as
+    of the last call (``router_bodies[i]`` is vertex ``n_networks + i``).
+    """
+
+    #: the per-entry arrays, in the order ``_lower`` returns them
+    _COLUMNS = ("_kind", "_vid", "_age", "_installed_at", "_n_links")
+
+    def __init__(self) -> None:
+        self.entries: list = []
+        self._bodies: list = []  # per entry; None but for the two types
+        self._kind = np.zeros(0, np.int8)
+        self._vid = np.zeros(0, np.int64)
+        self._age = np.zeros(0, np.float64)
+        self._installed_at = np.zeros(0, np.float64)
+        self._n_links = np.zeros(0, np.int64)
+        self._links = np.zeros((0, 4), np.int64)
+        self._link_off = np.zeros(1, np.int64)
+        self._model: _VertexModel | None = None
+        self.router_bodies: list[LsaRouter] = []
+
+    @staticmethod
+    def _lower(entries) -> tuple:
+        """Lower a run of LSDB entries: the per-entry ``_COLUMNS``, the
+        link rows of all of them, and their bodies."""
+        kind, vid, age, installed_at, n_links, links, bodies = (
+            [], [], [], [], [], [], []
+        )
+        for e in entries:
+            lsa = e.lsa
+            k, v, body, n0 = _OTHER, 0, None, len(links)
+            if lsa.type == LsaType.ROUTER:
+                k, v, body = _ROUTER, int(lsa.adv_rtr), lsa.body
+                for link in body.links:
+                    lt = link.link_type
+                    if lt == RouterLinkType.POINT_TO_POINT:
+                        lk = _P2P
+                    elif lt == RouterLinkType.TRANSIT_NETWORK:
+                        lk = _TRANSIT
+                    elif lt == RouterLinkType.VIRTUAL_LINK:
+                        # Virtual links are router-router edges whose
+                        # cost is the transit-area distance (§15); for
+                        # SPF they behave as p2p.
+                        lk = _VLINK
+                    else:
+                        continue
+                    links.append(
+                        (lk, int(link.id), link.metric, int(link.data))
+                    )
+            elif lsa.type == LsaType.NETWORK:
+                k, v, body = _NETWORK, int(lsa.lsid), lsa.body
+                for rid in body.attached:
+                    links.append((_ATTACHED, int(rid), 0, 0))
+            kind.append(k)
+            vid.append(v)
+            age.append(lsa.age)
+            installed_at.append(e.installed_at)
+            n_links.append(len(links) - n0)
+            bodies.append(body)
+        return (
+            (
+                np.array(kind, np.int8),
+                np.array(vid, np.int64),
+                np.array(age, np.float64),
+                np.array(installed_at, np.float64),
+                np.array(n_links, np.int64),
+            ),
+            np.array(links, np.int64).reshape(-1, 4),
+            bodies,
+        )
+
+    def _refresh(self, lsdb: Lsdb) -> None:
+        """Bring the lowering up to ``lsdb``: lower the entries that are
+        not the kept object at their place, splice their segments in."""
+        cur = list(lsdb.entries.values())
+        kept = self.entries
+        stale = [
+            i for i, same in enumerate(map(operator.is_, kept, cur))
+            if not same
+        ]
+        if len(kept) != len(cur):
+            # A changed length: everything from the first difference on.
+            first = stale[0] if stale else min(len(kept), len(cur))
+            runs = [(first, len(kept))]
+            fresh = [cur[first:]]
+        else:
+            runs = []
+            for i in stale:
+                if runs and runs[-1][1] == i:
+                    runs[-1] = (runs[-1][0], i + 1)
+                else:
+                    runs.append((i, i + 1))
+            fresh = [cur[lo:hi] for lo, hi in runs]
+        lowered = sum(len(f) for f in fresh)
+        _TOPOLOGY_LSAS.labels(path="lowered").inc(lowered)
+        _TOPOLOGY_LSAS.labels(path="reused").inc(len(cur) - lowered)
+        if not runs:
+            return
+        parts = [self._lower(f) for f in fresh]
+        off = self._link_off
+        self._links = _spliced(
+            self._links, [(off[lo], off[hi]) for lo, hi in runs],
+            [links for _cols, links, _bodies in parts],
+        )
+        for col, name in enumerate(self._COLUMNS):
+            setattr(self, name, _spliced(
+                getattr(self, name), runs, [p[0][col] for p in parts]
+            ))
+        for (lo, hi), (_cols, _links, bodies) in zip(
+            reversed(runs), reversed(parts)
+        ):
+            self._bodies[lo:hi] = bodies
+        self.entries = cur
+        self._link_off = np.concatenate(([0], np.cumsum(self._n_links)))
+
+    def _vertex_model(self, r_pos, n_pos) -> _VertexModel:
+        """The vertex model of the live router and network LSAs at
+        entries ``r_pos`` / ``n_pos``: last call's object, index dicts
+        and all, when the ids in LSDB order are last call's."""
+        r_ids, n_ids = self._vid[r_pos], self._vid[n_pos]
+        m = self._model
+        if (
+            m is not None
+            and np.array_equal(m.rtr.ids, r_ids)
+            and np.array_equal(m.net.ids, n_ids)
+        ):
+            return m
+        rtr, net = _VertexIds(r_ids), _VertexIds(n_ids)
+        # Vertex ordering contract: Network < Router (ospfv2/spf.rs:42-45).
+        networks = [
+            self.entries[i].lsa.lsid for i in n_pos[net.order].tolist()
+        ]
+        routers = [
+            self.entries[i].lsa.adv_rtr for i in r_pos[rtr.order].tolist()
+        ]
+        keys = np.concatenate(
+            (2 * n_ids[net.order], 2 * r_ids[rtr.order] + 1)
+        )
+        vertex_at = np.argsort(keys, kind="stable")
+        keys = keys[vertex_at]
+        emit_keys = np.concatenate(
+            (2 * r_ids[rtr.emit] + 1, 2 * n_ids[net.emit])
+        )
+        m = self._model = _VertexModel(
+            rtr, net, routers, networks,
+            router_index={
+                r: len(networks) + i for i, r in enumerate(routers)
+            },
+            network_index={a: i for i, a in enumerate(networks)},
+            keys=keys, vertex_at=vertex_at,
+            emit_vertex=vertex_at[lookup_sorted(keys, emit_keys)[0]],
+        )
+        return m
+
+    def build_topology(
+        self,
+        lsdb: Lsdb,
+        router_id: IPv4Address,
+        now: float,
+        iface_by_addr: dict[IPv4Address, str],
+        iface_by_nbr: dict[IPv4Address, tuple[str, IPv4Address]],
+        p2p_nbr_addr: dict[tuple, IPv4Address] | None = None,
+        iface_by_ifindex: dict[int, str] | None = None,
+        vlink_nexthops: dict | None = None,
+        iface_srlg: dict[str, int] | None = None,
+        partition_of: dict | None = None,
+    ) -> SpfTopology | None:
+        """:func:`build_topology` through this kept lowering."""
+        self._refresh(lsdb)
+        # MaxAge LSAs are excluded (RFC 2328 §16.1 note): the expression
+        # LsaEntry.current_age computes, element for element.
+        live = ~(self._age + (now - self._installed_at) >= MAX_AGE)
+        r_pos = np.flatnonzero(live & (self._kind == _ROUTER))
+        n_pos = np.flatnonzero(live & (self._kind == _NETWORK))
+        m = self._vertex_model(r_pos, n_pos)
+        routers, networks = m.routers, m.networks
+        nn = len(networks)
+        n = nn + len(routers)
+        bodies = self._bodies
+        self.router_bodies = [bodies[i] for i in r_pos[m.rtr.body].tolist()]
+        root = m.router_index.get(router_id)
+        if root is None:
+            return None  # no self LSA yet (reference: SpfRootNotFound)
+        is_router = np.zeros(n, bool)
+        is_router[nn:] = True
+
+        # Every link row of every emitted segment: router-LSAs before
+        # network-LSAs, each in LSDB order, links in LSA order.
+        seg = np.concatenate((r_pos[m.rtr.emit], n_pos[m.net.emit]))
+        count = self._n_links[seg]
+        end = np.cumsum(count)
+        rows = np.repeat(self._link_off[seg] - (end - count), count)
+        rows += np.arange(len(rows))
+        kind, nbr, metric, data = self._links[rows].T
+        src = np.repeat(m.emit_vertex, count)
+        # A link becomes an edge when its far end is a live vertex ...
+        at, there = lookup_sorted(m.keys, 2 * nbr + (kind != _TRANSIT))
+        dst = m.vertex_at[at]
+        edge = np.flatnonzero(there)
+        # ... and the far end links back (bidirectionality check,
+        # spf.rs:653-664).
+        edge = edge[mutual_keep_mask(src[edge], dst[edge])]
+        kind, nbr, data = kind[edge], nbr[edge], data[edge]
+        topo = Topology(
+            n_vertices=n,
+            is_router=is_router,
+            edge_src=src[edge].astype(np.int32),
+            edge_dst=dst[edge].astype(np.int32),
+            edge_cost=metric[edge].astype(np.int32),
+            root=root,
+        )
+
+        # Next-hop atoms: edges out of the root, and edges out of root-adjacent
+        # transit networks (the hops==0 direct-calculation cases).
+        atoms: list[NexthopAtom] = []
+        atom_ids = np.full(topo.n_edges, -1, np.int32)
+        root_nets: list[int] = []
+        # Map vertex index -> transit our-iface (for root->net edges).
+        net_if: dict[int, str] = {}
+        for link in self.router_bodies[root - nn].links:
+            if link.link_type == RouterLinkType.TRANSIT_NETWORK:
+                vi = m.network_index.get(link.id)
+                if vi is not None:
+                    ifname = iface_by_addr.get(link.data)
+                    if ifname is not None:
+                        net_if[vi] = ifname
+        for e in np.flatnonzero(topo.edge_src == root).tolist():
+            v = int(topo.edge_dst[e])
+            if kind[e] == _VLINK:
+                # Virtual link: next hops borrowed from the transit area's
+                # path to the vlink neighbor (§16.1).
+                expand = (vlink_nexthops or {}).get(IPv4Address(int(nbr[e])))
+                if expand:
+                    atom_ids[e] = len(atoms)
+                    atoms.append(NexthopAtom(None, None, expand))
+                continue
+            # Per-edge link_data (parallel p2p links each resolve to
+            # their own interface).
+            link_data = IPv4Address(int(data[e]))
+            ifname = iface_by_addr.get(link_data)
+            if is_router[v]:
+                # p2p neighbor: the link's own interface (parallel links
+                # each get their own atom), neighbor addr per interface.
+                # Unnumbered links carry the MIB ifIndex in link_data
+                # (RFC 2328 A.4.2) instead of an address.
+                rid = routers[v - nn]
+                if (
+                    ifname is None
+                    and iface_by_ifindex is not None
+                    and int(link_data) < 0x1000000  # 0.x.y.z: never an addr
+                ):
+                    ifname = iface_by_ifindex.get(int(link_data))
+                addr = None
+                if ifname is not None and p2p_nbr_addr is not None:
+                    addr = p2p_nbr_addr.get((ifname, rid))
+                if ifname is not None and addr is not None:
+                    atom_ids[e] = len(atoms)
+                    atoms.append(NexthopAtom(ifname, addr))
+                else:
+                    hop = iface_by_nbr.get(rid)
+                    if hop is not None:
+                        atom_ids[e] = len(atoms)
+                        atoms.append(NexthopAtom(hop[0], hop[1]))
+            else:
+                root_nets.append(v)
+                # Directly-attached transit network: next hop is the
+                # outgoing interface itself (no gateway address).
+                if ifname is not None:
+                    atom_ids[e] = len(atoms)
+                    atoms.append(NexthopAtom(ifname, None))
+        # Edges out of a network vertex end at routers only.
+        for e in np.flatnonzero(
+            np.isin(topo.edge_src, root_nets) & (topo.edge_dst != root)
+        ).tolist():
+            u = int(topo.edge_src[e])
+            # Destination router's address on that network = the link.data
+            # of ITS transit link pointing at this network's DR address.
+            dr_addr = networks[u]
+            ifname = net_if.get(u)
+            if ifname is None:
+                continue
+            for link in self.router_bodies[int(topo.edge_dst[e]) - nn].links:
+                if (
+                    link.link_type == RouterLinkType.TRANSIT_NETWORK
+                    and link.id == dr_addr
+                ):
+                    atom_ids[e] = len(atoms)
+                    atoms.append(NexthopAtom(ifname, link.data))
+                    break
+
+        topo.edge_direct_atom = atom_ids
+        if iface_srlg:
+            # Interface fast-reroute SRLG config -> the edge_srlg seam the
+            # FRR policy masks consume (srlg_disjoint).
+            apply_interface_srlg(
+                topo, [a.ifname for a in atoms], iface_srlg
+            )
+        if partition_of:
+            # Hierarchical partition hint (ISSUE 15): per-router group
+            # labels (config/topology-design groupings the operator knows —
+            # PoPs, rings, sub-area clusters); a transit network rides the
+            # lowest-labeled attached router so zero-cost net->rtr edges
+            # stay intra-partition wherever the grouping allows.
+            groups: list = []
+            for i in n_pos[m.net.body].tolist():
+                att = [
+                    partition_of[r]
+                    for r in bodies[i].attached
+                    if r in partition_of
+                ]
+                groups.append(min(att) if att else None)
+            for rid in routers:
+                groups.append(partition_of.get(rid))
+            apply_partition_hint(topo, groups)
+        topo.touch()
+        return SpfTopology(topo, atoms, m.router_index, m.network_index)
+
+
 def build_topology(
     lsdb: Lsdb,
     router_id: IPv4Address,
@@ -151,211 +558,19 @@ def build_topology(
     resolve through their own interface (the per-link link_data of our
     router LSA selects the interface).
     MaxAge LSAs are excluded (RFC 2328 §16.1 note).
+
+    Edges come in LSDB order, links in LSA order, router-LSAs before
+    network-LSAs: ``build_ell``'s slot assignment and DeltaPath's
+    lineage read that order.  This lowers the whole LSDB; a caller that
+    runs SPF again and again keeps a :class:`LoweredLsdb` per area and
+    calls its ``build_topology`` (same arguments, same result), which
+    lowers only the LSAs installed since its last call.
     """
-    routers: list[IPv4Address] = []
-    networks: list[IPv4Address] = []  # keyed by DR interface address (lsid)
-    rlsa: dict[IPv4Address, LsaRouter] = {}
-    nlsa: dict[IPv4Address, LsaNetwork] = {}
-    for e in lsdb.all():
-        if e.current_age(now) >= 3600:
-            continue
-        lsa = e.lsa
-        if lsa.type == LsaType.ROUTER:
-            rlsa[lsa.adv_rtr] = lsa.body
-            routers.append(lsa.adv_rtr)
-        elif lsa.type == LsaType.NETWORK:
-            nlsa[lsa.lsid] = lsa.body
-            networks.append(lsa.lsid)
-
-    if router_id not in rlsa:
-        return None  # no self LSA yet (reference: SpfRootNotFound)
-
-    # Vertex ordering contract: Network < Router (ospfv2/spf.rs:42-45).
-    networks.sort()
-    routers.sort()
-    network_index = {a: i for i, a in enumerate(networks)}
-    router_index = {r: len(networks) + i for i, r in enumerate(routers)}
-    n = len(networks) + len(routers)
-    is_router = np.zeros(n, bool)
-    is_router[len(networks) :] = True
-
-    src, dst, cost = [], [], []
-    # Per-edge link_data for edges out of the root (parallel p2p links
-    # each resolve to their own interface); vlink edges tracked apart.
-    root_edge_data: dict[int, IPv4Address] = {}
-    root_vlink_edges: dict[int, IPv4Address] = {}  # edge -> nbr router id
-    for rid, body in rlsa.items():
-        u = router_index[rid]
-        for link in body.links:
-            if link.link_type in (
-                RouterLinkType.POINT_TO_POINT,
-                RouterLinkType.VIRTUAL_LINK,
-            ):
-                # Virtual links are router-router edges whose cost is the
-                # transit-area distance (§15); for SPF they behave as p2p.
-                v = router_index.get(link.id)
-                if v is not None:
-                    if rid == router_id:
-                        if link.link_type == RouterLinkType.VIRTUAL_LINK:
-                            root_vlink_edges[len(src)] = link.id
-                        else:
-                            root_edge_data[len(src)] = link.data
-                    src.append(u), dst.append(v), cost.append(link.metric)
-            elif link.link_type == RouterLinkType.TRANSIT_NETWORK:
-                v = network_index.get(link.id)
-                if v is not None:
-                    if rid == router_id:
-                        root_edge_data[len(src)] = link.data
-                    src.append(u), dst.append(v), cost.append(link.metric)
-    for dr_addr, body in nlsa.items():
-        u = network_index[dr_addr]
-        for rid in body.attached:
-            v = router_index.get(rid)
-            if v is not None:
-                src.append(u), dst.append(v), cost.append(0)
-
-    # Mutual-link filter (bidirectionality check, spf.rs:653-664) applied
-    # here with index tracking so root-edge link_data survives filtering.
-    from holo_tpu.ops.graph import mutual_keep_mask
-
-    keep_mask = mutual_keep_mask(
-        np.array(src, np.int32), np.array(dst, np.int32)
-    )
-    keep = [i for i in range(len(src)) if keep_mask[i]]
-    remap = {old: new for new, old in enumerate(keep)}
-    root_edge_data = {
-        remap[i]: d for i, d in root_edge_data.items() if i in remap
-    }
-    root_vlink_edges = {
-        remap[i]: r for i, r in root_vlink_edges.items() if i in remap
-    }
-    topo = Topology(
-        n_vertices=n,
-        is_router=is_router,
-        edge_src=np.array([src[i] for i in keep], np.int32).reshape(-1),
-        edge_dst=np.array([dst[i] for i in keep], np.int32).reshape(-1),
-        edge_cost=np.array([cost[i] for i in keep], np.int32).reshape(-1),
-        root=router_index[router_id],
+    return LoweredLsdb().build_topology(
+        lsdb, router_id, now, iface_by_addr, iface_by_nbr, p2p_nbr_addr,
+        iface_by_ifindex, vlink_nexthops, iface_srlg, partition_of,
     )
 
-    # Next-hop atoms: edges out of the root, and edges out of root-adjacent
-    # transit networks (the hops==0 direct-calculation cases).
-    atoms: list[NexthopAtom] = []
-    atom_ids = np.full(topo.n_edges, -1, np.int32)
-    root = topo.root
-    root_nets: set[int] = set()
-    self_body = rlsa[router_id]
-    # Map vertex index -> transit our-iface (for root->net edges).
-    net_if: dict[int, str] = {}
-    for link in self_body.links:
-        if link.link_type == RouterLinkType.TRANSIT_NETWORK:
-            vi = network_index.get(link.id)
-            if vi is not None:
-                ifname = iface_by_addr.get(link.data)
-                if ifname is not None:
-                    net_if[vi] = ifname
-    for e in range(topo.n_edges):
-        if topo.edge_src[e] == root:
-            v = int(topo.edge_dst[e])
-            if e in root_vlink_edges:
-                # Virtual link: next hops borrowed from the transit area's
-                # path to the vlink neighbor (§16.1).
-                nbr_rid = root_vlink_edges[e]
-                expand = (vlink_nexthops or {}).get(nbr_rid)
-                if expand:
-                    atom_ids[e] = len(atoms)
-                    atoms.append(NexthopAtom(None, None, expand))
-                continue
-            link_data = root_edge_data.get(e)
-            if is_router[v]:
-                # p2p neighbor: the link's own interface (parallel links
-                # each get their own atom), neighbor addr per interface.
-                # Unnumbered links carry the MIB ifIndex in link_data
-                # (RFC 2328 A.4.2) instead of an address.
-                rid = routers[v - len(networks)]
-                ifname = (
-                    iface_by_addr.get(link_data)
-                    if link_data is not None
-                    else None
-                )
-                if (
-                    ifname is None
-                    and link_data is not None
-                    and iface_by_ifindex is not None
-                    and int(link_data) < 0x1000000  # 0.x.y.z: never an addr
-                ):
-                    ifname = iface_by_ifindex.get(int(link_data))
-                addr = None
-                if ifname is not None and p2p_nbr_addr is not None:
-                    addr = p2p_nbr_addr.get((ifname, rid))
-                if ifname is not None and addr is not None:
-                    atom_ids[e] = len(atoms)
-                    atoms.append(NexthopAtom(ifname, addr))
-                else:
-                    hop = iface_by_nbr.get(rid)
-                    if hop is not None:
-                        atom_ids[e] = len(atoms)
-                        atoms.append(NexthopAtom(hop[0], hop[1]))
-            else:
-                root_nets.add(v)
-                # Directly-attached transit network: next hop is the
-                # outgoing interface itself (no gateway address).
-                ifname = (
-                    iface_by_addr.get(link_data)
-                    if link_data is not None
-                    else None
-                )
-                if ifname is not None:
-                    atom_ids[e] = len(atoms)
-                    atoms.append(NexthopAtom(ifname, None))
-        # second pass below needs root_nets complete
-    for e in range(topo.n_edges):
-        u = int(topo.edge_src[e])
-        v = int(topo.edge_dst[e])
-        if u in root_nets and is_router[v] and v != root:
-            # Destination router's address on that network = the link.data
-            # of ITS transit link pointing at this network's DR address.
-            rid = routers[v - len(networks)]
-            dr_addr = networks[u]
-            body = rlsa.get(rid)
-            ifname = net_if.get(u)
-            if body is None or ifname is None:
-                continue
-            for link in body.links:
-                if (
-                    link.link_type == RouterLinkType.TRANSIT_NETWORK
-                    and link.id == dr_addr
-                ):
-                    atom_ids[e] = len(atoms)
-                    atoms.append(NexthopAtom(ifname, link.data))
-                    break
-
-    topo.edge_direct_atom = atom_ids
-    if iface_srlg:
-        # Interface fast-reroute SRLG config -> the edge_srlg seam the
-        # FRR policy masks consume (srlg_disjoint).
-        apply_interface_srlg(
-            topo, [a.ifname for a in atoms], iface_srlg
-        )
-    if partition_of:
-        # Hierarchical partition hint (ISSUE 15): per-router group
-        # labels (config/topology-design groupings the operator knows —
-        # PoPs, rings, sub-area clusters); a transit network rides the
-        # lowest-labeled attached router so zero-cost net->rtr edges
-        # stay intra-partition wherever the grouping allows.
-        groups: list = []
-        for dr_addr in networks:
-            att = [
-                partition_of[r]
-                for r in nlsa[dr_addr].attached
-                if r in partition_of
-            ]
-            groups.append(min(att) if att else None)
-        for rid in routers:
-            groups.append(partition_of.get(rid))
-        apply_partition_hint(topo, groups)
-    topo.touch()
-    return SpfTopology(topo, atoms, router_index, network_index)
 
 
 def link_spf_delta(

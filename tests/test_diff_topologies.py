@@ -14,7 +14,13 @@ import numpy as np
 import pytest
 
 from holo_tpu import telemetry
-from holo_tpu.ops.graph import Topology, TopologyDelta, diff_topologies
+from holo_tpu.ops.graph import (
+    Topology,
+    TopologyDelta,
+    diff_topologies,
+    lookup_sorted,
+    mutual_keep_mask,
+)
 from holo_tpu.spf.synth import clone_topology as clone
 
 
@@ -358,3 +364,67 @@ def test_counter_bumps_its_path_once(path, case):
     after = read()
     moved = {p: after[p] - before[p] for p in after}
     assert moved == {p: int(p == path) for p in moved}
+
+
+# -- the mutual-link filter (ISSUE 30): a packed-key membership test
+
+
+def set_of_pairs_keep_mask(edge_src, edge_dst) -> np.ndarray:
+    """``mutual_keep_mask`` as it stood before ISSUE 30, verbatim but for
+    the name: a Python set of pairs and a comprehension over the edges."""
+    src = np.asarray(edge_src)
+    dst = np.asarray(edge_dst)
+    fwd = set(zip(src.tolist(), dst.tolist()))
+    return np.array([(d, s) in fwd for s, d in zip(src, dst)], dtype=bool)
+
+
+@pytest.mark.parametrize("dtype", [np.int32, np.int64])
+@pytest.mark.parametrize("n_edges", [0, 1, 50, 30_000])
+def test_mutual_keep_mask_equals_the_set_of_pairs(n_edges, dtype):
+    """Random edge lists with duplicates, self-loops and one-sided
+    links; vertex ids up to the int32 limit, and below zero."""
+    for seed in range(4):
+        rng = np.random.default_rng([n_edges, seed])
+        n_vertices = max(2, n_edges // 3)
+        src = rng.integers(0, n_vertices, n_edges)
+        dst = rng.integers(0, n_vertices, n_edges)
+        back = rng.random(n_edges) < 0.5  # half the links have a reverse
+        src = np.concatenate((src, dst[back]))
+        dst = np.concatenate((dst, src[: n_edges][back]))
+        loops = rng.integers(0, n_vertices, n_edges // 10)
+        src = np.concatenate((src, loops, src[: n_edges // 5]))  # duplicates
+        dst = np.concatenate((dst, loops, dst[: n_edges // 5]))
+        order = rng.permutation(len(src))
+        src, dst = src[order], dst[order]
+        if seed == 2:  # the ends of the id range
+            scale = (2**31 - 1) // n_vertices
+            src, dst = src * scale, dst * scale
+        if seed == 3:
+            src, dst = src - n_vertices // 2, dst - n_vertices // 2
+        src, dst = src.astype(dtype), dst.astype(dtype)
+        before = src.copy(), dst.copy()
+        got = mutual_keep_mask(src, dst)
+        want = set_of_pairs_keep_mask(src, dst)
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert np.array_equal(got, want)
+        assert np.array_equal(src, before[0])  # the inputs stay as given
+        assert np.array_equal(dst, before[1])
+        if n_edges >= 50:
+            assert 0 < got.sum() < len(got)
+        if n_edges <= 50:  # a list is as good as an array
+            assert np.array_equal(
+                mutual_keep_mask(src.tolist(), dst.tolist()), want
+            )
+
+
+@pytest.mark.parametrize("n_keys", [0, 1, 7, 5_000])
+def test_lookup_sorted_is_dict_get_with_the_last_of_equal_keys(n_keys):
+    rng = np.random.default_rng(n_keys)
+    keys = np.sort(rng.integers(-50, max(4 * n_keys, 1), n_keys))  # equal keys
+    wanted = rng.integers(-60, max(4 * n_keys, 1) + 10, 3 * n_keys + 5)
+    index = {int(k): i for i, k in enumerate(keys)}
+    at, there = lookup_sorted(keys, wanted)
+    assert there.tolist() == [int(w) in index for w in wanted]
+    assert at[there].tolist() == [
+        index[int(w)] for w in wanted if int(w) in index
+    ]

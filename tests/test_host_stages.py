@@ -260,6 +260,49 @@ def test_spf_run_books_six_stages_once_per_run_and_run_bounds_them(recorder):
     assert ("enter", "loop.routing") in ev  # the RIB's work, by actor
 
 
+def _lsas_counted() -> dict:
+    snap = telemetry.snapshot("holo_ospf_topology_lsas_total")
+    return {
+        path: sum(v for k, v in snap.items() if f"path={path}" in k)
+        for path in ("lowered", "reused")
+    }
+
+
+def test_second_run_lowers_the_flapped_lsas_and_lowering_goes_with_base():
+    """The ``topology`` stage keeps the area's lowered LSDB between runs
+    (ISSUE 30): a flap re-installs two router-LSAs, the next run lowers
+    those two and reuses the rest; the instance holds the lowering
+    beside the DeltaPath base and drops the two together."""
+    from holo_tpu.protocols.ospf.packet import LsaKey, LsaType
+
+    net = _storm_net()
+    inst, area = net.inst, net.area
+    assert set(inst._spf_lowerings) == set(inst._spf_delta_bases) == {
+        area.area_id
+    }
+    kept = inst._spf_lowerings[area.area_id]
+    net.flap(net.flappable[0], lost=False)
+    net.loop.advance(30.0)
+    before, runs0 = _lsas_counted(), inst.spf_run_count
+    net.flap(net.flappable[1], lost=False)
+    net.loop.advance(30.0)
+    assert inst.spf_run_count == runs0 + 1
+    after = _lsas_counted()
+    n = len(area.lsdb.entries)
+    assert after["lowered"] - before["lowered"] == 2
+    assert after["reused"] - before["reused"] == n - 2
+    assert inst._spf_lowerings[area.area_id] is kept
+    assert kept.entries == list(area.lsdb.entries.values())
+
+    # No self LSA: no topology, and neither a base nor a lowering kept.
+    rid = inst.config.router_id
+    area.lsdb.remove(LsaKey(LsaType.ROUTER, rid, rid))
+    inst._spf_force_full = True
+    inst.run_spf()
+    assert area.area_id not in inst._spf_delta_bases
+    assert area.area_id not in inst._spf_lowerings
+
+
 def test_waterfall_keeps_its_phases_and_telescopes_with_stages_armed(recorder):
     """The ledger folds only marshal / delta / device / readback /
     solve: the host stages leave every cut where it was."""
