@@ -291,7 +291,9 @@ class Daemon:
     # Instance-side callbacks the providers install: these mutate shared
     # provider/RIB state and must run on the primary loop, not on the
     # instance's thread.
-    _MARSHALLED_CALLBACKS = ("route_cb", "lib_cb", "on_state", "notif_cb")
+    _MARSHALLED_CALLBACKS = (
+        "route_cb", "route_delta_cb", "lib_cb", "on_state", "notif_cb",
+    )
 
     def _place_instance(self, inst):
         from holo_tpu.utils.preempt import (

@@ -623,6 +623,9 @@ class RoutingProvider(Provider, Actor):
             ibus, kernel or MockKernel(), microloop_delay=microloop_delay
         )
         self.rib.on_change = self._rib_changed
+        from holo_tpu.routing.sink import RouteSink
+
+        self._route_sink = RouteSink(self.rib)
         self.instances: dict[str, OspfInstance] = {}
 
     def attach(self, loop_):
@@ -1018,7 +1021,7 @@ class RoutingProvider(Provider, Actor):
                 name=actor,
                 router_id=IPv4Address(router_id),
                 netio=self.netio_factory(actor),
-                route_cb=self._ospfv3_routes_to_rib,
+                route_delta_cb=self._ospfv3_delta_to_rib,
                 notif_cb=self.yang_notify,
             )
             inst = self._place_instance(inst)
@@ -1135,75 +1138,25 @@ class RoutingProvider(Provider, Actor):
                     )
 
     def _sink_routes(self, protocol, items: dict) -> None:
-        """Shared delta route sink: items = {prefix: (metric, {(if, addr)})}
+        """Shared delta route sink (``routing/sink.py`` RouteSink over
+        this provider's RIB): items = {prefix: (metric, {(if, addr)})}
         or, with IP-FRR repairs, (metric, nhs, {primary -> (backup,
-        labels)}) — the backups ride the RouteMsg so the RIB can flip to
-        them on BFD/link-down without waiting for this layer.
-
-        Caches the last pushed set per protocol so unchanged routes skip
-        RIB churn; the cache is cleared when the instance stops (otherwise
-        a disable/re-enable would suppress re-installation).
-        """
-        from holo_tpu.utils.southbound import (
-            DEFAULT_DISTANCE,
-            Nexthop,
-            RouteKeyMsg,
-            RouteMsg,
-        )
-
-        caches = getattr(self, "_route_caches", None)
-        if caches is None:
-            caches = self._route_caches = {}
-        old = caches.get(protocol, {})
-        for prefix in old.keys() - items.keys():
-            self.rib.route_del(RouteKeyMsg(protocol, prefix))
-        for prefix, entry in items.items():
-            if old.get(prefix) == entry:
-                continue
-            metric, nhs = entry[0], entry[1]
-            raw_backups = entry[2] if len(entry) > 2 else None
-            backups = {}
-            for (pi, pa), ((bi, ba), labels) in (raw_backups or {}).items():
-                if pa is None or ba is None:
-                    continue
-                backups[Nexthop(addr=pa, ifname=pi)] = Nexthop(
-                    addr=ba, ifname=bi, labels=tuple(labels)
-                )
-            self.rib.route_add(
-                RouteMsg(
-                    protocol=protocol,
-                    prefix=prefix,
-                    distance=DEFAULT_DISTANCE.get(protocol, 250),
-                    metric=metric,
-                    nexthops=frozenset(
-                        Nexthop(addr=a, ifname=i) for i, a in nhs
-                    ),
-                    backups=backups,
-                )
-            )
-        caches[protocol] = dict(items)
+        labels)})."""
+        self._route_sink.push(protocol, items)
 
     def _drop_instance_routes(self, protocol, inst_routes) -> None:
-        from holo_tpu.utils.southbound import RouteKeyMsg
+        self._route_sink.drop(protocol, inst_routes)
 
-        for prefix in inst_routes:
-            self.rib.route_del(RouteKeyMsg(protocol, prefix))
-        if getattr(self, "_route_caches", None):
-            self._route_caches.pop(protocol, None)
-
-    def _ospfv3_routes_to_rib(self, routes):
+    def _ospfv3_delta_to_rib(self, changed, removed):
+        """OSPFv3 hands over only what an SPF run changed of its table
+        (``OspfV3Instance.route_delta_cb``)."""
+        from holo_tpu.routing.sink import v6_route_item
         from holo_tpu.utils.southbound import Protocol
 
-        self._sink_routes(
+        self._route_sink.push_delta(
             Protocol.OSPFV3,
-            {
-                p: (
-                    r.dist,
-                    frozenset(r.nexthops),
-                    getattr(r, "backups", None),
-                )
-                for p, r in routes.items()
-            },
+            {p: v6_route_item(r) for p, r in changed.items()},
+            removed,
         )
 
     def _apply_isis(self, new):

@@ -17,7 +17,6 @@ retransmission lists.
 
 from __future__ import annotations
 
-import enum
 from dataclasses import dataclass, field
 from ipaddress import IPv4Address, IPv4Network
 
@@ -89,8 +88,12 @@ from holo_tpu.protocols.ospf.packet import (
     RouterLink,
     RouterLinkType,
 )
-from holo_tpu.protocols.ospf.spf_run import (
+from holo_tpu.protocols.ospf.spf_run import (  # noqa: F401 (re-exported)
     LoweredLsdb,
+    SpfDelayFsm,
+    SpfFsmState,
+    SpfTimers,
+    aggregate_area_ranges,
     derive_routes,
     link_spf_delta,
     reachable_router_flags,
@@ -171,22 +174,8 @@ class IfDownMsg:
     ifname: str
 
 
-# ===== SPF delay FSM (RFC 8405; reference holo-ospf/src/spf.rs:270-484) ==
-
-
-class SpfFsmState(enum.Enum):
-    QUIET = "quiet"
-    SHORT_WAIT = "short-wait"
-    LONG_WAIT = "long-wait"
-
-
-@dataclass
-class SpfTimers:
-    initial_delay: float = 0.05
-    short_delay: float = 0.2
-    long_delay: float = 5.0
-    hold_down: float = 10.0
-    time_to_learn: float = 0.5
+# The RFC 8405 SPF-delay FSM (SpfFsmState, SpfTimers, SpfDelayFsm) is
+# spf_run's: OSPFv3 runs the same one.
 
 
 @dataclass
@@ -294,7 +283,7 @@ _PKT_TYPE_YANG = {
 _CHECK_SKIP = object()
 
 
-class OspfInstance(Actor):
+class OspfInstance(SpfDelayFsm, Actor):
     """One OSPFv2 routing process."""
 
     def __init__(
@@ -2691,27 +2680,10 @@ class OspfInstance(Actor):
             self._spf_timer = self.loop.timer(self.name, SpfDelayTimerMsg)
         if self._hold_timer is None:
             self._hold_timer = self.loop.timer(self.name, SpfHoldDownMsg)
-        self._hold_timer.start(cfg.hold_down)  # reset on every IGP event
-        if self.spf_state == SpfFsmState.QUIET:
-            self._learn_deadline = now + cfg.time_to_learn
-            self.spf_state = SpfFsmState.SHORT_WAIT
-            self._spf_timer.start(cfg.initial_delay)
-        elif self.spf_state == SpfFsmState.SHORT_WAIT:
-            if now >= (self._learn_deadline or 0):
-                self.spf_state = SpfFsmState.LONG_WAIT
-                self._spf_timer.start(cfg.long_delay)
-            elif not self._spf_timer.armed:
-                self._spf_timer.start(cfg.short_delay)
-        elif self.spf_state == SpfFsmState.LONG_WAIT:
-            if not self._spf_timer.armed:
-                self._spf_timer.start(cfg.long_delay)
+        self._spf_delay_event(cfg, now, self._spf_timer, self._hold_timer)
 
     def _spf_timer_fired(self) -> None:
         self.run_spf()
-
-    def _spf_holddown_fired(self) -> None:
-        self.spf_state = SpfFsmState.QUIET
-        self._learn_deadline = None
 
     # ----- SPF execution + route programming
 
@@ -3195,40 +3167,13 @@ class OspfInstance(Actor):
             # an active advertised range aggregate into the range prefix
             # at the max component distance (or its configured cost);
             # advertise=false ranges black-hole their components.
-            src_ranges = self.areas[src_aid].ranges
-            eff: dict = {}
-            range_max: dict = {}
-            # Areas a range's COMPONENT routes exit through: the split
-            # horizon below must also cover range aggregates.
-            range_nh_areas: dict = {}
-            for prefix, route in routes.items():
-                matches = [
-                    r for r in src_ranges if prefix.subnet_of(r["prefix"])
-                ]
-                # Most-specific range wins (Appendix C.2 semantics).
-                rng = max(
-                    matches,
-                    key=lambda r: r["prefix"].prefixlen,
-                    default=None,
-                )
-                if rng is None:
-                    eff[prefix] = route.dist
-                elif rng.get("advertise", True):
-                    cur = range_max.get(rng["prefix"], -1)
-                    range_max[rng["prefix"]] = max(cur, route.dist)
-                    acc = range_nh_areas.setdefault(
-                        rng["prefix"], set()
-                    )
-                    for aid2 in self.areas:
-                        if _nexthops_in_area(route, aid2):
-                            acc.add(aid2)
-            for r in src_ranges:
-                if r["prefix"] in range_max:
-                    eff[r["prefix"]] = (
-                        r["cost"]
-                        if r.get("cost") is not None
-                        else range_max[r["prefix"]]
-                    )
+            eff, range_nh_areas, _active = aggregate_area_ranges(
+                routes, self.areas[src_aid].ranges,
+                lambda route: [
+                    aid2 for aid2 in self.areas
+                    if _nexthops_in_area(route, aid2)
+                ],
+            )
             for prefix, dist in eff.items():
                 for dst_aid in self.areas:
                     if dst_aid == src_aid:

@@ -9,12 +9,17 @@ links keyed by (router-id, interface-id).
 
 Scope: p2p + broadcast interfaces (router-id DR election with a
 Waiting/BackupSeen analog, network LSAs, network-referenced
-Intra-Area-Prefix LSAs), single area, intra-area v6 routes over router
-AND network vertices; inter-area (ABR) lands next.
+Intra-Area-Prefix LSAs), multi-area ABR with area address ranges,
+stub areas, externals; intra-area v6 routes over router AND network
+vertices.  What the two versions share beyond the NSM lives in
+``spf_run.py``, as the reference shares it over its ``Version`` trait:
+the RFC 8405 SPF-delay FSM, the ranges, the kept LSDB lowering and the
+DeltaPath seam; an SPF run has the v2 instance's host stages.
 """
 
 from __future__ import annotations
 
+import itertools
 from dataclasses import dataclass, field
 from ipaddress import IPv4Address, IPv6Address, IPv6Network
 
@@ -30,10 +35,22 @@ from holo_tpu.protocols.ospf.instance import (
     _OSPF_SPF_RUNS,
 )
 from holo_tpu.protocols.ospf.interface import ElectionView, IfType, elect_dr_bdr
-from holo_tpu.protocols.ospf.lsdb import MIN_LS_ARRIVAL, Lsdb, next_seq_no
+from holo_tpu.protocols.ospf.lsdb import (
+    LS_REFRESH_TIME,
+    MIN_LS_ARRIVAL,
+    AgeScan,
+    Lsdb,
+    next_seq_no,
+)
 from holo_tpu.protocols.ospf.spf_run import (
-    apply_interface_srlg,
+    DERIVE_NEXTHOPS,
+    LoweredLsdbV3,
+    NexthopAtom,
+    SpfDelayFsm,
+    SpfTimers,
+    aggregate_area_ranges,
     atom_bits,
+    link_spf_delta,
     srlg_bits,
 )
 from holo_tpu.protocols.ospf.neighbor import (
@@ -43,13 +60,35 @@ from holo_tpu.protocols.ospf.neighbor import (
     nsm_transition,
 )
 from holo_tpu.spf.backend import ScalarSpfBackend, SpfBackend
-from holo_tpu.telemetry import convergence
+from holo_tpu.telemetry import convergence, profiling
 from holo_tpu.utils.ip import ALL_SPF_RTRS_V6
 from holo_tpu.utils.netio import NetIo, NetRxPacket
 from holo_tpu.utils.runtime import Actor
 
 DD_CHUNK = 64
 AGE_TICK = 1.0
+
+_AREA_SPF = telemetry.counter(
+    "holo_ospf_area_spf_total",
+    "OSPFv3 areas of a full SPF run by what the run did with them: "
+    "dispatched to the backend, or reused from the last run because "
+    "none of the area's inputs had changed",
+    ("disposition",),
+)
+_RIB_DELTA_ROUTES = telemetry.histogram(
+    "holo_ospf_rib_delta_routes",
+    "Routes an OSPFv3 SPF run handed to its route sink (the whole "
+    "table through route_cb, or the changed and withdrawn prefixes "
+    "through route_delta_cb)",
+    buckets=(1, 4, 16, 64, 256, 1024, 4096, 16384, 65536),
+)
+
+
+def legacy_spf_timers() -> SpfTimers:
+    """What an ``OspfV3Instance`` runs when no timers are configured:
+    the RFC 8405 FSM with every delay at the 0.1 s this instance always
+    waited (one run 0.1 s after the first event of a burst)."""
+    return SpfTimers(initial_delay=0.1, short_delay=0.1, long_delay=0.1)
 
 
 @dataclass
@@ -125,6 +164,11 @@ class SpfTimerV3:
 
 
 @dataclass
+class SpfHoldDownV3:
+    pass
+
+
+@dataclass
 class WaitTimerV3:
     ifname: str
 
@@ -176,13 +220,17 @@ class V3Area:
     # default inter-area-prefix from its ABRs.
     summary: bool = True
     stub_default_cost: int = 10  # ietf-ospf default-cost default
+    # RFC 2328 area address ranges (RFC 5340 keeps §12.4.3): [{prefix,
+    # advertise, cost}], the v2 Area's shape; intra-area prefixes under
+    # a range are advertised into other areas as the range alone.
+    ranges: list = field(default_factory=list)
 
     @property
     def no_external(self) -> bool:
         return self.stub or self.nssa
 
 
-class OspfV3Instance(Actor):
+class OspfV3Instance(SpfDelayFsm, Actor):
     """One OSPFv3 routing process: multi-area ABR (inter-area-prefix
     LSAs), stub areas, externals, LAN + p2p circuits."""
 
@@ -197,12 +245,22 @@ class OspfV3Instance(Actor):
         route_cb=None,
         notif_cb=None,
         nvstore=None,
+        spf_timers: SpfTimers | None = None,
+        route_delta_cb=None,
     ):
         self.name = name
         self.router_id = router_id
         self.netio = netio
         self.backend = spf_backend or ScalarSpfBackend()
+        # RFC 8405 SPF-delay timers (the FSM is spf_run.SpfDelayFsm,
+        # shared with the v2 instance).
+        self.spf_timers = spf_timers or legacy_spf_timers()
+        # ``route_cb(routes)`` gets the whole table after every run;
+        # ``route_delta_cb(changed, removed)``, when set, gets only the
+        # prefixes whose route changed or went (and route_cb is not
+        # called): the sink then does no whole-table compare.
         self.route_cb = route_cb
+        self.route_delta_cb = route_delta_cb
         self.notif_cb = notif_cb
         self.interfaces: dict[str, V3Interface] = {}
         self.areas: dict[IPv4Address, V3Area] = {}
@@ -225,9 +283,24 @@ class OspfV3Instance(Actor):
         # 2..8 arms the vectorized multipath dispatch (same contract
         # as the v2 instance's config.max_paths).
         self.max_paths: int | None = None
-        # DeltaPath: the previous run's (vertex keys, atoms, topology)
-        # per area — the diff base for in-place device-graph updates.
+        # DeltaPath: the previous run's marshaled SpfTopologyV3 per
+        # area — the diff base for in-place device-graph updates — and
+        # the area's kept lowering (only LSAs installed since the last
+        # run are lowered again).
         self._spf_delta_bases: dict = {}
+        self._spf_lowerings: dict = {}
+        # Per area: what its intra-area table advertises into other
+        # areas (ranges applied), kept while the table object is the
+        # last run's.
+        self._advert_cache: dict = {}
+        self._active_ranges: set = set()
+        # An area whose SPF inputs did not change since the last full
+        # run keeps its result and its intra-area table and is not
+        # dispatched (False: every area is dispatched in every run, the
+        # control arm of the tests and of PERF.md section 6).
+        self.reuse_unchanged_areas = True
+        self._area_kept: dict = {}  # aid -> (st, backend, knobs, out, intra)
+        self._age_scans: dict = {}  # aid -> lsdb.AgeScan of the area's LSDB
         # Hierarchical partition hint (ISSUE 15): router-id -> group
         # label lowered through spf_run.apply_partition_hint at the
         # marshal seam (same contract as the v2 instance).
@@ -248,7 +321,6 @@ class OspfV3Instance(Actor):
         self.spf_log: list[dict] = []
         self._dd_seq = 0x3000
         self._next_iface_id = 1
-        self._spf_pending = False
         self._timers: dict[tuple, object] = {}
         self._inter_ids: dict = {}  # summarized prefix/asbr -> lsid
         # RFC 7166 64-bit tx sequence number: restart-safe via a durable
@@ -273,6 +345,7 @@ class OspfV3Instance(Actor):
         self._age_timer = self.loop.timer(self.name, AgeTickV3)
         self._age_timer.start(AGE_TICK)
         self._spf_timer = self.loop.timer(self.name, SpfTimerV3)
+        self._hold_timer = self.loop.timer(self.name, SpfHoldDownV3)
 
     def add_interface(
         self,
@@ -342,8 +415,9 @@ class OspfV3Instance(Actor):
         elif isinstance(msg, RxmtTimerV3):
             self._rxmt(msg.ifname, msg.nbr_id)
         elif isinstance(msg, SpfTimerV3):
-            self._spf_pending = False
             self.run_spf()
+        elif isinstance(msg, SpfHoldDownV3):
+            self._spf_holddown_fired()
         elif isinstance(msg, WaitTimerV3):
             iface = self.interfaces.get(msg.ifname)
             if iface is not None and iface.up and iface.is_lan:
@@ -958,16 +1032,29 @@ class OspfV3Instance(Actor):
         for iface in self.interfaces.values():
             for nbr in iface.neighbors.values():
                 held |= set(nbr.ls_rxmt)
-        dbs = [a.lsdb for a in self.areas.values()] + [
-            i.link_lsdb for i in self.interfaces.values()
-        ]
-        for db in dbs:
+        now = self.loop.clock.now()
+        for area in self.areas.values():
+            # An entry whose header says MaxAge is MaxAge on the clock
+            # too: the area's kept ages name the few there are.
+            for e in self._age_scan(area).at_least(
+                area.lsdb, now, P.MAX_AGE
+            ):
+                if e.lsa.is_maxage and e.lsa.key not in held:
+                    area.lsdb.remove(e.lsa.key)
+        for iface in self.interfaces.values():
+            db = iface.link_lsdb
             for key in [
                 k
                 for k, e in db.entries.items()
                 if e.lsa.is_maxage and k not in held
             ]:
                 db.remove(key)
+
+    def _age_scan(self, area: V3Area) -> AgeScan:
+        scan = self._age_scans.get(area.area_id)
+        if scan is None:
+            scan = self._age_scans[area.area_id] = AgeScan()
+        return scan
 
     def _arm_rxmt(self, iface: V3Interface, nbr: Neighbor) -> None:
         t = self._timer(
@@ -1021,6 +1108,14 @@ class OspfV3Instance(Actor):
             else area.lsdb
         )
         old = scope_db.get(key)
+        if (
+            old is not None
+            and not old.lsa.is_maxage
+            and old.lsa.body == body
+        ):
+            # The same body encodes to the same bytes: nothing to build
+            # (an ABR wants a thousand summaries again in every run).
+            return
         lsa = P.Lsa(
             age=0,
             type=ltype,
@@ -1299,9 +1394,23 @@ class OspfV3Instance(Actor):
                 i for i in self.interfaces.values()
                 if self._area_of(i) is area
             ]
-            dbs = [(area.lsdb, None)] + [(i.link_lsdb, i) for i in ifaces]
-            for db, iface in dbs:
-                for e in db.refresh_due(now, self.router_id):
+            # The area's database through its kept ages (an area holds
+            # thousands of LSAs, a circuit's link-scope database two).
+            old = self._age_scan(area).at_least(
+                area.lsdb, now, LS_REFRESH_TIME
+            )
+            dbs = [(area.lsdb, None, old)] + [
+                (i.link_lsdb, i, i.link_lsdb.all()) for i in ifaces
+            ]
+            for db, iface, aged in dbs:
+                aged = list(aged)
+                for e in aged:
+                    if (
+                        e.lsa.adv_rtr != self.router_id
+                        or e.lsa.is_maxage
+                        or e.current_age(now) < LS_REFRESH_TIME
+                    ):
+                        continue
                     lsa = P.Lsa(
                         age=0,
                         type=e.lsa.type,
@@ -1312,9 +1421,12 @@ class OspfV3Instance(Actor):
                     )
                     lsa.encode()
                     self._install_and_flood(area, lsa, from_iface=iface)
-                for key in db.maxage_keys(now):
-                    e = db.get(key)
-                    if e is not None and not e.lsa.is_maxage:
+                for e in aged:
+                    if (
+                        db.get(e.lsa.key) is e  # not refreshed above
+                        and e.current_age(now) >= P.MAX_AGE
+                        and not e.lsa.is_maxage
+                    ):
                         # Natural expiry: pin the header age at MaxAge so
                         # the flood (and the §14 sweep) see the flush.
                         self._install_and_flood(
@@ -1343,17 +1455,22 @@ class OspfV3Instance(Actor):
             else convergence.TRIGGER_IFCONFIG,
             instance=self.name,
         )
-        if not self._spf_pending:
-            self._spf_pending = True
-            self._spf_timer.start(0.1)
+        self._spf_delay_event(
+            self.spf_timers, self.loop.clock.now(),
+            self._spf_timer, self._hold_timer,
+        )
+
+    def set_area_ranges(self, area_id: IPv4Address, ranges: list) -> None:
+        """Configure an area's address ranges ([{prefix, advertise,
+        cost}]); a config event, so the next run is a full one."""
+        self.areas[area_id].ranges = list(ranges)
+        self._schedule_spf()
 
     @staticmethod
     def _expand_atoms(words, atoms) -> frozenset:
         """Atom bits -> next-hop tuples; NexthopAtom vlink atoms expand
         to their borrowed transit-area set (§16.1), same typed design
         as the v2 marshaling (spf_run.NexthopAtom.expand)."""
-        from holo_tpu.protocols.ospf.spf_run import NexthopAtom
-
         out = set()
         for a in atom_bits(words, len(atoms)):
             atom = atoms[a]
@@ -1574,7 +1691,26 @@ class OspfV3Instance(Actor):
     def run_spf(self) -> None:
         with convergence.spf_run(self._conv_pending, self.name):
             with telemetry.span("ospfv3.spf", instance=self.name):
-                self._run_spf_traced()
+                # The v2 instance's host stages under its names (site
+                # ospf.spf): run holds the whole run, topology / link /
+                # derive / inter / publish its parts, one observation
+                # of each per run whatever the number of areas.
+                with profiling.stage("ospf.spf", "run"):
+                    self._run_spf_traced()
+
+    def _backbone_vlink_peers(self, backbone: V3Area) -> bool:
+        """Whether our backbone Router-LSA names a virtual link (or one
+        is configured): only then does the backbone's marshal wait for
+        the transit areas' results."""
+        if self.vlink_config:
+            return True
+        e = backbone.lsdb.get(
+            P.LsaKey(P.LsaType.ROUTER, IPv4Address(0), self.router_id)
+        )
+        return e is not None and any(
+            link.link_type == P.RouterLinkType.VIRTUAL_LINK
+            for link in e.lsa.body.links
+        )
 
     def _run_spf_traced(self) -> None:
         triggers = self._spf_triggers
@@ -1589,58 +1725,44 @@ class OspfV3Instance(Actor):
         _OSPF_SPF_RUNS.labels(instance=self.name, type="full").inc()
         self.spf_run_count += 1
         start_time = self.loop.clock.now()
-        area_results = {}
-        # Backbone last: its SPF borrows transit-area next hops for
-        # virtual links (§16.1), like the v2 instance.
-        ordered = sorted(
-            self.areas.values(), key=lambda a: int(a.area_id) == 0
-        )
-        for area in ordered:
-            vlink_nexthops = None
-            if int(area.area_id) == 0:
-                vlink_nexthops = self._vlink_nexthops(
-                    area, area_results
-                )
-            out = self._area_spf(area, vlink_nexthops)
-            if out is not None:
-                area_results[area.area_id] = out
-
-        routes: dict[IPv6Network, V6Route] = {}
+        area_results: dict = {}
         intra_by_area: dict[IPv4Address, dict] = {}
-        # 1. intra-area routes (preferred over inter/external).
-        for aid, (index, keys, res, atoms, prefix_lsas) in area_results.items():
-            intra = {}
-            for adv, body in prefix_lsas:
-                if body.ref_type == int(P.LsaType.ROUTER):
-                    v = index.get(("R", body.ref_adv_rtr))
-                elif body.ref_type == int(P.LsaType.NETWORK):
-                    v = index.get(("N", body.ref_adv_rtr, int(body.ref_lsid)))
-                else:
-                    continue
-                if v is None or res.dist[v] >= INF:
-                    continue
-                nhs = self._expand_atoms(res.nexthop_words[v], atoms)
-                for entry in body.prefixes:
-                    prefix, metric = entry[0], entry[1]
-                    opts = body.entry_opts(entry)
-                    total = int(res.dist[v]) + metric
-                    cur = intra.get(prefix)
-                    if cur is None or total < cur.dist:
-                        intra[prefix] = V6Route(
-                            prefix, total, nhs, prefix_options=opts,
-                            area_id=aid, vertex=v,
-                        )
-                    elif total == cur.dist:
-                        intra[prefix] = V6Route(
-                            prefix, total, cur.nexthops | nhs,
-                            prefix_options=cur.prefix_options,
-                            area_id=aid, vertex=cur.vertex,
-                        )
-            intra_by_area[aid] = intra
-            for prefix, route in intra.items():
-                cur = routes.get(prefix)
-                if cur is None or route.dist < cur.dist:
-                    routes[prefix] = route
+        # Every area in one pass; a backbone with virtual links in a
+        # second one, because its SPF borrows transit-area next hops
+        # (§16.1), like the v2 instance.
+        backbone = self.areas.get(IPv4Address(0))
+        passes = [[
+            a for a in self.areas.values() if int(a.area_id) != 0
+        ]]
+        if backbone is not None:
+            if self._backbone_vlink_peers(backbone):
+                passes.append([backbone])
+            else:
+                self.vlink_state = []
+                passes[0].append(backbone)
+        for areas in passes:
+            self._spf_pass(
+                areas, area_results, intra_by_area,
+                vlinks=areas is not passes[0],
+            )
+        # Area order as before: the backbone last.  A prefix is nearly
+        # always one area's: the tables are joined by whole-dict
+        # operations, which reuse the hashes the dicts hold (hashing an
+        # ip_network anew costs a microsecond, and there are 13,000),
+        # and only a prefix two areas both have is compared.
+        routes: dict[IPv6Network, V6Route] = {}
+        for aid in sorted(intra_by_area, key=lambda a: int(a) == 0):
+            intra = intra_by_area[aid]
+            both = routes.keys() & intra.keys()
+            if not both:
+                routes.update(intra)
+                continue
+            settled = {prefix: routes[prefix] for prefix in both}
+            routes.update(intra)
+            for prefix, cur in settled.items():
+                route = intra[prefix]
+                if cur.dist < route.dist:
+                    routes[prefix] = cur
                 elif route.dist == cur.dist:
                     # Cross-area ECMP union keeps the first contributing
                     # area's (area_id, vertex) — the FRR consumption key
@@ -1652,38 +1774,43 @@ class OspfV3Instance(Actor):
                         area_id=cur.area_id, vertex=cur.vertex,
                     )
 
-        # 2. inter-area routes from received Inter-Area-Prefix LSAs:
-        #    distance = dist(advertising ABR in that area) + metric.
-        #    The candidate table covers EVERY advertised prefix (intra
-        #    preference applies only at install time) so a later partial
-        #    run can fall back to it when an intra path withdraws.
-        inter_routes: dict[IPv6Network, V6Route] = {}
-        self._derive_inter_area(area_results, inter_routes)
-        for prefix, route in inter_routes.items():
-            if prefix not in routes:
-                routes[prefix] = route
+        with profiling.stage("ospf.spf", "inter"):
+            adverts = self._area_adverts(intra_by_area)
+            # 2. inter-area routes from received Inter-Area-Prefix LSAs:
+            #    distance = dist(advertising ABR in that area) + metric.
+            #    The candidate table covers EVERY advertised prefix
+            #    (intra preference applies only at install time) so a
+            #    later partial run can fall back to it when an intra
+            #    path withdraws.
+            inter_routes: dict[IPv6Network, V6Route] = {}
+            self._derive_inter_area(area_results, inter_routes)
+            for prefix, route in inter_routes.items():
+                if prefix not in routes:
+                    routes[prefix] = route
 
-        # 3. AS-external routes (lowest preference): RFC 5340 type 0x4005.
-        #    E2 ranks on the external metric, E1 on asbr-dist + metric.
-        routes.update(self._derive_external(area_results, routes))
+            # 3. AS-external routes (lowest preference): RFC 5340 type
+            #    0x4005.  E2 ranks on the external metric, E1 on
+            #    asbr-dist + metric.
+            routes.update(self._derive_external(area_results, routes))
 
-        # 4. ABR duties: inter-area-prefix origination (each area's intra
-        #    prefixes into every other area; default into stub areas).
-        if self.is_abr:
-            self._originate_inter_area(
-                intra_by_area, inter_routes, area_results
+            # 4. ABR duties: inter-area-prefix origination (each area's
+            #    intra prefixes, ranges applied, into every other area;
+            #    default into stub areas).
+            if self.is_abr:
+                self._originate_inter_area(
+                    adverts, inter_routes, area_results
+                )
+
+            self.spf_log.append(
+                {
+                    "run": self.spf_run_count,
+                    "type": "full",
+                    "start-time": start_time,
+                    "end-time": self.loop.clock.now(),
+                    "route-count": len(routes),
+                }
             )
-
-        self.spf_log.append(
-            {
-                "run": self.spf_run_count,
-                "type": "full",
-                "start-time": start_time,
-                "end-time": self.loop.clock.now(),
-                "route-count": len(routes),
-            }
-        )
-        del self.spf_log[:-32]
+            del self.spf_log[:-32]
         # Cache the run's products for prefix-scoped partial updates
         # (reference route.rs:200-333 update_rib_partial).
         self._spf_cache = {
@@ -1692,10 +1819,164 @@ class OspfV3Instance(Actor):
             "routes": routes,
             "inter_routes": inter_routes,
         }
+        with profiling.stage("ospf.spf", "publish"):
+            self._publish(routes, area_results)
+
+    def _spf_pass(
+        self, areas: list, area_results: dict, intra_by_area: dict,
+        vlinks: bool,
+    ) -> None:
+        """Marshal, dispatch and derive ``areas``; every stage once for
+        all of them.  ``vlinks``: this is the backbone's own pass, after
+        the transit areas' results."""
+        marshaled: dict = {}
+        with profiling.stage("ospf.spf", "topology"):
+            for area in areas:
+                vlink_nexthops = None
+                if vlinks:
+                    vlink_nexthops = self._vlink_nexthops(
+                        area, area_results
+                    )
+                st = self._area_marshal(area, vlink_nexthops)
+                if st is not None:
+                    marshaled[area.area_id] = st
+        mp_k = (
+            self.max_paths
+            if self.max_paths is not None and self.max_paths > 1
+            else 1
+        )
+        knobs = (self.frr, mp_k)
+        fresh: dict = {}
+        for aid, st in marshaled.items():
+            kept = self._area_kept.get(aid)
+            if (
+                kept is not None and kept[0] is st
+                and kept[1] is self.backend and kept[2] == knobs
+            ):
+                # The lowering handed out the last run's object: none
+                # of the area's inputs changed.
+                _AREA_SPF.labels(disposition="reused").inc()
+            else:
+                fresh[aid] = st
+        # DeltaPath seam (same contract as the v2 instance): identical
+        # vertex model + atom table → diff against the previous run's
+        # topology so the device-resident graph updates in place.
+        with profiling.stage("ospf.spf", "link"):
+            for aid, st in fresh.items():
+                prev = self._spf_delta_bases.get(aid)
+                if prev is not st:
+                    link_spf_delta(prev, st)
+                self._spf_delta_bases[aid] = st
+        outs: dict = {}
+        for aid, st in fresh.items():
+            _AREA_SPF.labels(disposition="dispatched").inc()
+            res = self.backend.compute(st.topo, multipath_k=mp_k)
+            self._area_frr(aid, st.topo)
+            outs[aid] = (st.index, st.keys, res, st.atoms, st.prefix_lsas)
+        with profiling.stage("ospf.spf", "derive"):
+            for aid, out in outs.items():
+                self._area_kept[aid] = (
+                    fresh[aid], self.backend, knobs, out,
+                    self._derive_intra(aid, out),
+                )
+        for aid in marshaled:  # in the areas' order, reused or not
+            _st, _be, _kn, area_results[aid], intra_by_area[aid] = (
+                self._area_kept[aid]
+            )
+
+    def _derive_intra(self, aid, out) -> dict:
+        """1. intra-area routes (preferred over inter/external) of one
+        area from its SPF result.  A next-hop set is decoded once per
+        distinct bitmask row, so routes behind one set share one
+        frozenset."""
+        index, _keys, res, atoms, prefix_lsas = out
+        dist_of, words_of = res.dist, res.nexthop_words
+        router_t, network_t = int(P.LsaType.ROUTER), int(P.LsaType.NETWORK)
+        decoded: dict = {}
+        intra: dict = {}
+        offers = 0
+        for _adv, body in prefix_lsas:
+            if not body.prefixes:
+                continue
+            if body.ref_type == router_t:
+                v = index.get(("R", body.ref_adv_rtr))
+            elif body.ref_type == network_t:
+                v = index.get(("N", body.ref_adv_rtr, int(body.ref_lsid)))
+            else:
+                continue
+            if v is None:
+                continue
+            base = int(dist_of[v])
+            if base >= INF:
+                continue
+            words = words_of[v]
+            row = words.tobytes()
+            nhs = decoded.get(row)
+            if nhs is None:
+                nhs = decoded[row] = self._expand_atoms(words, atoms)
+            offers += len(body.prefixes)
+            for entry in body.prefixes:
+                prefix, total = entry[0], base + entry[1]
+                cur = intra.get(prefix)
+                if cur is None or total < cur.dist:
+                    intra[prefix] = V6Route(
+                        prefix, total, nhs,
+                        prefix_options=body.entry_opts(entry),
+                        area_id=aid, vertex=v,
+                    )
+                elif total == cur.dist:
+                    intra[prefix] = V6Route(
+                        prefix, total, cur.nexthops | nhs,
+                        prefix_options=cur.prefix_options,
+                        area_id=aid, vertex=cur.vertex,
+                    )
+        # The v2 derive's counter: offers by how their set was had.
+        DERIVE_NEXTHOPS.labels(path="decoded").inc(len(decoded))
+        DERIVE_NEXTHOPS.labels(path="reused").inc(offers - len(decoded))
+        return intra
+
+    @staticmethod
+    def _route_delta(old: dict, new: dict) -> tuple[dict, list]:
+        """``({prefix: route} that changed or came, [prefixes] that
+        went)`` between two route tables.  Two runs build their tables
+        in one order, so the walk goes down both at once and looks a
+        prefix up (which hashes it) only from where they part."""
+        def differs(o, r) -> bool:
+            return o is not r and (
+                o is None
+                or o.dist != r.dist
+                or o.nexthops != r.nexthops
+                or o.backups != r.backups
+            )
+
+        changed: dict = {}
+        side_by_side = zip(new.items(), old.items())
+        at = 0
+        for (prefix, r), (was, o) in side_by_side:
+            if prefix is not was and prefix != was:
+                break
+            if differs(o, r):
+                changed[prefix] = r
+            at += 1
+        if at == len(new) == len(old):
+            return changed, []
+        for prefix, r in itertools.islice(new.items(), at, None):
+            if differs(old.get(prefix), r):
+                changed[prefix] = r
+        return changed, [p for p in old if p not in new]
+
+    def _publish(self, routes: dict, area_results: dict) -> None:
+        """The end of a run, full or partial: clamp, join the repairs,
+        hand the table (or what changed of it) to the route sink."""
         self._clamp_max_paths(routes, area_results)
         self._attach_frr_backups(routes, area_results)
-        self.routes = routes
-        if self.route_cb is not None:
+        old, self.routes = self.routes, routes
+        if self.route_delta_cb is not None:
+            changed, removed = self._route_delta(old, routes)
+            _RIB_DELTA_ROUTES.observe(len(changed) + len(removed))
+            self.route_delta_cb(changed, removed)
+        elif self.route_cb is not None:
+            _RIB_DELTA_ROUTES.observe(len(routes))
             self.route_cb(routes)
 
     def _clamp_max_paths(self, routes: dict, area_results: dict | None = None) -> None:
@@ -1802,10 +2083,12 @@ class OspfV3Instance(Actor):
         received Inter-Area-Prefix LSAs (RFC 2328 §16.2 hierarchy rules).
         Shared by the full run and the prefix-scoped partial run
         (``only`` restricts to the changed prefixes)."""
+        active = self._active_ranges
         for aid, (index, _k, res, atoms, _pl) in area_results.items():
             area = self.areas.get(aid)
             if area is None:
                 continue
+            expanded: dict = {}  # ABR vertex -> its next-hop set
             if self.is_abr and aid != IPv4Address(0):
                 # §16.2 hierarchy: an ABR examines summaries from the
                 # backbone only (non-ABRs use their single attached area).
@@ -1821,11 +2104,19 @@ class OspfV3Instance(Actor):
                 prefix = lsa.body.prefix
                 if only is not None and prefix not in only:
                     continue  # partial run: out-of-scope prefix
+                if prefix in active:
+                    # §16.2 (3): a summary that equals one of our own
+                    # active area ranges is ignored.
+                    continue
                 abr_v = index.get(("R", lsa.adv_rtr))
                 if abr_v is None or res.dist[abr_v] >= INF:
                     continue
                 dist = int(res.dist[abr_v]) + lsa.body.metric
-                nhs = self._expand_atoms(res.nexthop_words[abr_v], atoms)
+                nhs = expanded.get(abr_v)
+                if nhs is None:
+                    nhs = expanded[abr_v] = self._expand_atoms(
+                        res.nexthop_words[abr_v], atoms
+                    )
                 cur = inter_routes.get(prefix)
                 if cur is None or dist < cur.dist:
                     inter_routes[prefix] = V6Route(
@@ -2005,6 +2296,10 @@ class OspfV3Instance(Actor):
                     routes[prefix] = inter_routes[prefix]
             external |= {p for p in intra_set if p not in routes}
             origination_dirty = True
+            # The tables were edited in place: what they advertise has
+            # to be computed again.
+            self._advert_cache.clear()
+        adverts = self._area_adverts(intra_by_area)
 
         if inter_network:
             for prefix in inter_network:
@@ -2043,9 +2338,7 @@ class OspfV3Instance(Actor):
             )
 
         if origination_dirty and self.is_abr:
-            self._originate_inter_area(
-                intra_by_area, inter_routes, area_results
-            )
+            self._originate_inter_area(adverts, inter_routes, area_results)
 
         log_type = (
             "intra" if intra_set
@@ -2067,57 +2360,112 @@ class OspfV3Instance(Actor):
         # Rebuilt routes need their repairs re-joined like the full run,
         # or a partial run would publish them backup-less and flap the
         # kernel entries off/on their precomputed repairs.
-        self._clamp_max_paths(routes, area_results)
-        self._attach_frr_backups(routes, area_results)
-        self.routes = routes
-        if self.route_cb is not None:
-            self.route_cb(routes)
+        with profiling.stage("ospf.spf", "publish"):
+            self._publish(routes, area_results)
+
+    def _nh_areas_of(self):
+        """``route -> frozenset of the areas its next hops exit
+        through``, one look at the interfaces per distinct next-hop set."""
+        memo: dict = {}
+        interfaces = self.interfaces
+
+        def nh_areas(route) -> frozenset:
+            areas = memo.get(route.nexthops)
+            if areas is None:
+                areas = memo[route.nexthops] = frozenset(
+                    interfaces[ifname].config.area_id
+                    for ifname, _addr in route.nexthops
+                    if ifname in interfaces
+                )
+            return areas
+
+        return nh_areas
+
+    def _area_adverts(self, intra_by_area: dict) -> dict:
+        """Per area what its intra-area table advertises into the other
+        areas, ``{prefix: (dist, prefix options, exit areas)}``: every
+        prefix as it is, or, under the area's address ranges (RFC 2328
+        §12.4.3), a range at its largest component's distance for its
+        components.  ``exit areas`` are those the next hops (of an
+        aggregate: of any component) leave through: the split horizon.
+        Sets ``_active_ranges``.  An area's entry is kept while its
+        table is the object it was computed from."""
+        nh_areas = self._nh_areas_of()
+        out: dict = {}
+        active: set = set()
+        for aid, intra in intra_by_area.items():
+            area = self.areas.get(aid)
+            if area is None:
+                continue
+            stamp = tuple(
+                (r["prefix"], r.get("advertise", True), r.get("cost"))
+                for r in area.ranges
+            )
+            kept = self._advert_cache.get(aid)
+            if kept is None or kept[0] is not intra or kept[1] != stamp:
+                eff, range_nh_areas, area_active = aggregate_area_ranges(
+                    intra, area.ranges, nh_areas
+                )
+                advert = {}
+                for prefix, dist in eff.items():
+                    exits = range_nh_areas.get(prefix)
+                    if exits is not None:
+                        advert[prefix] = (dist, 0, frozenset(exits))
+                    else:
+                        r = intra[prefix]
+                        advert[prefix] = (
+                            dist, r.prefix_options, nh_areas(r)
+                        )
+                kept = self._advert_cache[aid] = (
+                    intra, stamp, advert, area_active
+                )
+            out[aid] = kept[2]
+            active |= kept[3]
+        for aid in list(self._advert_cache):
+            if aid not in out:
+                del self._advert_cache[aid]
+        self._active_ranges = active
+        return out
 
     def _originate_inter_area(
-        self, intra_by_area: dict, inter_routes: dict, area_results: dict
+        self, adverts: dict, inter_routes: dict, area_results: dict
     ) -> None:
         backbone = IPv4Address(0)
         wanted: dict[IPv4Address, dict] = {aid: {} for aid in self.areas}
-
-        def _nexthops_in_area(route, dst_aid) -> bool:
-            # area.rs:628-630 split horizon: skip a route whose next
-            # hops already exit through the destination area.
-            for ifname, _addr in route.nexthops:
-                iface = self.interfaces.get(ifname)
-                if iface is not None and iface.config.area_id == dst_aid:
-                    return True
-            return False
-
+        nh_areas = self._nh_areas_of()
         # The reference walks the final RIB (area.rs:602-643): intra
         # routes summarize everywhere, inter routes into non-backbone
-        # areas only; a route never returns to its own area.
+        # areas only; a route never returns to its own area, nor (split
+        # horizon, area.rs:628-630) into an area its next hops already
+        # exit through.  candidates: prefix -> (dist, prefix options,
+        # source area, is intra, exit areas).
         candidates: dict = {}
-        for src_aid, intra in intra_by_area.items():
-            for prefix, route in intra.items():
+        for src_aid, advert in adverts.items():
+            for prefix, (dist, popts, exits) in advert.items():
                 cur = candidates.get(prefix)
-                if cur is None or route.dist < cur.dist:
-                    candidates[prefix] = route
+                if cur is None or dist < cur[0]:
+                    candidates[prefix] = (dist, popts, src_aid, True, exits)
         for prefix, route in inter_routes.items():
             if prefix not in candidates:  # intra always wins
-                candidates[prefix] = route
-        for prefix, route in candidates.items():
+                candidates[prefix] = (
+                    route.dist, route.prefix_options, route.area_id,
+                    False, nh_areas(route),
+                )
+        for prefix, (dist, popts, src_aid, intra, exits) in (
+            candidates.items()
+        ):
             for dst_aid in self.areas:
-                if route.area_id == dst_aid:
+                if src_aid == dst_aid:
                     continue
-                if (
-                    route.route_type != "intra-area"
-                    and dst_aid == backbone
-                ):
+                if not intra and dst_aid == backbone:
                     continue  # only intra advertises into the backbone
                 if not self.areas[dst_aid].summary:
                     continue  # totally stubby: default only
-                if _nexthops_in_area(route, dst_aid):
+                if dst_aid in exits:
                     continue
                 cur = wanted[dst_aid].get(prefix)
-                if cur is None or route.dist < cur[0]:
-                    wanted[dst_aid][prefix] = (
-                        route.dist, route.prefix_options
-                    )
+                if cur is None or dist < cur[0]:
+                    wanted[dst_aid][prefix] = (dist, popts)
         default = IPv6Network("::/0")
         for aid, area in self.areas.items():
             if area.stub:
@@ -2144,6 +2492,12 @@ class OspfV3Instance(Actor):
                     cur = asbr_wanted[dst_aid].get(e.lsa.adv_rtr)
                     if cur is None or int(res.dist[v]) < cur:
                         asbr_wanted[dst_aid][e.lsa.adv_rtr] = int(res.dist[v])
+        # Our own LSAs per area, found once for both flush passes.
+        rid = self.router_id
+        own = {
+            aid: [k for k in area.lsdb.entries if k.adv_rtr == rid]
+            for aid, area in self.areas.items()
+        }
         for aid, asbrs in asbr_wanted.items():
             area = self.areas[aid]
             wanted_lsids = set()
@@ -2156,10 +2510,9 @@ class OspfV3Instance(Actor):
                     lsid,
                     P.LsaInterAreaRouter(metric=dist, dest_router_id=rid),
                 )
-            for key in list(area.lsdb.entries):
+            for key in own[aid]:
                 if (
                     key.type == P.LsaType.INTER_AREA_ROUTER
-                    and key.adv_rtr == self.router_id
                     and key.lsid not in wanted_lsids
                 ):
                     e = area.lsdb.entries.get(key)
@@ -2179,10 +2532,9 @@ class OspfV3Instance(Actor):
                         metric=dist, prefix=prefix, prefix_options=popts
                     ),
                 )
-            for key in list(area.lsdb.entries):
+            for key in own[aid]:
                 if (
                     key.type == P.LsaType.INTER_AREA_PREFIX
-                    and key.adv_rtr == self.router_id
                     and key.lsid not in wanted_lsids
                 ):
                     # .get: a flush above may have swept drained MaxAge
@@ -2251,80 +2603,11 @@ class OspfV3Instance(Actor):
         if not was_asbr:
             self._originate_router_lsa()
 
-    def _area_spf(self, area: V3Area, vlink_nexthops: dict | None = None):
-        """Per-area SPF: returns (index, keys, result, atoms, prefix_lsas)
-        or None when we have no router LSA in the area."""
-        now = self.loop.clock.now()
-        routers: dict[IPv4Address, P.LsaRouterV3] = {}
-        networks: dict[tuple, P.LsaNetworkV3] = {}  # (adv, iface id)
-        prefix_lsas: list[tuple] = []  # (adv_rtr, body)
-        for e in area.lsdb.all():
-            if e.current_age(now) >= P.MAX_AGE:
-                continue
-            if e.lsa.type == P.LsaType.ROUTER:
-                routers[e.lsa.adv_rtr] = e.lsa.body
-            elif e.lsa.type == P.LsaType.NETWORK:
-                networks[(e.lsa.adv_rtr, int(e.lsa.lsid))] = e.lsa.body
-            elif e.lsa.type == P.LsaType.INTRA_AREA_PREFIX:
-                prefix_lsas.append((e.lsa.adv_rtr, e.lsa.body))
-        if self.router_id not in routers:
-            return None
-        # Vertex ordering contract: network vertices sort before routers
-        # so zero-cost network->router edges settle first (shared engine
-        # semantics — see the v2/IS-IS marshaling).
-        keys = [("N",) + k for k in sorted(networks, key=lambda k: (int(k[0]), k[1]))]
-        keys += [("R", rid) for rid in sorted(routers, key=int)]
-        index = {k: i for i, k in enumerate(keys)}
-        n = len(keys)
-        is_router = np.array([k[0] == "R" for k in keys], bool)
-        src, dst, cost = [], [], []
-        edge_kind = []  # per edge: router-link type int, or -1 (network)
-        edge_nbr_ifid = []  # p2p/vlink: the neighbor's iface id
-        for rid, body in routers.items():
-            u = index[("R", rid)]
-            for link in body.links:
-                if link.link_type == P.RouterLinkType.TRANSIT_NETWORK:
-                    v = index.get(
-                        ("N", link.nbr_router_id, link.nbr_iface_id)
-                    )
-                else:
-                    v = index.get(("R", link.nbr_router_id))
-                if v is not None:
-                    src.append(u)
-                    dst.append(v)
-                    cost.append(link.metric)
-                    edge_kind.append(int(link.link_type))
-                    edge_nbr_ifid.append(link.nbr_iface_id)
-        for (adv, ifid), body in networks.items():
-            u = index[("N", adv, ifid)]
-            for member in body.attached:
-                v = index.get(("R", member))
-                if v is not None:
-                    src.append(u)
-                    dst.append(v)
-                    cost.append(0)
-                    edge_kind.append(-1)
-                    edge_nbr_ifid.append(0)
-        from holo_tpu.ops.graph import mutual_keep_mask
-
-        src_a = np.array(src, np.int32).reshape(-1)
-        dst_a = np.array(dst, np.int32).reshape(-1)
-        keep = mutual_keep_mask(src_a, dst_a)
-        edge_kind = [k for k, kp in zip(edge_kind, keep) if kp]
-        edge_nbr_ifid = [
-            i for i, kp in zip(edge_nbr_ifid, keep) if kp
-        ]
-        topo = Topology(
-            n_vertices=n,
-            is_router=is_router,
-            edge_src=src_a[keep],
-            edge_dst=dst_a[keep],
-            edge_cost=np.array(cost, np.int32).reshape(-1)[keep],
-            root=index[("R", self.router_id)],
-        )
-
-        atoms = []
-        atom_ids = np.full(topo.n_edges, -1, np.int32)
+    def _area_marshal(self, area: V3Area, vlink_nexthops: dict | None = None):
+        """The area's LSDB as an ``SpfTopologyV3`` through the lowering
+        the area keeps between runs, or None when we have no router LSA
+        in the area.  Only the interface maps are built here, per
+        interface; nothing loops over the LSDB's links."""
         # Per-link hop resolution: parallel p2p links to the same
         # neighbor are distinct atoms, matched by the neighbor's
         # interface id carried in its hellos (and in our router-LSA's
@@ -2332,6 +2615,7 @@ class OspfV3Instance(Actor):
         nbr_hop = {}  # rid -> (ifname, src) — any one link (fallback)
         nbr_hop_by_ifid = {}  # (rid, nbr iface id) -> (ifname, src)
         lan_iface_of = {}  # network vertex key -> our iface on that LAN
+        iface_srlg = {}
         for iface in self._area_ifaces(area):
             for nbr in iface.neighbors.values():
                 if nbr.state == NsmState.FULL and not iface.is_lan:
@@ -2344,123 +2628,55 @@ class OspfV3Instance(Actor):
                 lan_iface_of[
                     ("N", iface.dr, self._dr_iface_id(iface))
                 ] = iface
-        root_lans: set[int] = set()
-        for e_i in range(topo.n_edges):
-            if topo.edge_src[e_i] == topo.root:
-                k = keys[int(topo.edge_dst[e_i])]
-                if k[0] == "R":
-                    hop = None
-                    if edge_kind[e_i] == int(
-                        P.RouterLinkType.VIRTUAL_LINK
-                    ):
-                        # Virtual link: borrowed transit-area set only —
-                        # a direct-adjacency fallback here would pair
-                        # the vlink metric with the wrong next hop.
-                        borrowed = (vlink_nexthops or {}).get(k[1])
-                        if borrowed:
-                            from holo_tpu.protocols.ospf.spf_run import (
-                                NexthopAtom,
-                            )
-
-                            hop = NexthopAtom(None, None, borrowed)
-                    else:
-                        hop = nbr_hop_by_ifid.get(
-                            (k[1], edge_nbr_ifid[e_i])
-                        ) or nbr_hop.get(k[1])
-                    if hop is not None:
-                        atom_ids[e_i] = len(atoms)
-                        atoms.append(hop)
-                elif k in lan_iface_of:
-                    # Directly-attached LAN: the network vertex's route
-                    # (the LAN prefix) is reached on the interface itself
-                    # — same (ifname, no-address) atom the v2 marshaling
-                    # assigns (spf_run.py root_edge_data).
-                    root_lans.add(int(topo.edge_dst[e_i]))
-                    atom_ids[e_i] = len(atoms)
-                    atoms.append((lan_iface_of[k].name, None))
-        # Network -> member edges on root-attached LANs: the direct next
-        # hop is the member's link-local on that LAN (hops==0 rule).
-        for e_i in range(topo.n_edges):
-            u = int(topo.edge_src[e_i])
-            if u in root_lans:
-                iface = lan_iface_of[keys[u]]
-                member = keys[int(topo.edge_dst[e_i])][1]
-                if member == self.router_id:
-                    continue
-                nbr = iface.neighbors.get(member)
-                if nbr is not None:
-                    atom_ids[e_i] = len(atoms)
-                    atoms.append((iface.name, nbr.src))
-        topo.edge_direct_atom = atom_ids
-        iface_srlg = {
-            i.name: srlg_bits(i.config.srlg)
-            for i in self._area_ifaces(area)
-            if i.config.srlg
-        }
-        if iface_srlg:
-            # v3 atoms are NexthopAtom (vlinks) or (ifname, addr)
-            # tuples — normalize to per-atom interface names.
-            apply_interface_srlg(
-                topo,
-                [
-                    a.ifname if hasattr(a, "ifname") else a[0]
-                    for a in atoms
-                ],
-                iface_srlg,
-            )
-        if self.spf_partition_of:
-            # Hierarchical partition hint (ISSUE 15): router groups
-            # from config; a network vertex rides the lowest-labeled
-            # attached router (v2 contract — zero-cost net->rtr edges
-            # stay intra-partition wherever the grouping allows).
-            from holo_tpu.protocols.ospf.spf_run import (
-                apply_partition_hint,
-            )
-
-            part_of = self.spf_partition_of
-            groups: list = []
-            for k in keys:
-                if k[0] == "R":
-                    groups.append(part_of.get(k[1]))
-                else:
-                    att = [
-                        part_of[m]
-                        for m in networks[(k[1], k[2])].attached
-                        if m in part_of
-                    ]
-                    groups.append(min(att) if att else None)
-            apply_partition_hint(topo, groups)
-        topo.touch()
-
-        # DeltaPath seam (same contract as the v2 instance): identical
-        # vertex ordering + atom table → diff against the previous
-        # run's topology so the device-resident graph updates in place.
-        prev = self._spf_delta_bases.get(area.area_id)
-        if prev is not None and prev[0] == keys and prev[1] == atoms:
-            from holo_tpu.ops.graph import diff_topologies
-
-            delta = diff_topologies(prev[2], topo)
-            if delta is not None:
-                topo.link_delta(delta)
-        self._spf_delta_bases[area.area_id] = (keys, atoms, topo)
-
-        mp_k = (
-            self.max_paths
-            if self.max_paths is not None and self.max_paths > 1
-            else 1
+            if iface.config.srlg:
+                iface_srlg[iface.name] = srlg_bits(iface.config.srlg)
+        lowering = self._spf_lowerings.get(area.area_id)
+        if lowering is None:
+            lowering = self._spf_lowerings[area.area_id] = LoweredLsdbV3()
+        st = lowering.build_topology(
+            area.lsdb, self.router_id, self.loop.clock.now(), nbr_hop,
+            nbr_hop_by_ifid, lan_iface_of, vlink_nexthops,
+            iface_srlg=iface_srlg, partition_of=self.spf_partition_of,
+            keep_unchanged=self.reuse_unchanged_areas,
         )
-        res = self.backend.compute(topo, multipath_k=mp_k)
-        # IP-FRR: the area's backup-table batch rides the same SPF
-        # moment (all-roots matrix + per-link post-convergence planes).
+        if st is None:
+            self._spf_delta_bases.pop(area.area_id, None)
+            self._spf_lowerings.pop(area.area_id, None)
+            self._area_kept.pop(area.area_id, None)
+        return st
+
+    def _area_frr(self, area_id, topo) -> None:
+        """IP-FRR: the area's backup-table batch rides the same SPF
+        moment (all-roots matrix + per-link post-convergence planes)."""
         cfg = self.frr
         if cfg is not None and cfg.active():
             from holo_tpu.frr.manager import ensure_engine
 
             self._frr_engine = ensure_engine(self._frr_engine, cfg)
-            self.frr_tables[area.area_id] = self._frr_engine.compute(topo)
+            self.frr_tables[area_id] = self._frr_engine.compute(topo)
         else:
-            self.frr_tables.pop(area.area_id, None)
-        return index, keys, res, atoms, prefix_lsas
+            self.frr_tables.pop(area_id, None)
+
+    def _area_spf(self, area: V3Area, vlink_nexthops: dict | None = None):
+        """One area's marshal and SPF outside a run (the protocol's own
+        marshal as ``spf/synth_proto.py`` extracts it): (index, keys,
+        result, atoms, prefix_lsas), or None when we have no router LSA
+        in the area."""
+        st = self._area_marshal(area, vlink_nexthops)
+        if st is None:
+            return None
+        prev = self._spf_delta_bases.get(area.area_id)
+        if prev is not st:
+            link_spf_delta(prev, st)
+        self._spf_delta_bases[area.area_id] = st
+        mp_k = (
+            self.max_paths
+            if self.max_paths is not None and self.max_paths > 1
+            else 1
+        )
+        res = self.backend.compute(st.topo, multipath_k=mp_k)
+        self._area_frr(area.area_id, st.topo)
+        return st.index, st.keys, res, st.atoms, st.prefix_lsas
 
     # -- rx/tx
 

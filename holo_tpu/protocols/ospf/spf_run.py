@@ -12,12 +12,19 @@ Bridges the protocol LSDB to the tensor/scalar SPF backends:
 - :func:`derive_routes` turns backend results (distances + ECMP atom
   bitmasks) into per-prefix intra-area routes (reference
   route::update_rib_full, holo-ospf/src/route.rs:146-197).
+
+What OSPFv2 and OSPFv3 share beyond that lives here too, as the
+reference shares it over its ``Version`` trait: the RFC 8405 SPF-delay
+FSM (:class:`SpfDelayFsm`), area address ranges
+(:func:`aggregate_area_ranges`) and OSPFv3's own kept lowering
+(:class:`LoweredLsdbV3`, the same splice machinery over v3 LSA types).
 """
 
 from __future__ import annotations
 
+import enum
 import operator
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from ipaddress import IPv4Address, IPv4Network
 
 import numpy as np
@@ -40,6 +47,136 @@ from holo_tpu.protocols.ospf.packet import (
 )
 from holo_tpu.spf.backend import SpfResult
 from holo_tpu.utils.ip import apply_mask
+
+
+# ===== SPF delay FSM (RFC 8405; reference holo-ospf/src/spf.rs:270-484) ==
+
+
+class SpfFsmState(enum.Enum):
+    QUIET = "quiet"
+    SHORT_WAIT = "short-wait"
+    LONG_WAIT = "long-wait"
+
+
+@dataclass
+class SpfTimers:
+    initial_delay: float = 0.05
+    short_delay: float = 0.2
+    long_delay: float = 5.0
+    hold_down: float = 10.0
+    time_to_learn: float = 0.5
+
+
+class SpfDelayFsm:
+    """The RFC 8405 SPF-delay FSM of an instance actor, v2 or v3
+    (reference holo-ospf/src/spf.rs:295-484, one FSM over the
+    ``Version`` trait): QUIET→SHORT_WAIT on the first IGP event
+    (``initial_delay``); further events in SHORT_WAIT use
+    ``short_delay`` until ``time_to_learn`` expires, then LONG_WAIT uses
+    ``long_delay``; ``hold_down`` of quiet returns to QUIET.  The
+    instance owns the two loop timers and hands them in."""
+
+    spf_state = SpfFsmState.QUIET
+    _learn_deadline: float | None = None
+
+    def _spf_delay_event(
+        self, cfg: SpfTimers, now: float, spf_timer, hold_timer
+    ) -> None:
+        """One IGP event: (re)arm the SPF timer as the state says."""
+        hold_timer.start(cfg.hold_down)  # reset on every IGP event
+        if self.spf_state == SpfFsmState.QUIET:
+            self._learn_deadline = now + cfg.time_to_learn
+            self.spf_state = SpfFsmState.SHORT_WAIT
+            spf_timer.start(cfg.initial_delay)
+        elif self.spf_state == SpfFsmState.SHORT_WAIT:
+            if now >= (self._learn_deadline or 0):
+                self.spf_state = SpfFsmState.LONG_WAIT
+                spf_timer.start(cfg.long_delay)
+            elif not spf_timer.armed:
+                spf_timer.start(cfg.short_delay)
+        elif self.spf_state == SpfFsmState.LONG_WAIT:
+            if not spf_timer.armed:
+                spf_timer.start(cfg.long_delay)
+
+    def _spf_holddown_fired(self) -> None:
+        self.spf_state = SpfFsmState.QUIET
+        self._learn_deadline = None
+
+
+# ===== Area address ranges (RFC 2328 §12.4.3 / Appendix C.2) ============
+
+
+def _range_key(prefix) -> tuple[int, int]:
+    """A range by its length and its network's leading bits: what a
+    prefix under it is probed with."""
+    return (
+        prefix.prefixlen,
+        int(prefix.network_address)
+        >> (prefix.max_prefixlen - prefix.prefixlen),
+    )
+
+
+def aggregate_area_ranges(routes: dict, ranges, nh_areas_of) -> tuple:
+    """One area's intra-area routes as its ABR advertises them into the
+    other areas: components of an active advertised range aggregate
+    into the range's prefix at the largest component distance (or the
+    range's configured cost), ``advertise=false`` ranges black-hole
+    their components, the most specific range wins, and a prefix under
+    no range goes as it is.
+
+    ``routes``: ``{prefix: route}`` with ``route.dist``.  ``ranges``:
+    ``[{"prefix", "advertise", "cost"}]``.  ``nh_areas_of(route)``: the
+    areas the route's next hops exit through.  Returns ``(eff,
+    range_nh_areas, active)``: ``{prefix: distance}`` to advertise; per
+    aggregate the areas its COMPONENTS exit through, which the
+    caller's split horizon must cover too; and the prefixes of the
+    ranges that are active (one component or more is reachable),
+    advertised or not (RFC 2328 §16.2 (3) ignores a summary that
+    equals one)."""
+    if not ranges:
+        return {p: r.dist for p, r in routes.items()}, {}, set()
+    # The most specific range of a prefix is one dict probe per range
+    # length, longest first, instead of a ``subnet_of`` per range.  Of
+    # two ranges with one prefix the first stays, as ``max(matches)``
+    # over the list kept it.
+    index: dict = {}
+    for r in ranges:
+        index.setdefault(_range_key(r["prefix"]), r)
+    lengths = sorted({k[0] for k in index}, reverse=True)
+    eff: dict = {}
+    # Per range key (hashing an ip_network costs more than the rest of
+    # the loop): [largest advertised distance, exit areas].
+    acc: dict = {}
+    for prefix, route in routes.items():
+        key = None
+        plen, bits = prefix.prefixlen, prefix.max_prefixlen
+        net = int(prefix.network_address)
+        for length in lengths:
+            if length <= plen and (length, net >> (bits - length)) in index:
+                key = (length, net >> (bits - length))
+                break
+        if key is None:
+            eff[prefix] = route.dist
+            continue
+        slot = acc.get(key)
+        if slot is None:
+            slot = acc[key] = [-1, set()]
+        if index[key].get("advertise", True):
+            if route.dist > slot[0]:
+                slot[0] = route.dist
+            slot[1].update(nh_areas_of(route))
+    active = {index[key]["prefix"] for key in acc}
+    range_nh_areas = {
+        index[key]["prefix"]: slot[1]
+        for key, slot in acc.items() if slot[0] >= 0
+    }
+    for r in ranges:
+        slot = acc.get(_range_key(r["prefix"]))
+        if slot is not None and slot[0] >= 0:
+            eff[r["prefix"]] = (
+                r["cost"] if r.get("cost") is not None else slot[0]
+            )
+    return eff, range_nh_areas, active
 
 
 def srlg_bits(groups) -> int:
@@ -179,7 +316,13 @@ class _VertexIds:
         # Of equal ids the dict keeps the LAST LSA's body, at the place
         # the id was FIRST inserted: the segments to emit, in order ...
         lasts = self.order[np.roll(first, -1)]
-        self.emit = lasts[np.argsort(self.order[first])]
+        # (OSPFv3 numbers its vertices by DISTINCT id: several
+        # Router-LSAs of one router are one vertex.  ``uniq[g]`` is
+        # vertex g's id, ``last[g]`` the LSA whose body it has, and
+        # ``emit_rank`` the vertices in the order their segments go.)
+        self.uniq, self.last = by_id[first], lasts
+        self.emit_rank = np.argsort(self.order[first], kind="stable")
+        self.emit = lasts[self.emit_rank]
         # ... and the body behind every vertex.
         self.body = lasts[np.cumsum(first) - 1]
 
@@ -290,9 +433,10 @@ class LoweredLsdb:
             bodies,
         )
 
-    def _refresh(self, lsdb: Lsdb) -> None:
+    def _refresh(self, lsdb: Lsdb) -> list:
         """Bring the lowering up to ``lsdb``: lower the entries that are
-        not the kept object at their place, splice their segments in."""
+        not the kept object at their place, splice their segments in.
+        Returns the ``(lo, hi)`` runs of places that were lowered."""
         cur = list(lsdb.entries.values())
         kept = self.entries
         stale = [
@@ -316,7 +460,7 @@ class LoweredLsdb:
         _TOPOLOGY_LSAS.labels(path="lowered").inc(lowered)
         _TOPOLOGY_LSAS.labels(path="reused").inc(len(cur) - lowered)
         if not runs:
-            return
+            return runs
         parts = [self._lower(f) for f in fresh]
         off = self._link_off
         self._links = _spliced(
@@ -333,6 +477,7 @@ class LoweredLsdb:
             self._bodies[lo:hi] = bodies
         self.entries = cur
         self._link_off = np.concatenate(([0], np.cumsum(self._n_links)))
+        return runs
 
     def _vertex_model(self, r_pos, n_pos) -> _VertexModel:
         """The vertex model of the live router and network LSAs at
@@ -537,6 +682,333 @@ class LoweredLsdb:
         return SpfTopology(topo, atoms, m.router_index, m.network_index)
 
 
+# ===== OSPFv3: the same kept lowering over RFC 5340's LSA types ========
+
+# Entry kinds beyond the base's (an Intra-Area-Prefix LSA takes no part
+# in the graph, but its body is what routes are derived from).
+_PREFIX = 3
+#: a network-LSA's attached-router row (router links carry their
+#: RouterLinkType, all positive)
+_V3_ATTACHED = -1
+
+
+@dataclass
+class SpfTopologyV3(SpfTopology):
+    """What one OSPFv3 area marshals to.  ``router_index`` is the whole
+    vertex index (``("R", router id)`` and ``("N", DR router id, DR
+    interface id)`` keys; ``network_index`` stays empty), so
+    :func:`link_spf_delta` guards a v3 area with the comparisons it
+    makes for a v2 one."""
+
+    keys: list = field(default_factory=list)  # vertex -> key
+    # live Intra-Area-Prefix LSAs, LSDB order: (adv_rtr, body)
+    prefix_lsas: list = field(default_factory=list)
+
+    @property
+    def index(self) -> dict:
+        return self.router_index
+
+
+@dataclass
+class _VertexModelV3:
+    rtr: _VertexIds  # live Router-LSAs; vertex nn + g for distinct id g
+    net: _VertexIds  # live Network-LSAs; vertex g
+    keys: list
+    index: dict
+    seg_pos: np.ndarray  # emitted segments, as places in r_pos ++ n_pos
+    seg_vertex: np.ndarray  # and the vertex each leaves from
+
+
+class LoweredLsdbV3(LoweredLsdb):
+    """:class:`LoweredLsdb` for an OSPFv3 area (RFC 5340 §4.8.1: the
+    vertex model of RFC 2328 §16.1 keyed by router id and by the DR's
+    (router id, interface id)).  Router-LSAs of one router are one
+    vertex, with the links of the last one in LSDB order, as a dict
+    keyed by advertising router holds them; a link row is ``(link type
+    or -1, neighbour router id, metric, neighbour interface id)``."""
+
+    def __init__(self) -> None:
+        super().__init__()
+        # (router id << 32 | interface id) needs all 64 bits
+        self._vid = np.zeros(0, np.uint64)
+        self._model3: _VertexModelV3 | None = None
+        # The last call's result, the other inputs it was made from and
+        # which of the entries that matter were live: handed out again
+        # while all of them stay what they were.
+        self._kept: tuple | None = None
+
+    @staticmethod
+    def _lower(entries) -> tuple:
+        from holo_tpu.protocols.ospf import packet_v3 as P
+
+        kind, vid, age, installed_at, n_links, links, bodies = (
+            [], [], [], [], [], [], []
+        )
+        for e in entries:
+            lsa = e.lsa
+            k, v, body, n0 = _OTHER, 0, None, len(links)
+            if lsa.type == P.LsaType.ROUTER:
+                k, v, body = _ROUTER, int(lsa.adv_rtr), lsa.body
+                for link in body.links:
+                    links.append((
+                        int(link.link_type), int(link.nbr_router_id),
+                        link.metric, link.nbr_iface_id,
+                    ))
+            elif lsa.type == P.LsaType.NETWORK:
+                k, body = _NETWORK, lsa.body
+                v = (int(lsa.adv_rtr) << 32) | int(lsa.lsid)
+                for rid in body.attached:
+                    links.append((_V3_ATTACHED, int(rid), 0, 0))
+            elif lsa.type == P.LsaType.INTRA_AREA_PREFIX:
+                k, body = _PREFIX, lsa.body
+            kind.append(k)
+            vid.append(v)
+            age.append(lsa.age)
+            installed_at.append(e.installed_at)
+            n_links.append(len(links) - n0)
+            bodies.append(body)
+        return (
+            (
+                np.array(kind, np.int8),
+                np.array(vid, np.uint64),
+                np.array(age, np.float64),
+                np.array(installed_at, np.float64),
+                np.array(n_links, np.int64),
+            ),
+            np.array(links, np.int64).reshape(-1, 4),
+            bodies,
+        )
+
+    def _moved(self, was: np.ndarray, before: list, runs: list) -> bool:
+        """Whether the refresh that lowered ``runs`` replaced, added or
+        dropped an entry that matters to the result (a Router-,
+        Network- or Intra-Area-Prefix LSA); ``was`` / ``before``: kinds
+        and entries as they were."""
+        if not runs:
+            return False
+        now = self.entries
+        if len(before) == len(now):
+            return any(
+                was[lo:hi].any() or self._kind[lo:hi].any()
+                for lo, hi in runs
+            )
+        # A changed length re-lowers all from the first difference on:
+        # what came or went is told by identity, not by place.
+        lo = runs[0][0]
+        old_ids = {id(e) for e in before[lo:]}
+        new_ids = {id(e) for e in now[lo:]}
+        return any(
+            was[lo + i] and id(e) not in new_ids
+            for i, e in enumerate(before[lo:])
+        ) or any(
+            self._kind[lo + i] and id(e) not in old_ids
+            for i, e in enumerate(now[lo:])
+        )
+
+    def _vertex_model3(self, r_pos, n_pos) -> _VertexModelV3:
+        r_ids, n_ids = self._vid[r_pos], self._vid[n_pos]
+        m = self._model3
+        if (
+            m is not None
+            and np.array_equal(m.rtr.ids, r_ids)
+            and np.array_equal(m.net.ids, n_ids)
+        ):
+            return m
+        rtr, net = _VertexIds(r_ids), _VertexIds(n_ids)
+        # Vertex ordering contract: networks sort before routers, so
+        # that zero-cost network->router edges settle first.
+        keys: list = []
+        for i in n_pos[net.last].tolist():
+            lsa = self.entries[i].lsa
+            keys.append(("N", lsa.adv_rtr, int(lsa.lsid)))
+        keys += [
+            ("R", self.entries[i].lsa.adv_rtr)
+            for i in r_pos[rtr.last].tolist()
+        ]
+        m = self._model3 = _VertexModelV3(
+            rtr, net, keys, {k: i for i, k in enumerate(keys)},
+            seg_pos=np.concatenate((rtr.emit, len(r_pos) + net.emit)),
+            seg_vertex=np.concatenate(
+                (len(net.uniq) + rtr.emit_rank, net.emit_rank)
+            ),
+        )
+        return m
+
+    def build_topology(
+        self,
+        lsdb: Lsdb,
+        router_id: IPv4Address,
+        now: float,
+        nbr_hop: dict,
+        nbr_hop_by_ifid: dict,
+        lan_iface_of: dict,
+        vlink_nexthops: dict | None = None,
+        iface_srlg: dict[str, int] | None = None,
+        partition_of: dict | None = None,
+        keep_unchanged: bool = False,
+    ) -> SpfTopologyV3 | None:
+        """One OSPFv3 area's LSDB as the SPF vertex / edge model, or
+        None while the area holds no Router-LSA of ours.
+
+        ``keep_unchanged``: where nothing this result is made from has
+        changed since the last call, return the last call's OBJECT.
+        What it is made from: the area's Router-, Network- and
+        Intra-Area-Prefix LSAs (an entry replaced or gone, and one that
+        reached MaxAge on the clock since), and every argument but
+        ``now``.
+
+        ``nbr_hop``: FULL point-to-point neighbour's router id ->
+        ``(ifname, link-local)``; ``nbr_hop_by_ifid``: the same keyed by
+        ``(router id, the neighbour's interface id)``, so that parallel
+        links each resolve through their own interface;
+        ``lan_iface_of``: network vertex key -> our interface on that
+        LAN.  Edges come in LSDB order, links in LSA order, Router-LSAs
+        before Network-LSAs; next-hop atoms are assigned for the root's
+        own out-edges and for the edges out of its LANs."""
+        from holo_tpu.protocols.ospf import packet_v3 as P
+
+        was, before = self._kind, self.entries
+        runs = self._refresh(lsdb)
+        live = ~(self._age + (now - self._installed_at) >= MAX_AGE)
+        inputs = (
+            router_id, nbr_hop, nbr_hop_by_ifid,
+            {
+                k: (i.name, sorted(
+                    (int(r), nb.src) for r, nb in i.neighbors.items()
+                ))
+                for k, i in lan_iface_of.items()
+            },
+            vlink_nexthops or None, iface_srlg or None,
+            partition_of or None,
+        )
+        # In LSDB order, so that an entry of another type coming or
+        # going between them moves nothing here.
+        live_matter = live[self._kind != _OTHER]
+        kept = self._kept
+        if (
+            keep_unchanged
+            and kept is not None
+            and not self._moved(was, before, runs)
+            and np.array_equal(live_matter, kept[2])
+            and inputs == kept[1]
+        ):
+            return kept[0]
+        self._kept = None
+        r_pos = np.flatnonzero(live & (self._kind == _ROUTER))
+        n_pos = np.flatnonzero(live & (self._kind == _NETWORK))
+        m = self._vertex_model3(r_pos, n_pos)
+        keys, index = m.keys, m.index
+        nn, n = len(m.net.uniq), len(keys)
+        root = index.get(("R", router_id))
+        if root is None:
+            return None
+        bodies, entries = self._bodies, self.entries
+        prefix_lsas = [
+            (entries[i].lsa.adv_rtr, bodies[i])
+            for i in np.flatnonzero(live & (self._kind == _PREFIX)).tolist()
+        ]
+        is_router = np.zeros(n, bool)
+        is_router[nn:] = True
+
+        seg = np.concatenate((r_pos, n_pos))[m.seg_pos]
+        count = self._n_links[seg]
+        end = np.cumsum(count)
+        rows = np.repeat(self._link_off[seg] - (end - count), count)
+        rows += np.arange(len(rows))
+        kind, nbr, metric, ifid = self._links[rows].T
+        src = np.repeat(m.seg_vertex, count)
+        # A transit link ends at the DR's network vertex, every other
+        # link (and a network's attached router) at a router vertex.
+        transit = kind == int(P.RouterLinkType.TRANSIT_NETWORK)
+        nbr64 = nbr.astype(np.uint64)
+        at_r, there_r = lookup_sorted(m.rtr.uniq, nbr64)
+        at_n, there_n = lookup_sorted(
+            m.net.uniq, (nbr64 << np.uint64(32)) | ifid.astype(np.uint64)
+        )
+        dst = np.where(transit, at_n, nn + at_r)
+        edge = np.flatnonzero(np.where(transit, there_n, there_r))
+        edge = edge[mutual_keep_mask(src[edge], dst[edge])]
+        kind, ifid = kind[edge], ifid[edge]
+        topo = Topology(
+            n_vertices=n,
+            is_router=is_router,
+            edge_src=src[edge].astype(np.int32),
+            edge_dst=dst[edge].astype(np.int32),
+            edge_cost=metric[edge].astype(np.int32),
+            root=root,
+        )
+
+        # Per-link hop resolution: parallel p2p links to one neighbour
+        # are distinct atoms, matched by the neighbour's interface id.
+        atoms: list = []
+        atom_ids = np.full(topo.n_edges, -1, np.int32)
+        root_lans: list[int] = []
+        for e in np.flatnonzero(topo.edge_src == root).tolist():
+            k = keys[int(topo.edge_dst[e])]
+            if k[0] == "R":
+                hop = None
+                if kind[e] == int(P.RouterLinkType.VIRTUAL_LINK):
+                    # Virtual link: the borrowed transit-area set only;
+                    # a direct adjacency here would pair the vlink
+                    # metric with the wrong next hop.
+                    borrowed = (vlink_nexthops or {}).get(k[1])
+                    if borrowed:
+                        hop = NexthopAtom(None, None, borrowed)
+                else:
+                    hop = nbr_hop_by_ifid.get(
+                        (k[1], int(ifid[e]))
+                    ) or nbr_hop.get(k[1])
+                if hop is not None:
+                    atom_ids[e] = len(atoms)
+                    atoms.append(hop)
+            elif k in lan_iface_of:
+                # Directly-attached LAN: reached on the interface
+                # itself, the (ifname, no address) atom of v2.
+                root_lans.append(int(topo.edge_dst[e]))
+                atom_ids[e] = len(atoms)
+                atoms.append((lan_iface_of[k].name, None))
+        # Network -> member edges of the root's LANs: the member's
+        # link-local on that LAN (the hops == 0 rule).
+        if root_lans:
+            for e in np.flatnonzero(
+                np.isin(topo.edge_src, root_lans)
+            ).tolist():
+                iface = lan_iface_of[keys[int(topo.edge_src[e])]]
+                member = keys[int(topo.edge_dst[e])][1]
+                if member == router_id:
+                    continue
+                member_nbr = iface.neighbors.get(member)
+                if member_nbr is not None:
+                    atom_ids[e] = len(atoms)
+                    atoms.append((iface.name, member_nbr.src))
+        topo.edge_direct_atom = atom_ids
+        if iface_srlg:
+            # v3 atoms are NexthopAtom (vlinks) or (ifname, addr) tuples.
+            apply_interface_srlg(
+                topo,
+                [a.ifname if hasattr(a, "ifname") else a[0] for a in atoms],
+                iface_srlg,
+            )
+        if partition_of:
+            # A network vertex rides the lowest-labelled attached router.
+            groups: list = []
+            for i in n_pos[m.net.last].tolist():
+                att = [
+                    partition_of[r]
+                    for r in bodies[i].attached
+                    if r in partition_of
+                ]
+                groups.append(min(att) if att else None)
+            groups += [partition_of.get(k[1]) for k in keys[nn:]]
+            apply_partition_hint(topo, groups)
+        topo.touch()
+        st = SpfTopologyV3(
+            topo, atoms, index, {}, keys=keys, prefix_lsas=prefix_lsas,
+        )
+        self._kept = (st, inputs, live_matter)
+        return st
+
+
 def build_topology(
     lsdb: Lsdb,
     router_id: IPv4Address,
@@ -635,7 +1107,7 @@ class IntraRoute:
     nh_weights: dict | None = None
 
 
-_DERIVE_NEXTHOPS = telemetry.counter(
+DERIVE_NEXTHOPS = _DERIVE_NEXTHOPS = telemetry.counter(
     "holo_ospf_derive_nexthops_total",
     "derive_routes' prefix offers by how the offering vertex's next-hop "
     "set was had: decoded from its bitmask row (once per distinct row "

@@ -49,9 +49,17 @@ def _spanning_edges(n: int, extra: int, rng) -> list[tuple[int, int, int]]:
 def ospfv3_multiarea_topologies(
     n_routers: int = 10_000, n_areas: int = 4, seed: int = 0
 ) -> list:
-    """BASELINE config 2: one ABR instance attached to ``n_areas`` areas
-    totalling ``n_routers`` routers; returns the per-area ``Topology``
-    objects produced by the instance's own ``_area_spf`` marshal."""
+    """BASELINE config 2 at the parity tests' size: one ABR instance
+    attached to ``n_areas`` random areas totalling ``n_routers`` routers;
+    returns the per-area ``Topology`` objects produced by the instance's
+    own ``_area_spf`` marshal.
+
+    The DEPLOYMENT for ``configs[1]`` is ``benchmark/areanet.py``
+    (configuration ``ospfv3-multiarea-10k``: four k = 44 fat-tree halls
+    and a backbone behind shared border routers, 9,660 routers, with
+    prefixes, ranges, a RIB and events).  This builder stays for
+    ``tests/test_synth_proto.py``, which runs it at 1/25 of that size;
+    it is not a second 10k generator and nothing measures it."""
     from holo_tpu.protocols.ospf import packet_v3 as P
     from holo_tpu.protocols.ospf.instance_v3 import (
         OspfV3Instance,

@@ -229,3 +229,78 @@ def test_storm_past_the_depth_cap_compiles_nothing_in_its_window(monkeypatch):
     assert rc == 3 and full_depth >= 1
     assert result["checks"]["no_compile_in_window"] is True
     assert result["checks"]["parity"] and result["failed"] == 0
+
+
+def _areas_of_one_shape(n_areas: int = 4, k: int = 6):
+    """Same-shaped areas of one ABR: the k-ary fat-tree with other
+    per-direction costs in each, the root the same vertex in all."""
+    from benchmark import fabric
+
+    return [fabric.fat_tree(k, 1, 3, seed=40 + a) for a in range(n_areas)]
+
+
+def test_four_areas_of_one_shape_share_every_program_and_one_width_floor(
+    programs,
+):
+    """ISSUE 31: an ABR serves several residents in turn through one
+    backend, four of them of one shape.  The first area compiles the
+    full program and the apply + incremental pair; the other three, and
+    every later delta on any of them, compile nothing, and their delta
+    chains advance apart."""
+    areas = _areas_of_one_shape()
+    be = TpuSpfBackend()
+    _same_bits(be.compute(areas[0]), areas[0])
+    step = _linked(areas[0], clone_topology(
+        areas[0], cost={0: int(areas[0].edge_cost[0]) + 1}
+    ))
+    _same_bits(be.compute(step), step)
+    heads = [step] + areas[1:]
+    compiles, built = _compiles(), programs.n
+    for topo in areas[1:]:
+        _same_bits(be.compute(topo), topo)
+    assert {be.prepare(t).in_src.shape for t in heads} == {
+        be.prepare(step).in_src.shape
+    }
+    # deltas in turn, two rounds: a cost change, then a lost switch
+    for round_ in range(2):
+        for a, head in enumerate(heads):
+            if round_ == 0:
+                nxt = clone_topology(
+                    head, cost={5 + a: int(head.edge_cost[5 + a]) + 2}
+                )
+            else:
+                lost = 3 + a
+                nxt = clone_topology(
+                    head,
+                    keep=(head.edge_src != lost) & (head.edge_dst != lost),
+                )
+            heads[a] = _linked(head, nxt)
+            _same_bits(be.compute(heads[a]), heads[a])
+    assert _compiles() == compiles and programs.n == built
+    delta = telemetry.snapshot("holo_spf_delta_total")
+    assert sum(v for k, v in delta.items() if "path=incremental" in k) >= 9
+    # one floor for the four of them (and one key: vertices, root, atoms)
+    floors = [
+        k for k in shared_graph_cache()._k_pad_floor
+        if k[0] == areas[0].n_vertices and k[1] == int(areas[0].root)
+    ]
+    assert len(floors) == 1
+
+
+def test_remarshal_of_one_area_keeps_the_width_its_siblings_compiled(programs):
+    """One of four same-shaped areas re-marshals with no lineage while
+    a switch is down (the atom table changed): the floor the four share
+    keeps its ELL width, so the full program it runs is the siblings'."""
+    areas = _areas_of_one_shape()
+    be = TpuSpfBackend()
+    for topo in areas:
+        be.compute(topo)
+    shape = be.prepare(areas[0]).in_src.shape
+    compiles, built = _compiles(), programs.n
+    widest = int(np.argmax(np.bincount(areas[2].edge_dst)))
+    keep = (areas[2].edge_src != widest) & (areas[2].edge_dst != widest)
+    again = clone_topology(areas[2], keep=keep)  # no link_delta: lineage-less
+    assert again.delta_base is None
+    _same_bits(be.compute(again), again)
+    assert be.prepare(again).in_src.shape == shape
+    assert _compiles() == compiles and programs.n == built

@@ -553,7 +553,10 @@ def test_metric_file_reads_the_decoded_share_of_the_counter():
         "name": "storm_derive_decode_share", "unit": "%", "better": "lower",
         "source": "program_counter", "layer": "readback + routes",
         "moves": "trigger_fib_p50_ms",
-        "workloads": ["backbone10k-flapstorm", "isp-zoo-storm"],
+        # the two OSPFv2 storm cells; later cells are appended (PR 31)
+        "workloads": [
+            "backbone10k-flapstorm", "isp-zoo-storm", *entry["workloads"][2:]
+        ],
     }
 
 
