@@ -268,12 +268,76 @@ class NexthopAtom:
 
 
 @dataclass
+class _ReachedFlags:
+    """What :meth:`DerivePlan.reached_flags` made last, and from what:
+    one per kept lowering, shared by the plans of its runs."""
+
+    routers: list | None = None  # the vertex model's list, by identity
+    flags: np.ndarray | None = None
+    every: dict = field(default_factory=dict)  # all routers, index order
+    reached: np.ndarray | None = None
+    out: dict = field(default_factory=dict)
+
+
+@dataclass
+class DerivePlan:
+    """What one SPF run's ``derive`` stage reads, by vertex index, as
+    :meth:`LoweredLsdb.build_topology` left it: the prefixes the run's
+    live LSAs offer and the flags of its routers.  It is the run's: made
+    from the LSDB and at the ``now`` of that call."""
+
+    now: float
+    # One row per offered prefix, sorted by vertex, a vertex's offers in
+    # its LSA's link order: the order a walk over the vertices meets them.
+    offer_vertex: np.ndarray  # int64[P]
+    offer_metric: np.ndarray  # int64[P]
+    prefixes: list[IPv4Network]  # [P]
+    # The router index's keys in its order, the vertex of each and the
+    # flags of that vertex's Router-LSA.
+    routers: list[IPv4Address]
+    router_vertex: np.ndarray  # int64[R]
+    router_flags: np.ndarray  # int64[R]
+    _kept: _ReachedFlags = field(default_factory=_ReachedFlags, repr=False)
+
+    def reached_flags(self, dist: np.ndarray) -> dict:
+        """:func:`reachable_router_flags` from the plan.  Callers only
+        read the dict: where the routers, their flags and the reached
+        set are the last call's, it is the last call's object."""
+        kept = self._kept
+        if kept.routers is not self.routers or not np.array_equal(
+            kept.flags, self.router_flags
+        ):
+            table = {
+                f: RouterFlags(f)
+                for f in np.unique(self.router_flags).tolist()
+            }
+            kept.every = dict(zip(
+                self.routers, map(table.get, self.router_flags.tolist())
+            ))
+            kept.routers, kept.flags = self.routers, self.router_flags
+            kept.reached = None
+        reached = dist[self.router_vertex] < INF
+        if kept.reached is None or not np.array_equal(kept.reached, reached):
+            # A copy keeps the keys' hashes and their order; what is
+            # hashed again is the few routers the run did not reach.
+            out = dict(kept.every)
+            routers = self.routers
+            for i in np.flatnonzero(~reached).tolist():
+                del out[routers[i]]
+            kept.reached, kept.out = reached, out
+        return kept.out
+
+
+@dataclass
 class SpfTopology:
     topo: Topology
     atoms: list[NexthopAtom]
     # vertex index maps
     router_index: dict[IPv4Address, int]
     network_index: dict[IPv4Address, int]
+    # Set by a lowering's build_topology; None on a hand-made topology,
+    # which derive_routes and reachable_router_flags serve from the LSDB.
+    plan: DerivePlan | None = None
 
 
 _TOPOLOGY_LSAS = telemetry.counter(
@@ -289,6 +353,17 @@ _TOPOLOGY_LSAS = telemetry.counter(
 # DR interface address (a router-LSA's transit link).
 _OTHER, _ROUTER, _NETWORK = 0, 1, 2
 _P2P, _VLINK, _TRANSIT, _ATTACHED = 0, 1, 2, 3
+
+
+def _offered(addr: IPv4Address, mask: IPv4Address):
+    """The prefix an LSA offers, made once, when the LSA is lowered.  A
+    mask that leaves host bits set (nothing checks one off the wire)
+    names no network: the pair is kept, and ``derive_routes`` raises
+    over it if a run reaches its vertex, as it did before lowerings."""
+    try:
+        return apply_mask(addr, mask)
+    except ValueError:
+        return addr, mask
 
 
 def _spliced(old: np.ndarray, runs, parts) -> np.ndarray:
@@ -343,6 +418,9 @@ class _VertexModel:
     keys: np.ndarray
     vertex_at: np.ndarray
     emit_vertex: np.ndarray  # source vertex per emitted segment
+    # ``router_index`` as a list of its keys and an array of its values
+    index_routers: list[IPv4Address]
+    index_vertex: np.ndarray
 
 
 class LoweredLsdb:
@@ -350,10 +428,14 @@ class LoweredLsdb:
 
     Per LSDB entry, in the LSDB's iteration order: what it is, its
     vertex id as an integer (a router-LSA's advertising router, a
-    network-LSA's link-state id), the two terms of its age, and its
-    segment of link rows ``(kind, neighbour id, metric, link data)``,
-    one per link that can become an edge.  Segments hold ids, not
-    vertex indices, so they outlive a change of the vertex set.
+    network-LSA's link-state id), the two terms of its age, a
+    router-LSA's flags, and its segment of link rows ``(kind, neighbour
+    id, metric, link data)``, one per link that can become an edge.
+    Segments hold ids, not vertex indices, so they outlive a change of
+    the vertex set.  Beside the link rows, per entry, its segment of
+    *offers*: the prefixes a route may be derived from (a router-LSA's
+    stub links, a network-LSA's own network), each with its metric.
+    The prefix objects are made when the LSA is lowered, and kept.
 
     ``Lsdb.install`` builds a new ``LsaEntry`` per install and nothing
     edits one in place, so what changed since the last call is found by
@@ -365,10 +447,15 @@ class LoweredLsdb:
 
     ``router_bodies`` is the live router-LSA bodies in vertex order as
     of the last call (``router_bodies[i]`` is vertex ``n_networks + i``).
+    The :class:`SpfTopology` a call returns carries the run's
+    :class:`DerivePlan`: the offers and the flags by vertex index.
     """
 
     #: the per-entry arrays, in the order ``_lower`` returns them
-    _COLUMNS = ("_kind", "_vid", "_age", "_installed_at", "_n_links")
+    _COLUMNS = (
+        "_kind", "_vid", "_age", "_installed_at", "_n_links", "_n_offers",
+        "_flags",
+    )
 
     def __init__(self) -> None:
         self.entries: list = []
@@ -378,25 +465,39 @@ class LoweredLsdb:
         self._age = np.zeros(0, np.float64)
         self._installed_at = np.zeros(0, np.float64)
         self._n_links = np.zeros(0, np.int64)
+        self._n_offers = np.zeros(0, np.int64)
+        self._flags = np.zeros(0, np.int64)
         self._links = np.zeros((0, 4), np.int64)
         self._link_off = np.zeros(1, np.int64)
+        self._offer_metric = np.zeros(0, np.int64)
+        self._offer_prefix: list[IPv4Network] = []
+        self._offer_off = np.zeros(1, np.int64)
         self._model: _VertexModel | None = None
+        self._reached = _ReachedFlags()
         self.router_bodies: list[LsaRouter] = []
 
     @staticmethod
     def _lower(entries) -> tuple:
         """Lower a run of LSDB entries: the per-entry ``_COLUMNS``, the
-        link rows of all of them, and their bodies."""
+        link rows of all of them, their bodies, and the offers of all
+        of them ``(metrics, prefixes)``."""
         kind, vid, age, installed_at, n_links, links, bodies = (
             [], [], [], [], [], [], []
         )
+        n_offers, flags, offer_metric, offer_prefix = [], [], [], []
         for e in entries:
             lsa = e.lsa
-            k, v, body, n0 = _OTHER, 0, None, len(links)
+            k, v, body, fl = _OTHER, 0, None, 0
+            n0, p0 = len(links), len(offer_prefix)
             if lsa.type == LsaType.ROUTER:
                 k, v, body = _ROUTER, int(lsa.adv_rtr), lsa.body
+                fl = int(body.flags)
                 for link in body.links:
                     lt = link.link_type
+                    if lt == RouterLinkType.STUB_NETWORK:
+                        offer_prefix.append(_offered(link.id, link.data))
+                        offer_metric.append(link.metric)
+                        continue
                     if lt == RouterLinkType.POINT_TO_POINT:
                         lk = _P2P
                     elif lt == RouterLinkType.TRANSIT_NETWORK:
@@ -413,6 +514,8 @@ class LoweredLsdb:
                     )
             elif lsa.type == LsaType.NETWORK:
                 k, v, body = _NETWORK, int(lsa.lsid), lsa.body
+                offer_prefix.append(_offered(lsa.lsid, body.mask))
+                offer_metric.append(0)
                 for rid in body.attached:
                     links.append((_ATTACHED, int(rid), 0, 0))
             kind.append(k)
@@ -420,6 +523,8 @@ class LoweredLsdb:
             age.append(lsa.age)
             installed_at.append(e.installed_at)
             n_links.append(len(links) - n0)
+            n_offers.append(len(offer_prefix) - p0)
+            flags.append(fl)
             bodies.append(body)
         return (
             (
@@ -428,9 +533,12 @@ class LoweredLsdb:
                 np.array(age, np.float64),
                 np.array(installed_at, np.float64),
                 np.array(n_links, np.int64),
+                np.array(n_offers, np.int64),
+                np.array(flags, np.int64),
             ),
             np.array(links, np.int64).reshape(-1, 4),
             bodies,
+            (np.array(offer_metric, np.int64), offer_prefix),
         )
 
     def _refresh(self, lsdb: Lsdb) -> list:
@@ -462,21 +570,27 @@ class LoweredLsdb:
         if not runs:
             return runs
         parts = [self._lower(f) for f in fresh]
-        off = self._link_off
+        off, offer_off = self._link_off, self._offer_off
         self._links = _spliced(
             self._links, [(off[lo], off[hi]) for lo, hi in runs],
-            [links for _cols, links, _bodies in parts],
+            [links for _cols, links, _bodies, _offers in parts],
+        )
+        offer_runs = [(offer_off[lo], offer_off[hi]) for lo, hi in runs]
+        self._offer_metric = _spliced(
+            self._offer_metric, offer_runs, [p[3][0] for p in parts]
         )
         for col, name in enumerate(self._COLUMNS):
             setattr(self, name, _spliced(
                 getattr(self, name), runs, [p[0][col] for p in parts]
             ))
-        for (lo, hi), (_cols, _links, bodies) in zip(
-            reversed(runs), reversed(parts)
+        for (lo, hi), (olo, ohi), (_cols, _links, bodies, offers) in zip(
+            reversed(runs), reversed(offer_runs), reversed(parts)
         ):
             self._bodies[lo:hi] = bodies
+            self._offer_prefix[olo:ohi] = offers[1]
         self.entries = cur
         self._link_off = np.concatenate(([0], np.cumsum(self._n_links)))
+        self._offer_off = np.concatenate(([0], np.cumsum(self._n_offers)))
         return runs
 
     def _vertex_model(self, r_pos, n_pos) -> _VertexModel:
@@ -507,16 +621,40 @@ class LoweredLsdb:
         emit_keys = np.concatenate(
             (2 * r_ids[rtr.emit] + 1, 2 * n_ids[net.emit])
         )
+        router_index = {r: len(networks) + i for i, r in enumerate(routers)}
         m = self._model = _VertexModel(
             rtr, net, routers, networks,
-            router_index={
-                r: len(networks) + i for i, r in enumerate(routers)
-            },
+            router_index=router_index,
             network_index={a: i for i, a in enumerate(networks)},
             keys=keys, vertex_at=vertex_at,
             emit_vertex=vertex_at[lookup_sorted(keys, emit_keys)[0]],
+            index_routers=list(router_index),
+            index_vertex=np.fromiter(
+                router_index.values(), np.int64, len(router_index)
+            ),
         )
         return m
+
+    def _derive_plan(self, m: _VertexModel, r_pos, n_pos, now) -> DerivePlan:
+        """The run's :class:`DerivePlan`: the offers of the entry behind
+        each vertex, networks before routers."""
+        r_body = r_pos[m.rtr.body]
+        behind = np.concatenate((n_pos[m.net.body], r_body))
+        count = self._n_offers[behind]
+        end = np.cumsum(count)
+        rows = np.repeat(self._offer_off[behind] - (end - count), count)
+        rows += np.arange(len(rows))
+        prefix = self._offer_prefix
+        return DerivePlan(
+            now,
+            offer_vertex=np.repeat(np.arange(len(behind)), count),
+            offer_metric=self._offer_metric[rows],
+            prefixes=[prefix[i] for i in rows.tolist()],
+            routers=m.index_routers,
+            router_vertex=m.index_vertex,
+            router_flags=self._flags[r_body[m.index_vertex - len(m.networks)]],
+            _kept=self._reached,
+        )
 
     def build_topology(
         self,
@@ -547,6 +685,7 @@ class LoweredLsdb:
         root = m.router_index.get(router_id)
         if root is None:
             return None  # no self LSA yet (reference: SpfRootNotFound)
+        plan = self._derive_plan(m, r_pos, n_pos, now)
         is_router = np.zeros(n, bool)
         is_router[nn:] = True
 
@@ -679,7 +818,9 @@ class LoweredLsdb:
                 groups.append(partition_of.get(rid))
             apply_partition_hint(topo, groups)
         topo.touch()
-        return SpfTopology(topo, atoms, m.router_index, m.network_index)
+        return SpfTopology(
+            topo, atoms, m.router_index, m.network_index, plan
+        )
 
 
 # ===== OSPFv3: the same kept lowering over RFC 5340's LSA types ========
@@ -767,6 +908,9 @@ class LoweredLsdbV3(LoweredLsdb):
             installed_at.append(e.installed_at)
             n_links.append(len(links) - n0)
             bodies.append(body)
+        # No offers and no flags: v3's routes come from the area's
+        # Intra-Area-Prefix LSAs (``_derive_intra``).
+        none = np.zeros(len(kind), np.int64)
         return (
             (
                 np.array(kind, np.int8),
@@ -774,9 +918,12 @@ class LoweredLsdbV3(LoweredLsdb):
                 np.array(age, np.float64),
                 np.array(installed_at, np.float64),
                 np.array(n_links, np.int64),
+                none,
+                none,
             ),
             np.array(links, np.int64).reshape(-1, 4),
             bodies,
+            (np.zeros(0, np.int64), []),
         )
 
     def _moved(self, was: np.ndarray, before: list, runs: list) -> bool:
@@ -1107,6 +1254,14 @@ class IntraRoute:
     nh_weights: dict | None = None
 
 
+_DERIVE_CALLS = telemetry.counter(
+    "holo_ospf_derive_calls_total",
+    "derive_routes calls by where the offers came from: planned (read by "
+    "vertex from the plan the area's kept lowering made for the run), or "
+    "walked (the LSDB and every vertex, for a topology without a plan)",
+    ("path",),
+)
+
 DERIVE_NEXTHOPS = _DERIVE_NEXTHOPS = telemetry.counter(
     "holo_ospf_derive_nexthops_total",
     "derive_routes' prefix offers by how the offering vertex's next-hop "
@@ -1202,7 +1357,12 @@ def reachable_router_flags(
     """Routers this SPF run reached, each with the flags of its
     Router-LSA as of the run (``RouterFlags(0)`` without a live one):
     operational state counts ABRs and ASBRs from these, not from the
-    live LSDB (reference area.rs:164-182)."""
+    live LSDB (reference area.rs:164-182).
+
+    With the run's plan on ``st`` nothing is read from ``lsdb``: the
+    flags are those of the LSA behind each router's vertex."""
+    if st.plan is not None:
+        return st.plan.reached_flags(res.dist)
     flags = {
         key.adv_rtr: e.lsa.body.flags
         for key, e in lsdb.entries.items()
@@ -1234,8 +1394,15 @@ def derive_routes(
     only) mean DIRECTLY ATTACHED (reference route.rs:96): they render in
     operational state but are never installed to the RIB — the connected
     route owns the FIB entry (see OspfInstance._sync_rib).
+
+    ``st.plan``, where the topology came from a lowering at this
+    ``now``, lists what every live vertex offers: only those vertices
+    are touched and ``lsdb`` is not read.  Without it the LSDB and every
+    vertex are walked; the routes are the same, in the same order.
     """
     routes: dict[IPv4Network, IntraRoute] = {}
+    plan = st.plan if st.plan is not None and st.plan.now == now else None
+    _DERIVE_CALLS.labels(path="walked" if plan is None else "planned").inc()
 
     def offer(prefix, dist, nhs, vertex=-1, weights=None):
         cur = routes.get(prefix)
@@ -1259,17 +1426,54 @@ def derive_routes(
                 vertex=cur.vertex, nh_weights=merged,
             )
 
-    inv_net = {i: a for a, i in st.network_index.items()}
-    inv_rtr = {i: r for r, i in st.router_index.items()}
-    nlsa = {}
-    rlsa = {}
-    for e in lsdb.all():
-        if e.current_age(now) >= 3600:
-            continue
-        if e.lsa.type == LsaType.NETWORK:
-            nlsa[e.lsa.lsid] = e.lsa.body
-        elif e.lsa.type == LsaType.ROUTER:
-            rlsa[e.lsa.adv_rtr] = e.lsa.body
+    def walked():
+        """``(vertex, prefix, cost)`` of every offer of every reachable
+        vertex, from the LSDB."""
+        inv_net = {i: a for a, i in st.network_index.items()}
+        inv_rtr = {i: r for r, i in st.router_index.items()}
+        nlsa = {}
+        rlsa = {}
+        for e in lsdb.all():
+            if e.current_age(now) >= 3600:
+                continue
+            if e.lsa.type == LsaType.NETWORK:
+                nlsa[e.lsa.lsid] = e.lsa.body
+            elif e.lsa.type == LsaType.ROUTER:
+                rlsa[e.lsa.adv_rtr] = e.lsa.body
+        dist = res.dist.tolist()
+        for v in range(st.topo.n_vertices):
+            if dist[v] >= INF:
+                continue
+            net = inv_net.get(v)
+            if net is not None:
+                body = nlsa.get(net)
+                if body is None:
+                    continue
+                yield v, apply_mask(net, body.mask), dist[v]
+            else:
+                body = rlsa.get(inv_rtr[v])
+                if body is None:
+                    continue
+                for link in body.links:
+                    if link.link_type == RouterLinkType.STUB_NETWORK:
+                        yield (
+                            v, apply_mask(link.id, link.data),
+                            dist[v] + link.metric,
+                        )
+
+    def planned():
+        """The same from the plan: its rows at reachable vertices."""
+        d = res.dist[plan.offer_vertex]
+        keep = np.flatnonzero(d < INF)
+        prefixes = plan.prefixes
+        for v, i, cost in zip(
+            plan.offer_vertex[keep].tolist(), keep.tolist(),
+            (d[keep] + plan.offer_metric[keep]).tolist(),
+        ):
+            prefix = prefixes[i]
+            if prefix.__class__ is tuple:
+                prefix = apply_mask(*prefix)  # raises: see _offered
+            yield v, prefix, cost
 
     # Per-vertex UCMP weights ride the multipath planes when the
     # dispatch carried them (max-paths > 1 → multipath kernel).
@@ -1280,45 +1484,27 @@ def derive_routes(
     # bytes, and only when a vertex that offers a prefix asks for it.
     # The shared frozenset is immutable: offer's union and the clamp
     # rebind, they never mutate.
-    dist = res.dist.tolist()
     words = res.nexthop_words
     stride = words.shape[1] * words.itemsize
     rows = words.tobytes()  # C order, whatever the plane's layout
     decoded: dict[bytes, frozenset] = {}
     offers = 0
-    for v in range(st.topo.n_vertices):
-        if dist[v] >= INF:
-            continue
-        net = inv_net.get(v)
-        if net is not None:
-            body = nlsa.get(net)
-            if body is None:
-                continue
-            offered = [(apply_mask(net, body.mask), dist[v])]
-        else:
-            body = rlsa.get(inv_rtr[v])
-            if body is None:
-                continue
-            offered = [
-                (apply_mask(link.id, link.data), dist[v] + link.metric)
-                for link in body.links
-                if link.link_type == RouterLinkType.STUB_NETWORK
-            ]
-        if not offered:
-            continue
-        row = rows[v * stride:(v + 1) * stride]
-        nhs = decoded.get(row)
-        if nhs is None:
-            nhs = decoded[row] = _atoms_of(words[v], st.atoms)
-        # A vertex's weights are its own nhw row's: not shared by mask.
-        weights = (
-            _atom_weights_of(words[v], nhw[v], st.atoms)
-            if nhw is not None
-            else None
-        )
-        for prefix, cost in offered:
-            offer(prefix, cost, nhs, vertex=v, weights=weights)
-        offers += len(offered)
+    at = -1
+    for v, prefix, cost in walked() if plan is None else planned():
+        if v != at:  # a vertex's offers come together
+            at = v
+            row = rows[v * stride:(v + 1) * stride]
+            nhs = decoded.get(row)
+            if nhs is None:
+                nhs = decoded[row] = _atoms_of(words[v], st.atoms)
+            # A vertex's weights are its own nhw row's: not shared by mask.
+            weights = (
+                _atom_weights_of(words[v], nhw[v], st.atoms)
+                if nhw is not None
+                else None
+            )
+        offer(prefix, cost, nhs, vertex=v, weights=weights)
+        offers += 1
     _DERIVE_NEXTHOPS.labels(path="decoded").inc(len(decoded))
     _DERIVE_NEXTHOPS.labels(path="reused").inc(offers - len(decoded))
     clamp_multipath(routes, max_paths)

@@ -8,12 +8,21 @@ old bodies are kept here as the oracle: the routes must come out with
 the same keys in the same insertion order (the FIB digest and the RIB's
 publish order hang on it) and equal in every field.  No case reads a
 clock.
+
+Since ISSUE 32 both functions read a *plan* where the topology carries
+one (``SpfTopology.plan``, which the area's kept ``LoweredLsdb`` makes
+for every run): the offers and the router flags by vertex index, no walk
+over the LSDB or the vertices.  Their bodies as they stood before it are
+the second oracle (``walked_*``), and a topology without a plan still
+runs them in the program.
 """
 
+import dataclasses
 import json
 import os
 import subprocess
 import sys
+from collections import Counter
 from ipaddress import IPv4Address, IPv4Network
 from pathlib import Path
 from types import SimpleNamespace
@@ -38,16 +47,21 @@ from holo_tpu.protocols.ospf.packet import (
 )
 from holo_tpu.protocols.ospf.spf_run import (
     IntraRoute,
+    LoweredLsdb,
     NexthopAtom,
     RouteNexthop,
     SpfTopology,
+    _atom_weights_of,
+    _atoms_of,
     atom_bits,
+    build_topology,
     clamp_multipath,
     derive_routes,
     reachable_router_flags,
 )
-from holo_tpu.spf.backend import SpfResult
+from holo_tpu.spf.backend import ScalarSpfBackend, SpfResult
 from holo_tpu.utils.ip import apply_mask
+from tests import test_build_topology as tbt
 
 REPO = Path(__file__).resolve().parents[1]
 AREA = IPv4Address("0.0.0.0")
@@ -485,8 +499,8 @@ def test_reachable_router_flags_equals_the_comprehension(case):
 # -- the counter, and the metric that reads it
 
 
-def _moved(before, after, path):
-    key = f"{FAMILY}{{path={path}}}"
+def _moved(before, after, path, family=FAMILY):
+    key = f"{family}{{path={path}}}"
     return after.get(key, 0) - before.get(key, 0)
 
 
@@ -574,3 +588,653 @@ def test_traced_storm_rehearsal_reads_the_decode_share(cell):
     report = json.loads(proc.stderr.strip().splitlines()[-1])
     assert "storm_derive_decode_share" in report["counts"]["metrics_read"]
     assert report["metrics"] == {} and report["failed"] == 0
+
+
+# == ISSUE 32: the plan ===================================================
+
+# -- the oracle: both functions as they stood before ISSUE 32, verbatim
+# -- but for the names and the counter
+
+
+def walked_router_flags(st, res, lsdb):
+    flags = {
+        key.adv_rtr: e.lsa.body.flags
+        for key, e in lsdb.entries.items()
+        if key.type == LsaType.ROUTER and not e.lsa.is_maxage
+    }
+    dist = res.dist.tolist()
+    no_flags = RouterFlags(0)
+    return {
+        rid: flags.get(rid, no_flags)
+        for rid, v in st.router_index.items()
+        if dist[v] < INF
+    }
+
+
+def walked_derive_routes(st, res, lsdb, now, area_id, max_paths=None):
+    routes = {}
+
+    def offer(prefix, dist, nhs, vertex=-1, weights=None):
+        cur = routes.get(prefix)
+        if cur is None or dist < cur.dist:
+            routes[prefix] = IntraRoute(
+                prefix, dist, nhs, area_id, vertex=vertex,
+                nh_weights=dict(weights) if weights else None,
+            )
+        elif dist == cur.dist:
+            merged = None
+            if cur.nh_weights or weights:
+                merged = dict(cur.nh_weights or {})
+                for nh, w in (weights or {}).items():
+                    merged[nh] = merged.get(nh, 0) + w
+            routes[prefix] = IntraRoute(
+                prefix, dist, cur.nexthops | nhs, area_id,
+                vertex=cur.vertex, nh_weights=merged,
+            )
+
+    inv_net = {i: a for a, i in st.network_index.items()}
+    inv_rtr = {i: r for r, i in st.router_index.items()}
+    nlsa = {}
+    rlsa = {}
+    for e in lsdb.all():
+        if e.current_age(now) >= 3600:
+            continue
+        if e.lsa.type == LsaType.NETWORK:
+            nlsa[e.lsa.lsid] = e.lsa.body
+        elif e.lsa.type == LsaType.ROUTER:
+            rlsa[e.lsa.adv_rtr] = e.lsa.body
+
+    nhw = getattr(res, "nh_weights", None)
+    dist = res.dist.tolist()
+    words = res.nexthop_words
+    stride = words.shape[1] * words.itemsize
+    rows = words.tobytes()
+    decoded = {}
+    for v in range(st.topo.n_vertices):
+        if dist[v] >= INF:
+            continue
+        net = inv_net.get(v)
+        if net is not None:
+            body = nlsa.get(net)
+            if body is None:
+                continue
+            offered = [(apply_mask(net, body.mask), dist[v])]
+        else:
+            body = rlsa.get(inv_rtr[v])
+            if body is None:
+                continue
+            offered = [
+                (apply_mask(link.id, link.data), dist[v] + link.metric)
+                for link in body.links
+                if link.link_type == RouterLinkType.STUB_NETWORK
+            ]
+        if not offered:
+            continue
+        row = rows[v * stride:(v + 1) * stride]
+        nhs = decoded.get(row)
+        if nhs is None:
+            nhs = decoded[row] = _atoms_of(words[v], st.atoms)
+        weights = (
+            _atom_weights_of(words[v], nhw[v], st.atoms)
+            if nhw is not None
+            else None
+        )
+        for prefix, cost in offered:
+            offer(prefix, cost, nhs, vertex=v, weights=weights)
+    clamp_multipath(routes, max_paths)
+    return routes
+
+
+def _same_routes(got: dict, want: dict) -> None:
+    """Route for route and in the dict's order: prefix, dist, nexthops,
+    area, vertex, nh_weights (dataclass equality), costs Python ints."""
+    assert list(got) == list(want)
+    for prefix, route in want.items():
+        assert got[prefix] == route, prefix
+        assert type(got[prefix].dist) is int
+        assert got[prefix].vertex == route.vertex
+
+
+class Checked:
+    """Both functions, each call held to its oracle: what the instance
+    is given to call in the storm cases, and what the area cases call."""
+
+    def __init__(self):
+        self.paths = Counter()
+        self.routes = self.flag_calls = self.unreached = 0
+        self.weighted = self.merged = 0
+
+    def flags(self, st, res, lsdb):
+        got = reachable_router_flags(st, res, lsdb)
+        want = walked_router_flags(st, res, lsdb)
+        assert list(got.items()) == list(want.items())
+        self.flag_calls += 1
+        self.unreached += len(st.router_index) - len(want)
+        return got
+
+    def derive(self, st, res, lsdb, now, area_id, max_paths=None):
+        before = telemetry.snapshot(CALLS)
+        got = derive_routes(st, res, lsdb, now, area_id, max_paths=max_paths)
+        after = telemetry.snapshot(CALLS)
+        for path in ("planned", "walked"):
+            self.paths[path] += int(_moved(before, after, path, CALLS))
+        want = walked_derive_routes(st, res, lsdb, now, area_id, max_paths)
+        _same_routes(got, want)
+        self.routes += len(want)
+        self.weighted += sum(r.nh_weights is not None for r in want.values())
+        self.merged += sum(len(r.nexthops) > 1 for r in want.values())
+        return got
+
+
+CALLS = "holo_ospf_derive_calls_total"
+
+
+# -- the storms: every SPF run of the instance, checked as it happens
+
+
+def _storm_own(max_paths=None):
+    from holo_tpu.spf.synth_storm import run_convergence_storm
+
+    report, _digest, net = run_convergence_storm(
+        n_routers=300, events=200, seed=32, max_paths=max_paths,
+        spf_backend=ScalarSpfBackend(),
+    )
+    assert report["outcomes"]["converged"] > 100
+    return net
+
+
+def _storm_heavy_degree():
+    """200 seeded events on the benchmark's heavy-degree rehearsal
+    network: flaps, shared-risk cuts, lost routers whose LSA stays, and
+    the loss of a hub at the port cap."""
+    from benchmark.popnet import PopNet
+    from holo_tpu.telemetry import convergence
+
+    config = json.loads(
+        (REPO / "benchmark/configs/tiny-isp.json").read_text()
+    )
+    net = PopNet(
+        config["lsdb"], ScalarSpfBackend(), config["spf_delay"], 5.0
+    )
+    convergence.configure(4096, clock=net.loop.clock.now)
+    try:
+        net.loop.advance(30.0)
+        rng = np.random.default_rng(32)
+        degree = net.graph.degrees()
+        hub = max(net.losable["core"], key=lambda i: degree[i])
+        assert degree[hub] == config["lsdb"]["port_cap"]
+        lost_routers = 0
+        for ev in range(200):
+            roll, lost = rng.random(), bool(rng.random() < 0.1)
+            if ev in (40, 41, 120, 121):
+                net.node(hub, lost=False)
+            elif roll < 0.6:
+                net.flap(
+                    net.flappable[int(rng.integers(len(net.flappable)))],
+                    lost=lost,
+                )
+            elif roll < 0.7:
+                net.srlg(int(rng.integers(len(net.graph.srlgs))), lost=lost)
+            elif roll < 0.85:
+                pool = net.losable["access"] + net.losable["core"]
+                down = [r for r in net.node_down if r != hub]
+                if len(down) >= 3:
+                    router = down[0]
+                else:
+                    router = pool[int(rng.integers(len(pool)))]
+                if router != hub:
+                    lost_routers += router not in net.node_down
+                    net.node(router, lost=lost)
+            else:
+                net.ifconfig_metric()
+            net.loop.advance(float(rng.uniform(0.05, 1.5)))
+        net.loop.advance(60.0)
+        assert lost_routers > 5
+    finally:
+        convergence.configure(0)
+    return net
+
+
+STORMS = {
+    "storm-network-200-events": _storm_own,
+    "storm-network-max-paths-2": lambda: _storm_own(max_paths=2),
+    "heavy-degree-network-200-events": _storm_heavy_degree,
+}
+
+
+@pytest.mark.parametrize("case", sorted(STORMS))
+def test_planned_equals_walked_in_every_run_of_a_storm(case, monkeypatch):
+    from holo_tpu.protocols.ospf import instance
+
+    check = Checked()
+    monkeypatch.setattr(instance, "derive_routes", check.derive)
+    monkeypatch.setattr(instance, "reachable_router_flags", check.flags)
+    net = STORMS[case]()
+    runs = net.inst.spf_run_count
+    assert runs >= 25
+    # every run was served from the plan of the area's kept lowering,
+    # and the storm is not a trivial one
+    assert +check.paths == {"planned": check.flag_calls}
+    assert check.flag_calls >= runs
+    assert check.routes > 10 * runs
+    assert check.unreached > 0
+    if case == "storm-network-max-paths-2":
+        assert check.weighted > 0 and check.merged > 0
+
+
+# -- an area that events are driven through, with offers of every kind
+
+
+class OfferingArea(tbt.Area):
+    """``test_build_topology``'s area (ring, chords, parallel and
+    unnumbered links, a virtual link, a transit network the root is on
+    and one it is not, a stub behind every third router, the root among
+    them) with flags on its routers, and stubs added by the cases."""
+
+    flags_now: dict = {}  # a case's own: router -> flags
+
+    def install(self, i: int, age: int = 0) -> None:
+        self.seq += 1
+        flags = RouterFlags(0)
+        if i % 3 == 1:
+            flags |= RouterFlags.B
+        if i % 4 == 2:
+            flags |= RouterFlags.E
+        flags = self.flags_now.get(i, flags)
+        self.lsdb.install(Lsa(
+            age, Options.E, LsaType.ROUTER, tbt._rid(i), tbt._rid(i),
+            self.seq, LsaRouter(flags, list(self.links[i])),
+        ), self.now)
+
+    def stub(self, i: int, prefix: str, metric: int) -> None:
+        self.links[i].append(_stub(prefix, metric))
+        self.install(i)
+
+
+class Runs:
+    """One area, its kept lowering, and a check after every step: the
+    kept lowering's plan, a fresh lowering's plan and no plan at all
+    give the oracle's routes and flags."""
+
+    def __init__(self, seed: int, max_paths=None, multipath_k: int = 1):
+        self.area = OfferingArea(seed)
+        self.kept = LoweredLsdb()
+        self.check = Checked()
+        self.max_paths, self.k = max_paths, multipath_k
+        self.last = None
+
+    def run(self):
+        area, args = self.area, self.area.args()
+        st = self.kept.build_topology(area.lsdb, **args)
+        fresh = build_topology(area.lsdb, **args)
+        if st is None:
+            assert fresh is None
+            self.last = None
+            return None
+        assert st.plan is not None and fresh.plan is not None
+        res = ScalarSpfBackend().compute(st.topo, multipath_k=self.k)
+        bare = dataclasses.replace(st, plan=None)
+        for topo in (st, fresh, bare):
+            self.flags = self.check.flags(topo, res, area.lsdb)
+            routes = self.check.derive(
+                topo, res, area.lsdb, area.now, AREA, self.max_paths
+            )
+        self.last = (st, res, routes)
+        return routes
+
+
+def _net(prefix):
+    return IPv4Network(prefix)
+
+
+def _case_transit_with_a_dr(r: Runs):
+    # network 0 has the root on it, network 1 does not; both offer their
+    # own prefix at the network vertex's distance
+    routes = r.run()
+    for j in (0, 1):
+        net = apply_mask(tbt._dr(j), IPv4Address("255.255.255.0"))
+        assert routes[net].vertex == r.last[0].network_index[tbt._dr(j)]
+    assert routes[apply_mask(tbt._dr(0), IPv4Address("255.255.255.0"))].dist == 3
+    tbt.flush_network(r.area)  # one of them leaves
+    assert len(r.run()) == len(routes) - 1
+    r.area.install_net(0)
+    r.area.install_net(1)
+    assert list(r.run()) == list(routes)
+
+
+def _case_one_prefix_equal_cost(r: Runs):
+    r.run()
+    st, res, _ = r.last
+    dist = res.dist
+    a, b = 4, 9
+    va, vb = st.router_index[tbt._rid(a)], st.router_index[tbt._rid(b)]
+    top = int(max(dist[va], dist[vb])) + 5
+    r.area.stub(a, "10.77.0.0/24", top - int(dist[va]))
+    r.area.stub(b, "10.77.0.0/24", top - int(dist[vb]))
+    route = r.run()[_net("10.77.0.0/24")]
+    st, res, _ = r.last
+    assert route.dist == top and route.vertex == min(va, vb)
+    assert route.nexthops == _atoms_of(
+        res.nexthop_words[va], st.atoms
+    ) | _atoms_of(res.nexthop_words[vb], st.atoms)
+
+
+def _case_one_prefix_unequal_cost(r: Runs):
+    r.run()
+    st, res, _ = r.last
+    a, b = 4, 9
+    va, vb = st.router_index[tbt._rid(a)], st.router_index[tbt._rid(b)]
+    r.area.stub(a, "10.78.0.0/24", 1)
+    r.area.stub(b, "10.78.0.0/24", 1 + abs(int(res.dist[va] - res.dist[vb])) + 3)
+    route = r.run()[_net("10.78.0.0/24")]
+    assert (route.dist, route.vertex) == (int(res.dist[va]) + 1, va)
+    # the dearer offer first in vertex order, then the cheaper one
+    r.area.stub(11, "10.79.0.0/24", 40)
+    r.area.stub(12, "10.79.0.0/24", 1)
+    r.run()
+    st, res, routes = r.last
+    v = st.router_index[tbt._rid(12)]
+    assert routes[_net("10.79.0.0/24")].vertex == v
+
+
+def _case_root_with_its_own_stubs(r: Runs):
+    r.area.stub(tbt.ROOT, "10.80.0.0/24", 7)
+    routes = r.run()
+    own = routes[_net("10.80.0.0/24")]
+    assert (own.dist, own.nexthops) == (7, frozenset())
+    assert own.vertex == r.last[0].topo.root
+    assert routes[_net("10.1.0.0/24")].nexthops == frozenset()  # the area's
+
+
+def _case_unreachable_vertices(r: Runs):
+    whole = r.run()
+    # router 9 and whatever hangs behind it alone lose every link back
+    for i in range(1, r.area.n):
+        r.area.links[i] = [
+            l for l in r.area.links[i]
+            if not (l.link_type == RouterLinkType.POINT_TO_POINT
+                    and l.id == tbt._rid(9))
+        ]
+        r.area.install(i)
+    seen = r.check.unreached
+    routes = r.run()
+    assert r.check.unreached > seen
+    assert _net("10.1.9.0/24") in whole and _net("10.1.9.0/24") not in routes
+
+
+def _case_age_3599_and_3600_at_now(r: Runs):
+    a = r.area
+    a.links[9].append(_stub("10.81.0.0/24", 1))
+    a.install(9, age=3500)
+    a.install_net(1, age=3500)
+    net1 = apply_mask(tbt._dr(1), IPv4Address("255.255.255.0"))
+    a.now += 99.0  # age 3599
+    routes = r.run()
+    assert _net("10.81.0.0/24") in routes and net1 in routes
+    a.now += 0.999  # 3599.999: current_age is still 3599
+    assert _net("10.81.0.0/24") in r.run()
+    a.now += 0.001  # 3600 on the second, and nothing was installed
+    routes = r.run()
+    assert _net("10.81.0.0/24") not in routes and net1 not in routes
+    assert tbt._rid(9) not in r.last[0].router_index
+    # a topology lowered at another second is not this second's plan
+    st, res, _ = r.last
+    before = telemetry.snapshot(CALLS)
+    got = derive_routes(st, res, a.lsdb, a.now - 50.0, AREA)
+    after = telemetry.snapshot(CALLS)
+    assert _moved(before, after, "walked", CALLS) == 1
+    _same_routes(got, walked_derive_routes(st, res, a.lsdb, a.now - 50.0, AREA))
+
+
+def _case_maxage_lsa_still_in_the_lsdb(r: Runs):
+    a = r.area
+    routes = r.run()
+    assert _net("10.1.6.0/24") in routes
+    a.flush(a.router_key(6))  # the MaxAge copy stays in the LSDB
+    assert a.lsdb.get(a.router_key(6)).lsa.is_maxage
+    routes = r.run()
+    assert _net("10.1.6.0/24") not in routes
+    a.install(6)
+    assert _net("10.1.6.0/24") in r.run()
+
+
+def _case_flags_change_between_runs(r: Runs):
+    a = r.area
+    r.run()
+    assert r.flags[tbt._rid(6)] == RouterFlags.E
+    assert r.flags[tbt._rid(4)] == RouterFlags.B
+    # the same routers, the same reached set: only a body's flags moved
+    a.flags_now = {6: RouterFlags.B | RouterFlags.E, 4: RouterFlags(0)}
+    a.install(6)
+    r.run()
+    assert r.flags[tbt._rid(6)] == RouterFlags.B | RouterFlags.E
+    assert r.flags[tbt._rid(4)] == RouterFlags.B  # not installed yet
+    a.install(4)
+    r.run()
+    assert r.flags[tbt._rid(4)] == RouterFlags(0)
+    first = r.kept.build_topology(a.lsdb, **a.args())
+    again = r.kept.build_topology(a.lsdb, **a.args())
+    res = r.last[1]
+    # nothing moved: the dict is the last run's object
+    assert reachable_router_flags(again, res, a.lsdb) is (
+        reachable_router_flags(first, res, a.lsdb)
+    )
+
+
+def _case_mask_no_network_has(r: Runs):
+    """Nothing checks a mask off the wire.  One that leaves host bits
+    set raises in ``derive_routes`` when a run reaches its vertex, with
+    a plan as without, and costs nothing while no run does."""
+    a = r.area
+    bad = RouterLink(
+        RouterLinkType.STUB_NETWORK, IPv4Address("10.84.3.7"),
+        IPv4Address("255.0.255.0"), 1,
+    )
+    a.links[9].append(bad)
+    a.install(9)
+    st = r.kept.build_topology(a.lsdb, **a.args())  # lowers it: no error
+    res = ScalarSpfBackend().compute(st.topo)
+    for topo in (st, dataclasses.replace(st, plan=None)):
+        with pytest.raises(ValueError, match="host bits"):
+            derive_routes(topo, res, a.lsdb, a.now, AREA)
+    with pytest.raises(ValueError, match="host bits"):
+        walked_derive_routes(st, res, a.lsdb, a.now, AREA)
+    # out of reach, it is not looked at
+    a.links[9] = [bad]
+    a.install(9)
+    routes = r.run()
+    assert tbt._rid(9) not in r.flags and routes
+
+
+def _case_vertex_set_grows_and_shrinks(r: Runs):
+    a = r.area
+    sizes = [len(r.run())]
+    for _ in range(3):
+        tbt.new_router(a)
+        a.stub(a.n - 1, f"10.82.{a.n}.0/24", 2)
+        sizes.append(len(r.run()))
+    assert sizes == [sizes[0] + k for k in range(4)]
+    for i in (a.n - 1, a.n - 2):
+        a.lsdb.remove(a.router_key(i))
+        sizes.append(len(r.run()))
+    a.flush(a.router_key(a.n - 3))
+    sizes.append(len(r.run()))
+    assert sizes[-3:] == [sizes[0] + 2, sizes[0] + 1, sizes[0]]
+    a.lsdb.entries.clear()
+    assert r.run() is None
+    for i in range(a.n - 3):
+        a.install(i)
+    assert len(r.run()) >= 5
+
+
+def _case_chain_of_50_runs(r: Runs):
+    """Fifty runs on one lowering, a seeded event before each; every
+    run is also held to a fresh lowering (``Runs.run``)."""
+    events = (*tbt.BACKGROUND, tbt.new_router)
+    derived = 0
+    for _ in range(50):
+        events[int(r.area.rng.integers(len(events)))](r.area)
+        derived += r.run() is not None
+    assert derived >= 45 and r.kept.entries
+
+
+def _case_max_paths(r: Runs):
+    a = r.area
+    # equal costs all round the ring and its chords, and a stub behind
+    # every router: real ECMP sets (the parallel links to router 1 at
+    # the least)
+    for i in range(a.n):
+        a.links[i] = [
+            RouterLink(l.link_type, l.id, l.data, 1)
+            if l.link_type == RouterLinkType.POINT_TO_POINT else l
+            for l in a.links[i]
+        ] + [_stub(f"10.83.{i}.0/24", 1)]
+        a.install(i)
+    routes = r.run()
+    assert r.check.weighted > 0
+    widest = max(len(x.nexthops) for x in routes.values())
+    assert widest <= (r.max_paths or 99)
+    if r.max_paths == 1:
+        assert widest == 1
+    else:
+        assert widest > 1
+    for route in routes.values():
+        if route.nh_weights is not None:
+            assert set(route.nh_weights) == set(route.nexthops)
+
+
+AREA_CASES = {
+    "transit-networks-with-a-dr": (_case_transit_with_a_dr, {}),
+    "one-prefix-from-two-routers-at-equal-cost": (
+        _case_one_prefix_equal_cost, {}),
+    "one-prefix-from-two-routers-at-unequal-cost": (
+        _case_one_prefix_unequal_cost, {}),
+    "root-with-its-own-stubs": (_case_root_with_its_own_stubs, {}),
+    "unreachable-vertices": (_case_unreachable_vertices, {}),
+    "lsa-at-age-3599-and-3600-at-now": (_case_age_3599_and_3600_at_now, {}),
+    "maxage-lsa-still-in-the-lsdb": (_case_maxage_lsa_still_in_the_lsdb, {}),
+    "max-paths-1-with-multipath-weights": (
+        _case_max_paths, dict(max_paths=1, multipath_k=4)),
+    "max-paths-4-with-multipath-weights": (
+        _case_max_paths, dict(max_paths=4, multipath_k=4)),
+    "vertex-set-grows-and-shrinks": (_case_vertex_set_grows_and_shrinks, {}),
+    "mask-that-no-network-has": (_case_mask_no_network_has, {}),
+    "router-flags-change-between-runs": (
+        _case_flags_change_between_runs, {}),
+    "chain-of-50-runs-on-one-lowering": (_case_chain_of_50_runs, {}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(AREA_CASES))
+def test_planned_equals_walked_on_an_area(case):
+    steps, options = AREA_CASES[case]
+    for seed in (1, 2, 3):
+        runs = Runs(seed, **options)
+        steps(runs)
+        # two of three topologies of every run carried a plan
+        paths = runs.check.paths
+        assert paths["planned"] == 2 * paths["walked"] > 0
+
+
+# -- what the planned derive does not do: counting stand-ins, no timing
+
+
+def _ring(n: int, stubs_at: dict):
+    """A ring of ``n`` routers, router 0 the root; ``stubs_at``: router
+    -> prefix.  Returns the area (an ``OfferingArea`` emptied and built
+    again as a ring) ready for ``build_topology``."""
+    area = OfferingArea.__new__(OfferingArea)
+    area.rng = np.random.default_rng(n)
+    area.lsdb, area.now, area.seq, area.n = Lsdb(), 1000.0, 0, n
+    area.links = {i: [] for i in range(n)}
+    area.nets = {}
+    for i in range(n):
+        area.connect(i, (i + 1) % n)
+    for i, prefix in stubs_at.items():
+        area.links[i].append(_stub(prefix, 1))
+    for i in range(n):
+        area.install(i)
+    return area
+
+
+class _Calls:
+    """Counts calls of ``IPv4Address.__hash__`` and ``Lsdb.all`` while
+    ``on``; both still do what they did."""
+
+    def __init__(self, monkeypatch):
+        self.on = False
+        self.hashes = self.alls = 0
+        real_hash, real_all = IPv4Address.__hash__, Lsdb.all
+
+        def counting_hash(addr):
+            self.hashes += self.on
+            return real_hash(addr)
+
+        def counting_all(lsdb):
+            self.alls += self.on
+            return real_all(lsdb)
+
+        monkeypatch.setattr(IPv4Address, "__hash__", counting_hash)
+        monkeypatch.setattr(Lsdb, "all", counting_all)
+
+
+def _derive_stage(area, kept, calls: _Calls, plan: bool = True):
+    """One SPF run's ``derive`` stage as the instance runs it, counted."""
+    st = kept.build_topology(area.lsdb, **area.args())
+    if not plan:
+        st = dataclasses.replace(st, plan=None)
+    res = ScalarSpfBackend().compute(st.topo)
+    calls.hashes = calls.alls = 0
+    calls.on = True
+    try:
+        flags = reachable_router_flags(st, res, area.lsdb)
+        routes = derive_routes(st, res, area.lsdb, area.now, AREA)
+    finally:
+        calls.on = False
+    return flags, routes, calls.hashes, calls.alls
+
+
+def test_a_run_on_a_kept_lowering_walks_no_lsdb_and_hashes_no_router_id(
+    monkeypatch,
+):
+    calls = _Calls(monkeypatch)
+    counted = {}
+    for n in (40, 400):
+        # the same eight prefixes, behind the root's eight nearest
+        stubs = {
+            i % n: f"10.90.{k}.0/24"
+            for k, i in enumerate((1, 2, 3, 4, -1, -2, -3, -4))
+        }
+        area, kept = _ring(n, stubs), LoweredLsdb()
+        _derive_stage(area, kept, calls)  # the area's first run
+        # a flap far from the root, and the run after it
+        far = n // 2
+        for i in (far, far + 1):
+            area.links[i] = [
+                l for l in area.links[i]
+                if l.id not in (tbt._rid(far), tbt._rid(far + 1))
+            ]
+            area.install(i)
+        before = telemetry.snapshot(CALLS)
+        flags, routes, hashes, alls = _derive_stage(area, kept, calls)
+        after = telemetry.snapshot(CALLS)
+        assert _moved(before, after, "planned", CALLS) == 1
+        assert _moved(before, after, "walked", CALLS) == 0
+        assert alls == 0
+        assert len(flags) == n and len(routes) == 8
+        counted[n] = hashes
+        # ... and the same run with no plan walks the LSDB and hashes
+        # by the router
+        before = after
+        w_flags, w_routes, w_hashes, w_alls = _derive_stage(
+            area, kept, calls, plan=False
+        )
+        after = telemetry.snapshot(CALLS)
+        assert _moved(before, after, "walked", CALLS) == 1
+        assert _moved(before, after, "planned", CALLS) == 0
+        assert w_alls == 1 and w_hashes > 4 * n
+        assert list(w_flags.items()) == list(flags.items())
+        _same_routes(routes, w_routes)
+    # the LSDB grew tenfold with the offered prefixes held: the hashes
+    # left (the next hops of the two rows decoded) did not
+    assert counted[400] == counted[40] <= 8

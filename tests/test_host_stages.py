@@ -303,6 +303,36 @@ def test_second_run_lowers_the_flapped_lsas_and_lowering_goes_with_base():
     assert area.area_id not in inst._spf_lowerings
 
 
+def test_every_run_of_the_instance_derives_from_the_run_plan():
+    """The ``derive`` stage reads the plan the area's kept lowering made
+    for the run (ISSUE 32): every ``derive_routes`` call of an instance
+    counts ``planned``; the base the instance keeps carries that run's
+    plan, whose offers are the routes' prefixes."""
+    def calls() -> dict:
+        snap = telemetry.snapshot("holo_ospf_derive_calls_total")
+        return {
+            path: sum(v for k, v in snap.items() if f"path={path}" in k)
+            for path in ("planned", "walked")
+        }
+
+    before = calls()
+    net = _storm_net()
+    inst, area = net.inst, net.area
+    for k in range(3):
+        net.flap(net.flappable[k], lost=False)
+        net.loop.advance(30.0)
+    after = calls()
+    assert after["walked"] == before["walked"]
+    assert after["planned"] - before["planned"] == inst.spf_run_count > 3
+    plan = inst._spf_delta_bases[area.area_id].plan
+    assert plan.now <= net.loop.clock.now()
+    offered = set(plan.prefixes)
+    intra = {p for p, r in inst.routes.items() if r.rtype == "intra"}
+    assert intra and intra <= offered
+    reached = inst._spf_lowerings[area.area_id]._reached.reached
+    assert len(inst._area_reachable_routers[area.area_id]) == reached.sum()
+
+
 def test_waterfall_keeps_its_phases_and_telescopes_with_stages_armed(recorder):
     """The ledger folds only marshal / delta / device / readback /
     solve: the host stages leave every cut where it was."""
