@@ -70,8 +70,7 @@ class Driver(storm.Driver):
             params["rxmt_delay_s"], max_paths=config.get("max_paths"),
         )
         lay = self.net.layout
-        streams = np.random.default_rng(self.seed).spawn(6)
-        self._mix, self._loss, self._gap, keep, self._pick, self._hot = streams
+        keep = self._order_blocks()
         self._edges = np.cumsum([params["mix"][k] for k in KINDS[:-1]])
         rank = np.arange(1, params["hot_set"] + 1, dtype=float)
         self._zipf = rank ** -params["zipf_s"]
@@ -91,7 +90,6 @@ class Driver(storm.Driver):
                 if abr not in lay.remote_abrs and a == BACKBONE
             }),
         }
-        self.injected = Counter()
         # A reservoir per area, so that the sample spans the areas
         # whatever their shares of the dispatches are.
         per_area = -(-int(params["parity_samples"]) // 3)
@@ -111,7 +109,7 @@ class Driver(storm.Driver):
             event()
             self.net.loop.advance(SCRIPT_GAP_S)
         for _ in range(int(params["warmup_events"]) - len(scripted)):
-            self._inject()
+            self._event()
             self.net.loop.advance(self._next_gap())
         self._settle()
         self.warmup = Counter(self.injected)
@@ -206,10 +204,9 @@ class Driver(storm.Driver):
 
     def _link(self, hall: int) -> tuple[int, int]:
         params, links = self.params, self.net.flappable[hall]
-        epoch = self.injected.total() // params["hot_epoch_events"]
         held = self._hot_links.get(hall)
-        if held is None or held[0] != epoch:
-            held = self._hot_links[hall] = (epoch, self._hot.choice(
+        if held is None or held[0] != self._block:  # lives one block
+            held = self._hot_links[hall] = (self._block, self._hot.choice(
                 len(links), size=min(params["hot_set"], len(links)),
                 replace=False,
             ))

@@ -62,15 +62,13 @@ class Driver(storm.Driver):
             self.config["lsdb"], self.backend, self.config["spf_delay"],
             params["rxmt_delay_s"],
         )
-        streams = np.random.default_rng(self.seed).spawn(6)
-        self._mix, self._loss, self._gap, keep, self._pick, self._hot = streams
+        keep = self._order_blocks()
         self._edges = np.cumsum([params["mix"][k] for k in KINDS[:-1]])
         rank = np.arange(1, params["hot_set"] + 1, dtype=float)
         self._zipf = rank ** -params["zipf_s"]
         self._zipf /= self._zipf.sum()
         self._hot_links, self._hot_epoch = None, -1
         self._bfd_down = self._carrier_down = False
-        self.injected = Counter()
         self.kept = parity.Reservoir(int(params["parity_samples"]), keep)
         self._sampling = False
         self._last_topo = None
@@ -86,7 +84,7 @@ class Driver(storm.Driver):
             event()
             self.net.loop.advance(SCRIPT_GAP_S)
         for _ in range(int(params["warmup_events"]) - len(scripted)):
-            self._inject()
+            self._event()
             self.net.loop.advance(self._next_gap())
         self._settle()
         self.warmup = Counter(self.injected)
@@ -146,9 +144,8 @@ class Driver(storm.Driver):
 
     def _link(self) -> tuple[int, int]:
         params, links = self.params, self.net.flappable
-        epoch = self.injected.total() // params["hot_epoch_events"]
-        if epoch != self._hot_epoch:
-            self._hot_epoch = epoch
+        if self._block != self._hot_epoch:  # a hot set lives one block
+            self._hot_epoch = self._block
             self._hot_links = self._hot.choice(
                 len(links), size=params["hot_set"], replace=False
             )

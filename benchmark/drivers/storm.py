@@ -8,10 +8,24 @@ system adds to its own timers.  The walls are the benchmark's own
 program's critical-path ledger; the ledger's exact per-event records
 give the phases.
 
+Where a cell names a ``pool``, every seed injects the same traffic in
+another order.  The traffic is a pool of ``pool`` blocks of injected
+events, a block as long as the cell's ``hot_epoch_events``
+(``BLOCK_EVENTS`` where it names none).  A block's generators (kinds,
+targets, losses, gaps, hot sets) are seeded from ``TRAFFIC_SEED`` and
+the block's number in the pool, never from ``--seed``, which only
+orders the pool (a run goes through it in that order, over and over)
+and samples the dispatches the parity check keeps.  Drawn whole from
+``--seed``, a window's median followed its seed's hot links and mix by
+up to 15% where one seed repeats within 1.5% (PERF.md, section 6).  A
+cell with no ``pool`` still draws all its traffic from ``--seed``: one
+whose median follows the order of its blocks as far as it followed its
+seed gains nothing from a pool (``v3-multiarea-storm``).
+
 params: ``mix`` (shares of lsa / bfd / carrier / ifconfig events),
 ``gap_short_share``, ``gap_short_s``, ``gap_long_s`` (bursty virtual
 gaps), ``drop_prob``, ``rxmt_delay_s`` (lost LSA arrivals),
-``warmup_events``, ``settle_s``, ``parity_samples``.
+``pool``, ``warmup_events``, ``settle_s``, ``parity_samples``.
 """
 
 from __future__ import annotations
@@ -28,6 +42,12 @@ from benchmark.stormnet import StormNet
 SPF_TRIGGERS = ("lsa", "ifconfig")
 #: more events than any window can hold: nothing is evicted
 CAPACITY = 1 << 17
+#: the traffic's generators, drawn anew at the edge of every block
+STREAMS = ("_mix", "_loss", "_gap", "_pick", "_hot")
+#: the one realisation of a cell's traffic that every ``--seed`` orders
+TRAFFIC_SEED = 34
+#: injected events a block, where a cell has no ``hot_epoch_events``
+BLOCK_EVENTS = 200
 
 
 class WallStamps:
@@ -80,8 +100,7 @@ class Driver:
             max_degree=lsdb["max_degree"], spf_delay=self.config["spf_delay"],
             rxmt_delay=self.params["rxmt_delay_s"],
         )
-        streams = np.random.default_rng(self.seed).spawn(4)
-        self._mix, self._loss, self._gap, keep = streams
+        keep = self._order_blocks()
         self._edges = np.cumsum([
             self.params["mix"][t] for t in ("lsa", "bfd", "carrier")
         ])
@@ -96,9 +115,42 @@ class Driver:
         # compiled (or fetched) before the window opens.
         self._arm()
         for _ in range(int(self.params["warmup_events"])):
-            self._inject()
+            self._event()
             self.net.loop.advance(self._next_gap())
         self._settle()
+
+    def _order_blocks(self) -> np.random.Generator:
+        """``--seed`` orders the pool of blocks, or seeds the traffic's
+        generators where the cell names no pool; it always seeds the
+        parity check's sample (returned)."""
+        self._block_events = int(
+            self.params.get("hot_epoch_events", BLOCK_EVENTS)
+        )
+        self._block = -1
+        self.injected = Counter()  # by kind, scripted warm-up included
+        rng = np.random.default_rng(self.seed)
+        if "pool" not in self.params:
+            self._order = None  # the streams, in the order they always had
+            self._mix, self._loss, self._gap, keep, self._pick, self._hot = (
+                rng.spawn(len(STREAMS) + 1)
+            )
+            return keep
+        order, keep = rng.spawn(2)
+        self._order = order.permutation(int(self.params["pool"]))
+        return keep
+
+    def _event(self) -> None:
+        """Inject the traffic's next event, from the generators of the
+        block it falls in."""
+        block = self.injected.total() // self._block_events
+        if block != self._block:
+            self._block = block
+            if self._order is not None:
+                at = int(self._order[block % len(self._order)])
+                fresh = np.random.default_rng([TRAFFIC_SEED, at])
+                for name, stream in zip(STREAMS, fresh.spawn(len(STREAMS))):
+                    setattr(self, name, stream)
+        self._inject()
 
     def _arm(self) -> None:
         """Fresh tracker and ledger: the window sees its own events."""
@@ -131,18 +183,23 @@ class Driver:
     def _inject(self) -> None:
         net, roll = self.net, self._mix.random()
         if roll < self._edges[0]:
+            kind = "lsa"
             edge = net.flappable[int(self._mix.integers(len(net.flappable)))]
             net.flap(
                 edge, lost=self._loss.random() < self.params["drop_prob"]
             )
         elif roll < self._edges[1]:
+            kind = "bfd"
             net.bfd(net.g0, "up" if self._bfd_down else "down")
             self._bfd_down = not self._bfd_down
         elif roll < self._edges[2]:
+            kind = "carrier"
             net.carrier("e1", operative=self._carrier_down)
             self._carrier_down = not self._carrier_down
         else:
+            kind = "ifconfig"
             net.ifconfig_metric()
+        self.injected[kind] += 1
 
     def _next_gap(self) -> float:
         """Mostly sub-second (a real flap storm), at times a lull of
@@ -168,7 +225,7 @@ class Driver:
         window.open()
         while window.tick():
             t0 = clock()
-            self._inject()
+            self._event()
             generator_s += clock() - t0
             events += 1
             advance(self._next_gap())
@@ -210,6 +267,10 @@ class Driver:
             "waterfalls": self.ledger.waterfalls(),
             "counts": {
                 "injected": events, "events": len(done),
+                "block_order": (
+                    None if self._order is None else self._order.tolist()
+                ),
+                "blocks_begun": self._block + 1,
                 "outcomes": outcomes, "spf_path_converged": len(walls),
                 "tail_samples_beyond": (
                     stats.samples_beyond(len(walls), 90.0) if walls else 0

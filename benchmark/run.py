@@ -116,6 +116,15 @@ class ReadContext:
         self.trace = reduce_file(path, self.window.trace_wall)
 
 
+def mismatches(report) -> int:
+    """Samples a parity report, however nested, found differing."""
+    if not isinstance(report, dict):
+        return 0
+    return len(report.get("mismatches", ())) + sum(
+        mismatches(part) for part in report.values()
+    )
+
+
 def memory_stats() -> dict:
     """Statistics of the fullest device (empty where the backend has
     none, as on the CPU)."""
@@ -191,16 +200,6 @@ def measure(
 
     compiles = int(window.counter_delta("holo_spf_jit_compiles_total"))
     fallback = witness.check()
-    checks = {
-        "platform_is_tpu": on_chip,
-        "fallback_clean": fallback["clean"],
-        "no_compile_in_window": compiles == 0 and programs_in_window == 0,
-        "parity": parity["ok"],
-        "nothing_failed": (
-            run["failed"] == 0 and run["attempted"] > 0
-            and len(run["end_to_end"]) > 0
-        ),
-    }
     notes = {
         "programs": clock.programs, "cache_hits": clock.cache_hits,
         "window_compiles": compiles, "programs_in_window": programs_in_window,
@@ -212,6 +211,35 @@ def measure(
         notes["window_s"] = window.wall
         notes["timing"] = run.get("timing", {})
     print("benchmark: " + json.dumps(notes), flush=True)
+    # Every number behind ``correct``, as [read, limit]: each comparison
+    # is exact, so a run is correct when none is over its limit.
+    compared = {
+        "chips_missing": [int(not on_chip), 0],
+        "fallback_dispatches": [fallback["fallbacks"], 0],
+        "unclean_breakers": [len(fallback["unclean"]), 0],
+        "window_compiles": [compiles, 0],
+        "programs_in_window": [programs_in_window, 0],
+        "parity_mismatches": [mismatches(parity), 0],
+        "parity_not_ok": [int(not parity["ok"]), 0],
+        "failed": [int(run["failed"]), 0],
+        "nothing_attempted": [int(run["attempted"] == 0), 0],
+        "end_to_end_unread": [int(len(run["end_to_end"]) == 0), 0],
+    }
+
+    def within(*names: str) -> bool:
+        return all(compared[n][0] <= compared[n][1] for n in names)
+
+    checks = {
+        "platform_is_tpu": within("chips_missing"),
+        "fallback_clean": within("fallback_dispatches", "unclean_breakers"),
+        "no_compile_in_window": within(
+            "window_compiles", "programs_in_window"
+        ),
+        "parity": within("parity_mismatches", "parity_not_ok"),
+        "nothing_failed": within(
+            "failed", "nothing_attempted", "end_to_end_unread"
+        ),
+    }
 
     result = {
         "correct": all(checks.values()),
@@ -238,6 +266,7 @@ def measure(
             # No device operation in the trace: fails the run on the
             # chip, and is what a rehearsal off it must find.
             result["trace_error"] = str(exc)
+            compared["trace_without_device_op"] = [1, 0]
             result["correct"] = False
         else:
             result["device"]["busy_s"] = ctx.trace.busy_s
@@ -251,12 +280,16 @@ def measure(
         result["device"].pop("busy_s", None)
         result["device"].pop("window_s", None)
         result.pop("breakdown", None)
+        result["compared"] = compared
         return result, RC_NO_CHIP
     if trace:
         result["metrics"] = layers
         result["end_to_end_traced"] = end_to_end
     else:
         result["metrics"] = end_to_end
+    result["compared"] = compared  # last in the line, and on stderr
+    print(f"benchmark: compared [read, limit] {json.dumps(compared)}",
+          file=sys.stderr, flush=True)
     return result, RC_OK if result["correct"] else RC_INCORRECT
 
 

@@ -49,14 +49,27 @@ def label(q: float) -> str:
     return f"p{q:g}"
 
 
+def interquartile_mean(values) -> float:
+    """Mean of the samples from the nearest-rank p25 to the p75."""
+    arr = np.sort(np.asarray(values, np.float64))
+    if arr.size == 0:
+        raise ValueError("interquartile mean of no samples")
+    return float(
+        arr[_rank(arr.size, 25.0) - 1: _rank(arr.size, 75.0)].mean()
+    )
+
+
 def summary(values) -> dict:
     """Count, median and the highest supported percentile of a sample,
-    as printed beside every timing the benchmark reports."""
+    as printed beside every timing the benchmark reports, with the mean
+    and the interquartile mean beside the median (notes, no metric)."""
     n = len(values)
     out: dict = {"count": n}
     if n == 0:
         return out
     out["p50"] = percentile(values, 50.0)
+    out["mean"] = float(np.mean(values))
+    out["iqm"] = interquartile_mean(values)
     top = highest_supported(n)
     out["highest"] = None if top is None else label(top)
     if top is not None and top != 50.0:

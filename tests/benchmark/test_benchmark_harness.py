@@ -125,6 +125,41 @@ def test_forced_dispatch_failure_is_incorrect_though_every_bit_matches(
     assert result["correct"] is False
 
 
+def test_every_number_compared_stands_beside_its_limit_last_in_the_line():
+    from holo_tpu.resilience.faults import FaultPlan, inject
+
+    clean, _rc = _measure("tiny-storm")
+    assert list(clean)[-1] == "compared"
+    over = {k for k, (read, limit) in clean["compared"].items() if read > limit}
+    assert over == {"chips_missing"}  # a rehearsal: no chip, all else clean
+    assert all(limit == 0 for _read, limit in clean["compared"].values())
+    with inject(FaultPlan(dispatch_fail={"spf.dispatch": 1})):
+        broken, _rc = _measure("tiny-storm")
+    read, limit = broken["compared"]["fallback_dispatches"]
+    assert read >= 1 and limit == 0
+
+
+@pytest.mark.parametrize("fault", [False, True])
+def test_correct_is_decided_by_the_numbers_compared_and_nothing_else(fault):
+    """``checks`` and ``correct`` are read off ``compared``: a check
+    fails exactly where one of its numbers is over its limit."""
+    from contextlib import nullcontext
+
+    from holo_tpu.resilience.faults import FaultPlan, inject
+
+    plan = FaultPlan(dispatch_fail={"spf.dispatch": 1})
+    with inject(plan) if fault else nullcontext():
+        result, _rc = _measure("tiny-single")
+    over = {k for k, (read, limit) in result["compared"].items() if read > limit}
+    failing = {k for k, ok in result["checks"].items() if not ok}
+    assert ("fallback_dispatches" in over) is fault
+    assert ("fallback_clean" in failing) is fault
+    assert "platform_is_tpu" in failing and "chips_missing" in over
+    assert len(failing) <= len(over) and result["correct"] is (not over)
+    if not fault:
+        assert failing == {"platform_is_tpu"} and over == {"chips_missing"}
+
+
 def test_clean_rehearsal_in_process_fails_only_on_the_platform():
     result, rc = _measure("tiny-single")
     assert rc == 3 and result["metrics"] == {}
@@ -162,3 +197,58 @@ def test_benchmark_json_lists_what_the_files_hold():
     assert {m["name"] for m in top["per_layer"]} == {
         p.stem for p in (bench / "layer_metrics").glob("*.json")
     }
+
+
+def _backend_answering(alter):
+    """``fabric.backend_of`` for a device backend whose every answer
+    passes through ``alter`` where it is produced."""
+    from holo_tpu.spf.backend import TpuSpfBackend
+
+    class Altered(TpuSpfBackend):
+        def compute(self, topo, edge_mask=None, **kw):
+            return alter(super().compute(topo, edge_mask, **kw))
+
+        def compute_whatif(self, topo, edge_masks, **kw):
+            return [
+                alter(res)
+                for res in super().compute_whatif(topo, edge_masks, **kw)
+            ]
+
+    return lambda config: Altered(**config.get("backend", {}))
+
+
+def _one_hop_further(res):
+    """The farthest vertex one unit further: a wrong answer."""
+    import dataclasses
+
+    dist = res.dist.copy()
+    dist[-1] += 1
+    return dataclasses.replace(res, dist=dist)
+
+
+@pytest.mark.parametrize(
+    "workload, alter, correct",
+    [("tiny-single", _one_hop_further, False),
+     ("tiny-sweep", _one_hop_further, False),
+     ("tiny-storm", _one_hop_further, False),
+     ("tiny-single", lambda res: res, True),
+     ("tiny-storm", lambda res: res, True)],
+)
+def test_an_answer_altered_where_it_is_produced_is_incorrect(
+    monkeypatch, workload, alter, correct
+):
+    """The control of ``correct``: the timed path broken underneath
+    the harness (every guarantee of the configuration's file rests on
+    these answers) fails the parity check, and only that one; the same
+    wrapper altering nothing passes it."""
+    from benchmark import fabric
+
+    monkeypatch.setattr(fabric, "backend_of", _backend_answering(alter))
+    result, _rc = _measure(workload)
+    assert result["checks"]["parity"] is correct
+    assert result["checks"]["fallback_clean"] and result["failed"] == 0
+    read, limit = result["compared"]["parity_not_ok"]
+    assert (read, limit) == (int(not correct), 0)
+    if not correct:
+        assert result["correct"] is False
+        assert result["compared"]["parity_mismatches"][0] >= 1

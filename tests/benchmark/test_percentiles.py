@@ -31,3 +31,27 @@ def test_percentile_is_a_measured_sample(q, want):
 def test_percentile_of_nothing_is_an_error():
     with pytest.raises(ValueError):
         stats.percentile([], 50.0)
+
+
+@pytest.mark.parametrize(
+    "values, mean, iqm",
+    [
+        # ranks 25..75 of 100: the long run in 13 is in the mean alone
+        ([float(v) for v in range(1, 100)] + [1000.0], 59.5, 50.0),
+        ([3.0, 1.0, 2.0, 4.0], 2.5, 2.0),  # ranks 1..3
+        ([7.0], 7.0, 7.0),
+    ],
+)
+def test_summary_prints_mean_and_interquartile_mean_beside_the_median(
+    values, mean, iqm
+):
+    out = stats.summary(values)
+    assert out["mean"] == pytest.approx(mean)
+    assert out["iqm"] == pytest.approx(iqm) == stats.interquartile_mean(values)
+    assert out["p50"] == stats.percentile(values, 50.0)
+
+
+def test_interquartile_mean_of_nothing_is_an_error():
+    assert stats.summary([]) == {"count": 0}
+    with pytest.raises(ValueError):
+        stats.interquartile_mean([])
