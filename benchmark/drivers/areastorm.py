@@ -62,6 +62,11 @@ class RunStamps(storm.WallStamps):
 
 
 class Driver(storm.Driver):
+    #: an instance of more than one area whose traffic is drawn whole
+    #: from ``--seed``: the same median under a name, and so a bound,
+    #: of its own (PERF.md, section 2)
+    METRIC = "multiarea_trigger_fib_p50_ms"
+
     def set_up(self) -> None:
         params, config = self.params, self.config
         self.backend = fabric.backend_of(config)

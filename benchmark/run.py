@@ -69,16 +69,43 @@ def load_plugin(kind: str, name: str):
     return importlib.import_module(f"benchmark.{kind}.{name}")
 
 
+#: all a twin may hold: what it takes from its original is never overridden
+TWIN_KEYS = {"name", "twin_of", "moves"}
+
+
+def layer_spec(name: str) -> dict:
+    """``layer_metrics/<name>.json``.  A metric has one ``moves``, so
+    the same reading in a cell that reports another end-to-end metric
+    is a twin: a file that names its original under ``twin_of`` and
+    holds only what differs, its ``name`` and its ``moves``."""
+    spec = load_json("layer_metrics", name)
+    if spec["name"] != name:
+        raise BenchError(f"{name}.json names metric {spec['name']!r}")
+    if "twin_of" not in spec:
+        return spec
+    if set(spec) != TWIN_KEYS:
+        raise BenchError(
+            f"twin {name}.json holds {sorted(spec)}, "
+            f"a twin holds {sorted(TWIN_KEYS)} and no more"
+        )
+    original = load_json("layer_metrics", spec["twin_of"])
+    if original["name"] != spec["twin_of"] or "twin_of" in original:
+        raise BenchError(
+            f"{name}.json is a twin of {spec['twin_of']!r}, "
+            "which is a twin itself or not the file of that name"
+        )
+    if original["moves"] == spec["moves"]:
+        raise BenchError(f"twin {name}.json moves what its original moves")
+    return {**original, **spec}
+
+
 def layer_specs(moved: set[str]) -> list[dict]:
     """Every layer metric that moves one of ``moved``, by name order."""
-    specs = []
-    for path in sorted((HERE / "layer_metrics").glob("*.json")):
-        spec = json.loads(path.read_text())
-        if spec["name"] != path.stem:
-            raise BenchError(f"{path.name} names metric {spec['name']!r}")
-        if spec["moves"] in moved:
-            specs.append(spec)
-    return specs
+    specs = [
+        layer_spec(path.stem)
+        for path in sorted((HERE / "layer_metrics").glob("*.json"))
+    ]
+    return [spec for spec in specs if spec["moves"] in moved]
 
 
 def read_layers(specs: list[dict], ctx) -> dict:

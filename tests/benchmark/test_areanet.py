@@ -178,14 +178,18 @@ def test_traced_rehearsal_reads_every_storm_metric_and_counts_the_storm():
     counts = report["counts"]
     read = set(counts["metrics_read"])
     assert {
-        "storm_spf_run_ms", "storm_topology_ms", "storm_delta_link_ms",
-        "storm_derive_ms", "storm_interarea_ms", "storm_publish_ms",
-        "storm_rib_apply_ms", "hold_coalesce_ms", "rib_fib_ms",
-        "storm_dispatch_ms", "local_repair_p50_ms",
-        "storm_derive_decode_share", "storm_topology_relower_share",
+        "multiarea_spf_run_ms", "multiarea_topology_ms",
+        "multiarea_delta_link_ms", "multiarea_derive_ms",
+        "multiarea_interarea_ms", "multiarea_publish_ms",
+        "multiarea_rib_apply_ms", "multiarea_hold_coalesce_ms",
+        "multiarea_rib_fib_ms", "multiarea_dispatch_ms",
+        "multiarea_local_repair_p50_ms", "multiarea_derive_decode_share",
+        "multiarea_topology_relower_share",
         "storm_area_dispatches_per_run", "storm_partial_run_share",
         "storm_rib_delta_routes_mean", "window_compiles",
     } <= read
+    # the OSPFv2 cells' names move another end-to-end metric
+    assert not {"storm_spf_run_ms", "hold_coalesce_ms", "rib_fib_ms"} & read
     assert set(counts["injected_by_kind"]) == {
         "link", "node", "summary", "bfd", "carrier", "ifconfig",
     }

@@ -176,6 +176,8 @@ def test_every_data_file_is_named_by_what_it_holds(kind):
 def test_benchmark_json_lists_what_the_files_hold():
     """BENCHMARK.json's cells, configurations and layer metrics are the
     files of the same names, with the same unit, layer and moves."""
+    from benchmark import run
+
     top = json.loads((REPO / "BENCHMARK.json").read_text())
     bench = REPO / "benchmark"
     for cfg in top["configs"]:
@@ -188,9 +190,7 @@ def test_benchmark_json_lists_what_the_files_hold():
             assert held[key] == cell[key]
     end_to_end = {m["name"] for m in top["end_to_end"]}
     for metric in top["per_layer"]:
-        held = json.loads(
-            (bench / f"layer_metrics/{metric['name']}.json").read_text()
-        )
+        held = run.layer_spec(metric["name"])  # a twin's, filled in
         for key in ("unit", "better", "source", "layer", "moves"):
             assert held[key] == metric[key], (metric["name"], key)
         assert metric["moves"] in end_to_end
