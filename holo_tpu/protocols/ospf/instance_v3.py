@@ -43,7 +43,7 @@ from holo_tpu.protocols.ospf.lsdb import (
     next_seq_no,
 )
 from holo_tpu.protocols.ospf.spf_run import (
-    DERIVE_NEXTHOPS,
+    KeptDerive,
     LoweredLsdbV3,
     NexthopAtom,
     SpfDelayFsm,
@@ -300,6 +300,9 @@ class OspfV3Instance(SpfDelayFsm, Actor):
         # control arm of the tests and of PERF.md section 6).
         self.reuse_unchanged_areas = True
         self._area_kept: dict = {}  # aid -> (st, backend, knobs, out, intra)
+        # aid -> (backend, knobs, spf_run.KeptDerive): the area's last
+        # intra-area derive, which the next one differs against
+        self._derive_kept: dict = {}
         self._age_scans: dict = {}  # aid -> lsdb.AgeScan of the area's LSDB
         # Hierarchical partition hint (ISSUE 15): router-id -> group
         # label lowered through spf_run.apply_partition_hint at the
@@ -1877,63 +1880,58 @@ class OspfV3Instance(SpfDelayFsm, Actor):
             for aid, out in outs.items():
                 self._area_kept[aid] = (
                     fresh[aid], self.backend, knobs, out,
-                    self._derive_intra(aid, out),
+                    self._derive_intra(aid, out, knobs),
                 )
         for aid in marshaled:  # in the areas' order, reused or not
             _st, _be, _kn, area_results[aid], intra_by_area[aid] = (
                 self._area_kept[aid]
             )
 
-    def _derive_intra(self, aid, out) -> dict:
+    @staticmethod
+    def _prefix_offers(body) -> tuple:
+        """An Intra-Area-Prefix LSA body as :class:`KeptDerive` lowers
+        it: the key of the vertex it refers to (None for a referenced
+        type that is no vertex) and its entries."""
+        if body.ref_type == int(P.LsaType.ROUTER):
+            key = ("R", body.ref_adv_rtr)
+        elif body.ref_type == int(P.LsaType.NETWORK):
+            key = ("N", body.ref_adv_rtr, int(body.ref_lsid))
+        else:
+            key = None
+        return key, [
+            (entry[0], entry[1], body.entry_opts(entry))
+            for entry in body.prefixes
+        ]
+
+    def _derive_intra(self, aid, out, knobs: tuple) -> dict:
         """1. intra-area routes (preferred over inter/external) of one
-        area from its SPF result.  A next-hop set is decoded once per
-        distinct bitmask row, so routes behind one set share one
-        frozenset."""
-        index, _keys, res, atoms, prefix_lsas = out
-        dist_of, words_of = res.dist, res.nexthop_words
-        router_t, network_t = int(P.LsaType.ROUTER), int(P.LsaType.NETWORK)
-        decoded: dict = {}
-        intra: dict = {}
-        offers = 0
-        for _adv, body in prefix_lsas:
-            if not body.prefixes:
-                continue
-            if body.ref_type == router_t:
-                v = index.get(("R", body.ref_adv_rtr))
-            elif body.ref_type == network_t:
-                v = index.get(("N", body.ref_adv_rtr, int(body.ref_lsid)))
-            else:
-                continue
-            if v is None:
-                continue
-            base = int(dist_of[v])
-            if base >= INF:
-                continue
-            words = words_of[v]
-            row = words.tobytes()
-            nhs = decoded.get(row)
-            if nhs is None:
-                nhs = decoded[row] = self._expand_atoms(words, atoms)
-            offers += len(body.prefixes)
-            for entry in body.prefixes:
-                prefix, total = entry[0], base + entry[1]
-                cur = intra.get(prefix)
-                if cur is None or total < cur.dist:
-                    intra[prefix] = V6Route(
-                        prefix, total, nhs,
-                        prefix_options=body.entry_opts(entry),
-                        area_id=aid, vertex=v,
-                    )
-                elif total == cur.dist:
-                    intra[prefix] = V6Route(
-                        prefix, total, cur.nexthops | nhs,
-                        prefix_options=cur.prefix_options,
-                        area_id=aid, vertex=cur.vertex,
-                    )
-        # The v2 derive's counter: offers by how their set was had.
-        DERIVE_NEXTHOPS.labels(path="decoded").inc(len(decoded))
-        DERIVE_NEXTHOPS.labels(path="reused").inc(offers - len(decoded))
-        return intra
+        area from its SPF result, by difference against the area's last
+        derive (:class:`KeptDerive`): a prefix none of whose inputs
+        moved keeps the route OBJECT it had.  The kept state is
+        dropped, and the derive a whole one, under the guards
+        ``_spf_pass`` holds ``_area_kept`` to (another backend, other
+        ``(frr, mp_k)`` knobs) and while IP-FRR is active:
+        ``_attach_frr_backups`` writes ``backups`` into the route
+        objects, and a kept one would carry a stale repair."""
+        index, keys, res, atoms, prefix_lsas = out
+        kept = self._derive_kept.get(aid)
+        if (
+            kept is None or kept[0] is not self.backend or kept[1] != knobs
+            or (self.frr is not None and self.frr.active())
+        ):
+            def make_route(prefix, dist, nexthops, options, vertex):
+                return V6Route(
+                    prefix, dist, nexthops, prefix_options=options,
+                    area_id=aid, vertex=vertex,
+                )
+
+            kept = self._derive_kept[aid] = (
+                self.backend, knobs,
+                KeptDerive(
+                    self._prefix_offers, self._expand_atoms, make_route
+                ),
+            )
+        return kept[2].derive(index, keys, atoms, res, prefix_lsas)
 
     @staticmethod
     def _route_delta(old: dict, new: dict) -> tuple[dict, list]:
@@ -2643,6 +2641,7 @@ class OspfV3Instance(SpfDelayFsm, Actor):
             self._spf_delta_bases.pop(area.area_id, None)
             self._spf_lowerings.pop(area.area_id, None)
             self._area_kept.pop(area.area_id, None)
+            self._derive_kept.pop(area.area_id, None)
         return st
 
     def _area_frr(self, area_id, topo) -> None:

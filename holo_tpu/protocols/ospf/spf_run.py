@@ -1156,6 +1156,285 @@ class LoweredLsdbV3(LoweredLsdb):
         return st
 
 
+# ===== Route derivation by difference ===================================
+
+_DERIVE_ROUTES = telemetry.counter(
+    "holo_ospf_derive_routes_total",
+    "KeptDerive.derive's routes by how the call had them: kept (the last "
+    "call's route object: no offer of the prefix sits on a vertex whose "
+    "distance or next-hop row moved, nor in an LSA that came, went or "
+    "was lowered anew), or rebuilt from the prefix's offers (every route "
+    "of a whole derive)",
+    ("path",),
+)
+
+#: distinct next-hop rows kept decoded between runs, while the atoms stay
+_DECODED_MAX = 4096
+
+
+def _atom_columns(words: np.ndarray, n_atoms: int) -> np.ndarray:
+    """Next-hop bitmask rows (``uint32[N, W]``) as one column per atom,
+    ``bool[N, n_atoms]``: atom ``a`` is bit ``a % 32`` of word ``a // 32``."""
+    atom = np.arange(n_atoms)
+    return (words[:, atom >> 5] >> (atom & 31).astype(words.dtype)) & 1 != 0
+
+
+class KeptDerive:
+    """One area's intra-area routes, derived by difference between SPF
+    runs: a route is rebuilt only where an input it is made from moved,
+    and is the last run's OBJECT everywhere else.
+
+    The state is the area's *offers* in flat form, in the order a walk
+    meets them (the live prefix-offering LSAs in LSDB order, entries in
+    LSA order): per offer its vertex (-1 where the LSA names none the
+    model has), its metric, its prefix object, its options, and the
+    *slot* of its prefix (equal prefixes share one); per slot last
+    run's route, or None where no offer of it was reachable.  Beside
+    them the planes of the last result.
+
+    ``lower(body)`` gives an LSA body's ``(vertex key or None,
+    [(prefix, metric, options), ...])``; ``expand(words, atoms)`` a
+    next-hop row's set; ``make_route(prefix, dist, nexthops, options,
+    vertex)`` a route, of which ``prefix`` is read back, and ``dist``,
+    ``nexthops``, ``prefix_options`` and ``vertex`` when offers tie.
+    """
+
+    def __init__(self, lower, expand, make_route) -> None:
+        self._lower, self._expand, self._make = lower, expand, make_route
+        self._keys: list | None = None  # the vertex model's, by identity
+        self._atoms: list | None = None
+        self._planes: tuple = ()  # (dist, nexthop_words, nh_weights)
+        self._bodies: list = []  # the offering LSAs, LSDB order
+        self._count = np.zeros(0, np.int64)  # offers per LSA
+        self._vertex = np.zeros(0, np.int64)  # per offer, as are these
+        self._metric = np.zeros(0, np.int64)
+        self._slot = np.zeros(0, np.int64)
+        self._prefix: list = []
+        self._opts: list = []
+        self._slot_of: dict = {}  # prefix -> slot
+        self._route: list = []  # per slot
+        self._reach = np.zeros(0, bool)  # per offer, as of the last run
+        self._table: dict = {}  # the last run's, never handed out
+        self._decoded: dict = {}  # next-hop row -> set, under _atoms
+
+    def _lowered(self, index: dict, bodies: list) -> tuple:
+        """``bodies`` as flat offers: ``(offers per body, vertex,
+        metric, slot, prefixes, options)``; a prefix not seen before
+        takes a new slot."""
+        count, vertex, metric, slot, prefix, opts = [], [], [], [], [], []
+        slot_of, route = self._slot_of, self._route
+        for body in bodies:
+            key, offers = self._lower(body)
+            v = -1 if key is None else index.get(key, -1)
+            count.append(len(offers))
+            for p, m, o in offers:
+                s = slot_of.get(p)
+                if s is None:
+                    s = slot_of[p] = len(route)
+                    route.append(None)
+                vertex.append(v)
+                metric.append(m)
+                slot.append(s)
+                prefix.append(p)
+                opts.append(o)
+        return (
+            np.array(count, np.int64), np.array(vertex, np.int64),
+            np.array(metric, np.int64), np.array(slot, np.int64),
+            prefix, opts,
+        )
+
+    def _reset(self, index: dict, bodies: list) -> None:
+        """Lower every LSA anew: no slot and no route of the last run
+        is kept."""
+        self._slot_of, self._route = {}, []
+        (
+            self._count, self._vertex, self._metric, self._slot,
+            self._prefix, self._opts,
+        ) = self._lowered(index, bodies)
+        self._bodies = bodies
+
+    def _follow(self, index: dict, bodies: list) -> np.ndarray | None:
+        """Bring the offers up to ``bodies``, told from the last run's
+        by identity: the segments of the LSAs that stayed are carried
+        over, the others lowered.  Returns the slots that an LSA which
+        came, went or was lowered anew offers or offered (``bool[S]``),
+        or None where no LSA differs."""
+        old = self._bodies
+        if len(old) == len(bodies) and all(map(operator.is_, old, bodies)):
+            return None
+        # ``old`` holds its bodies alive, so an id there is no other's.
+        at = {id(b): i for i, b in enumerate(old)}
+        was = np.array([at.get(id(b), -1) for b in bodies], np.int64)
+        came = np.flatnonzero(was < 0)
+        count, vertex, metric, slot, prefix, opts = self._lowered(
+            index, [bodies[i] for i in came.tolist()]
+        )
+        dirty = np.zeros(len(self._route), bool)
+        dirty[slot] = True
+        went = np.ones(len(old), bool)
+        went[was[was >= 0]] = False
+        dirty[self._slot[np.repeat(went, self._count)]] = True
+        # Each LSA's segment in (the last run's offers ++ the lowered).
+        n_old = len(self._vertex)
+        old_off = np.concatenate(([0], np.cumsum(self._count)))
+        new_off = n_old + np.concatenate(([0], np.cumsum(count)))
+        # (-1 reads the last place, here and below: overwritten)
+        start = old_off[was]
+        start[came] = new_off[:-1]
+        counts = np.append(self._count, 0)[was]
+        counts[came] = count
+        end = np.cumsum(counts)
+        rows = np.repeat(start - (end - counts), counts)
+        rows += np.arange(len(rows))
+        self._vertex = np.concatenate((self._vertex, vertex))[rows]
+        self._metric = np.concatenate((self._metric, metric))[rows]
+        self._slot = np.concatenate((self._slot, slot))[rows]
+        at_row = rows.tolist()
+        prefix = self._prefix + prefix
+        opts = self._opts + opts
+        self._prefix = [prefix[i] for i in at_row]
+        self._opts = [opts[i] for i in at_row]
+        self._count, self._bodies = counts, bodies
+        return dirty
+
+    def _sets_of(self, vertex: np.ndarray, words: np.ndarray, atoms) -> tuple:
+        """The next-hop set behind each of ``vertex``, one decode per
+        distinct row that no earlier run under these atoms decoded.
+        Returns ``(sets, rows decoded)``."""
+        uniq, inverse = np.unique(vertex, return_inverse=True)
+        decoded, fresh, sets = self._decoded, 0, []
+        for row in words[uniq]:
+            key = row.tobytes()
+            nhs = decoded.get(key)
+            if nhs is None:
+                nhs = decoded[key] = self._expand(row, atoms)
+                fresh += 1
+            sets.append(nhs)
+        return [sets[i] for i in inverse.tolist()], fresh
+
+    def _moved(self, planes: tuple, atoms: list) -> np.ndarray | None:
+        """The vertices whose row of any of ``planes`` is not the last
+        run's (``bool[N]``): what a route is made from, per vertex.
+        Under another atom table a bit names another hop, so the
+        next-hop rows are compared atom for atom (equal atoms paired in
+        order), and a row that holds an atom with no counterpart has
+        moved.  None where the two runs cannot be compared."""
+        dist, words, weights = planes
+        was_dist, was_words, was_weights = self._planes
+        if (weights is None) != (was_weights is None):
+            return None
+        moved = dist != was_dist
+        was_atoms = self._atoms
+        if atoms == was_atoms:
+            moved |= (words != was_words).any(axis=1)
+            if weights is not None:
+                moved |= (weights != was_weights).any(axis=1)
+            return moved
+        if weights is not None:
+            return None  # the weights' columns are atoms too: not paired
+        places: dict = {}
+        for now, atom in enumerate(atoms):
+            places.setdefault(atom, []).append(now)
+        pairs = [
+            (was, places[atom].pop(0))
+            for was, atom in enumerate(was_atoms) if places.get(atom)
+        ]
+        was_at = np.array([was for was, _now in pairs], np.int64)
+        now_at = np.array([now for _was, now in pairs], np.int64)
+        was_bits = _atom_columns(was_words, len(was_atoms))
+        now_bits = _atom_columns(words, len(atoms))
+        moved |= (was_bits[:, was_at] != now_bits[:, now_at]).any(axis=1)
+        moved |= np.delete(was_bits, was_at, axis=1).any(axis=1)
+        moved |= np.delete(now_bits, now_at, axis=1).any(axis=1)
+        return moved
+
+    def derive(self, index: dict, keys: list, atoms: list, res, lsas) -> dict:
+        """``{prefix: route}`` of one SPF result, key for key and in
+        the order a walk over ``lsas`` (``(advertising router, body)``
+        of the live offering LSAs, LSDB order) inserts them: a prefix
+        stands where its first reachable offer does; of its offers the
+        lowest total wins, equal totals unite their next-hop sets and
+        keep the first's options and vertex.
+
+        Whole (every route rebuilt, and counted so) where the last
+        run's state says nothing about this one: there is none, the
+        vertex model is another object (vertex ids moved), or the
+        result carries other planes.  The caller drops this object
+        where what it cannot see changed (another backend, other
+        knobs)."""
+        bodies = [body for _adv, body in lsas]
+        dist, words = res.dist, res.nexthop_words
+        planes = (dist, words, getattr(res, "nh_weights", None))
+        moved = None
+        if (
+            self._keys is keys
+            # slots of prefixes long gone: start again
+            and len(self._route) <= 2 * len(self._table) + 256
+        ):
+            moved = self._moved(planes, atoms)
+        same_offers = False
+        if moved is None:
+            self._reset(index, bodies)
+            self._keys = keys
+            dirty = np.ones(len(self._route), bool)
+        else:
+            dirty = self._follow(index, bodies)
+            same_offers = dirty is None
+            if same_offers:
+                dirty = np.zeros(len(self._route), bool)
+            # (an offer with no vertex reads the last vertex's flag)
+            dirty[self._slot[moved[self._vertex] & (self._vertex >= 0)]] = True
+        if atoms != self._atoms or len(self._decoded) > _DECODED_MAX:
+            self._atoms, self._decoded = list(atoms), {}
+        self._planes = planes
+
+        base = dist[self._vertex]
+        reach = (self._vertex >= 0) & (base < INF)
+        sel = np.flatnonzero(reach & dirty[self._slot])
+        vertex = self._vertex[sel]
+        sets, fresh = self._sets_of(vertex, words, atoms)
+        total = base[sel].astype(np.int64) + self._metric[sel]
+        prefix, opts, make = self._prefix, self._opts, self._make
+        new: dict = {}
+        for i, s, t, v, nhs in zip(
+            sel.tolist(), self._slot[sel].tolist(), total.tolist(),
+            vertex.tolist(), sets,
+        ):
+            cur = new.get(s)
+            if cur is None or t < cur.dist:
+                new[s] = make(prefix[i], t, nhs, opts[i], v)
+            elif t == cur.dist:
+                new[s] = make(
+                    prefix[i], t, cur.nexthops | nhs, cur.prefix_options,
+                    cur.vertex,
+                )
+        route = self._route
+        for s in np.flatnonzero(dirty).tolist():
+            route[s] = new.get(s)
+
+        if same_offers and np.array_equal(reach, self._reach):
+            # The keys and their order are the last run's: a copy keeps
+            # their hashes, and only a rebuilt prefix is hashed again.
+            table = dict(self._table)
+            for r in new.values():
+                table[r.prefix] = r
+        else:
+            at = np.flatnonzero(reach)
+            _slots, first = np.unique(self._slot[at], return_index=True)
+            at = at[np.sort(first)]
+            table = dict(zip(
+                [prefix[i] for i in at.tolist()],
+                [route[s] for s in self._slot[at].tolist()],
+            ))
+        self._reach, self._table = reach, table
+        _DERIVE_ROUTES.labels(path="rebuilt").inc(len(new))
+        _DERIVE_ROUTES.labels(path="kept").inc(len(table) - len(new))
+        _DERIVE_NEXTHOPS.labels(path="decoded").inc(fresh)
+        _DERIVE_NEXTHOPS.labels(path="reused").inc(int(reach.sum()) - fresh)
+        # The caller's own: a partial run edits it in place.
+        return dict(table)
+
+
 def build_topology(
     lsdb: Lsdb,
     router_id: IPv4Address,
@@ -1262,7 +1541,7 @@ _DERIVE_CALLS = telemetry.counter(
     ("path",),
 )
 
-DERIVE_NEXTHOPS = _DERIVE_NEXTHOPS = telemetry.counter(
+_DERIVE_NEXTHOPS = telemetry.counter(
     "holo_ospf_derive_nexthops_total",
     "derive_routes' prefix offers by how the offering vertex's next-hop "
     "set was had: decoded from its bitmask row (once per distinct row "
