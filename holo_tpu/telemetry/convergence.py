@@ -96,6 +96,25 @@ def set_critpath_hook(ledger) -> None:
     _CP_HOOK = ledger
 
 
+# SPF-run-begin hook (ISSUE 38): the ledger's ``run0`` stamp, taken
+# where spf_run drains an instance's pending ids (the seam OSPFv2,
+# OSPFv3 and IS-IS share; the ``ospf.spf.run`` stage's begin edge would
+# have served OSPF alone: IS-IS books no host stage).  A seam of its
+# own and not a method of _CP_HOOK: the benchmark lays its own object
+# over that one, which forwards the four calls it knows.  Same
+# contract: one module global, installed only by critpath.configure,
+# one None check when disarmed.
+_RUN_HOOK = None
+
+
+def set_run_hook(fn) -> None:
+    """Install/remove the SPF-run-begin hook ``fn(eids)``
+    (:func:`holo_tpu.telemetry.critpath.configure` is the only
+    caller); ``None`` disarms."""
+    global _RUN_HOOK
+    _RUN_HOOK = fn
+
+
 # SLO-engine hook (ISSUE 20): while armed, every fib_commit close ALSO
 # grades the event's end-cut latency against the declared objectives in
 # holo_tpu.telemetry.slo.  Same contract as _CP_HOOK: one module
@@ -482,6 +501,9 @@ def spf_run(pending: list, instance: str = ""):
     ``spf`` phase on normal completion.  Yields the drained ids."""
     eids = tuple(pending)
     del pending[:]
+    rh = _RUN_HOOK
+    if rh is not None and eids:
+        rh(eids)
     with activation(eids):
         yield eids
         if eids:

@@ -24,7 +24,8 @@ event the stamps become an ordered sequence of *cuts*, each clamped
 monotonically into ``[t_begin, t_end]``; phases are the differences
 between consecutive cuts, so they telescope to the wall exactly:
 
-    begin ──wake──▶ spf-scheduled ──coalesce_wait──▶ enqueue
+    begin ──wake──▶ spf-scheduled ──coalesce_wait──▶ spf-run-begin
+      ──coalesce_wait──▶ enqueue
       ──queue_wait──▶ marshal-begin ──marshal──▶ marshal-end
       ──device──▶ device-end ──force_wait──▶ force-end
       ──rib──▶ spf-observed ──rib──▶ rib-observed
@@ -50,6 +51,22 @@ residual that no stamp explains is *reported*, never hidden: the
 event with NO stamps at all books its whole wall there — held under 1%
 of the wall at p50 by ``tests/test_critpath.py::
 test_storm_delay_inflates_device_phase_digest_identical``.
+
+What an event waits for (ISSUE 38).  ``coalesce_wait`` holds two
+things: the **wait** in front of the SPF run that drains the event
+(every delivery the loop makes while the delay timer is pending, and
+the collector's pauses among them) and the run's own host work before
+its first dispatch, the **prerun** (``topology``, ``link``).  The
+``run0`` stamp, taken where :func:`convergence.spf_run` drains the
+event, cuts the phase in two; the phase is the sum of the two slices,
+so ``PHASES`` and every reading of them stay what they were.  Each
+completed record carries the split under ``hold``: ``wait``,
+``prerun`` and ``by``, the wait charged to the innermost spans that
+ran in it (the difference of two :func:`profiling.account_at`
+snapshots, which give the ``sched`` and ``run0`` stamps their times:
+it sums to ``wait``; ``{}`` where device profiling is off, and where
+the cut did not stand as stamped: a run that dispatched nothing has no
+``coalesce_wait`` to cut).
 
 Aggregation + sentinel
 ----------------------
@@ -131,21 +148,21 @@ class _Rec:
     resolves min/max-wards, inside the phase's own noise floor)."""
 
     __slots__ = (
-        "trigger", "t0", "sched", "enqueue", "launch0", "marshal0",
-        "marshal1", "device_end", "force0", "force1", "spf", "rib",
-        "t_end", "stalls", "engine", "kind", "bucket",
+        "trigger", "t0", "sched", "run0", "enqueue", "launch0", "marshal0",
+        "marshal1", "device_end", "force1", "spf", "rib",
+        "t_end", "stalls", "engine", "kind", "bucket", "acct0", "by",
     )
 
     def __init__(self, trigger: str, t0: float):
         self.trigger = trigger
         self.t0 = t0
         self.sched = None
+        self.run0 = None
         self.enqueue = None
         self.launch0 = None
         self.marshal0 = None
         self.marshal1 = None
         self.device_end = None
-        self.force0 = None
         self.force1 = None
         self.spf = None
         self.rib = None
@@ -154,53 +171,88 @@ class _Rec:
         self.engine = "-"
         self.kind = "-"
         self.bucket = "-"
+        # the thread's account (profiling.account_at) at the sched
+        # stamp, and what it gained by the run0 stamp ({} while device
+        # profiling is off)
+        self.acct0 = None
+        self.by: dict = {}
 
 
-def _decompose(rec: _Rec, t_done: float, fallback: bool) -> dict:
-    """The cut model: clamped-monotone cuts → telescoping phase dict.
+#: the phase each cut closes, in cut order: ``coalesce_wait`` and
+#: ``rib`` are two slices each (the wait in front of the SPF run and
+#: the run's host work before its first dispatch; route derivation and
+#: publish + apply)
+_CUT_PHASES = (
+    "wake", "coalesce_wait", "coalesce_wait", "queue_wait", "marshal",
+    "device", "force_wait", "rib", "rib", "fib_commit", "unattributed",
+)
+_WAIT, _PRERUN, _DERIVE = 1, 2, 7  # slices of _CUT_PHASES told apart
 
-    Every cut is forced into ``[previous cut, t_done]``, so the phase
-    diffs are non-negative and sum to ``t_done - t0`` exactly (each
-    term is an exact float difference of consecutive cuts)."""
+
+def _slices(rec: _Rec, t_done: float) -> list:
+    """The cut model: clamped-monotone cuts → their consecutive
+    differences, one per entry of ``_CUT_PHASES``.
+
+    Every cut is forced into ``[previous cut, t_done]``, so the slices
+    are non-negative and sum to ``t_done - t0`` exactly (each is an
+    exact float difference of consecutive cuts).  A missing stamp
+    inherits its predecessor (its slice reads zero); a missing ``run0``
+    inherits its successor instead, the hold's end, so that the whole
+    of ``coalesce_wait`` stays the wait it was before the cut existed."""
     mb = rec.marshal0 if rec.marshal0 is not None else rec.launch0
+    # No pipeline ⇒ no enqueue stamp: the sched→marshal hold is the
+    # SPF delay FSM coalescing triggers, so it books as coalesce_wait
+    # (queue_wait then reads zero), not vice versa.
+    hold_end = rec.enqueue if rec.enqueue is not None else mb
+    # run0 cuts the hold and moves no phase: kept inside the hold's end
+    run0 = rec.run0
+    if run0 is None or hold_end is None or run0 > hold_end:
+        run0 = hold_end
     cuts = (
-        ("wake", rec.sched),
-        # No pipeline ⇒ no enqueue stamp: the sched→marshal hold is the
-        # SPF delay FSM coalescing triggers, so it books as
-        # coalesce_wait (queue_wait then reads zero), not vice versa.
-        ("coalesce_wait", rec.enqueue if rec.enqueue is not None else mb),
-        ("queue_wait", mb),
-        ("marshal", rec.marshal1),
-        ("device", rec.device_end),
-        ("force_wait", rec.force1),
+        rec.sched,
+        run0,
+        hold_end,
+        mb,
+        rec.marshal1,
+        rec.device_end,
+        rec.force1,
         # rib spans BOTH slices of RIB sync: host route derivation
         # from the ready result (…→spf-observed) and route publish +
         # apply (…→rib-observed).
-        ("rib", rec.spf),
-        ("rib", rec.rib),
-        ("fib_commit", rec.t_end),
+        rec.spf,
+        rec.rib,
+        rec.t_end,
         # The closing segment past the last stamp: an event that
         # converged with NO stamps books its whole wall here — the
         # honest "no stamp explains this" residual the storm test bounds.
-        ("unattributed", t_done),
+        t_done,
     )
     prev = rec.t0
-    phases = dict.fromkeys(PHASES, 0.0)
-    derive = 0.0  # the …→spf-observed slice (fallback relabel below)
-    for i, (name, c) in enumerate(cuts):
+    out = []
+    for c in cuts:
         c = prev if c is None else min(max(c, prev), t_done)
-        phases[name] += c - prev
-        if i == 6:  # the first rib slice: route derivation
-            derive = c - prev
+        out.append(c - prev)
         prev = c
+    return out
+
+
+def _fold(slices: list, fallback: bool) -> dict:
+    """Slices → the telescoping phase dict."""
+    phases = dict.fromkeys(PHASES, 0.0)
+    for name, dt in zip(_CUT_PHASES, slices):
+        phases[name] += dt
     if fallback:
         # The scalar oracle served this event: the device segment
         # (absent) plus the derivation slice — which then holds the
         # oracle's compute — are its phase, not a device/rib lie.
-        phases["fallback"] = phases["device"] + derive
+        phases["fallback"] = phases["device"] + slices[_DERIVE]
         phases["device"] = 0.0
-        phases["rib"] -= derive
+        phases["rib"] -= slices[_DERIVE]
     return phases
+
+
+def _decompose(rec: _Rec, t_done: float, fallback: bool) -> dict:
+    return _fold(_slices(rec, t_done), fallback)
 
 
 def _verdict(phases: dict) -> str:
@@ -242,6 +294,7 @@ class CritPathLedger:
         self._completed = 0
         self._dropped = 0
         self._sheds = 0
+        self._no_run_stamp = 0
 
     # -- hot path: stamps -----------------------------------------------
 
@@ -260,7 +313,25 @@ class CritPathLedger:
     def ev_sched(self, eid: int) -> None:
         rec = self._recs.get(eid)
         if rec is not None and rec.sched is None:
-            rec.sched = profiling.clock()
+            rec.sched, rec.acct0 = profiling.account_at(profiling.clock())
+
+    def run_begin(self, eids) -> None:
+        """The SPF run that drains ``eids`` begins: the cut inside
+        ``coalesce_wait``, and what the loop's thread did since each
+        event's sched stamp (the account is a thread's own, and an
+        instance schedules and runs on its loop's thread)."""
+        now, acct = profiling.account_at(profiling.clock())
+        for eid in eids:
+            rec = self._recs.get(eid)
+            if rec is None or rec.run0 is not None:
+                continue
+            rec.run0 = now
+            acct0 = rec.acct0
+            if acct and acct0:
+                rec.by = {
+                    span: dt for span, total in acct.items()
+                    if (dt := total - acct0.get(span, 0.0)) > 0.0
+                }
 
     def ev_phase(self, eid: int, phase: str) -> None:
         rec = self._recs.get(eid)
@@ -292,7 +363,20 @@ class CritPathLedger:
         if t_done is None:
             t_done = profiling.clock()
         t_done = max(t_done, rec.t0)
-        phases = _decompose(rec, t_done, fallback)
+        slices = _slices(rec, t_done)
+        phases = _fold(slices, fallback)
+        if rec.run0 is None and (
+            rec.enqueue is not None or rec.marshal0 is not None
+            or rec.launch0 is not None
+        ):
+            self._no_run_stamp += 1  # a run nobody announced: never hidden
+        # The account explains the wait it was taken over, sched to
+        # run0: where the cut did not stand as stamped (clamped, or no
+        # dispatch followed and the phase reads zero) it explains
+        # nothing of this record.
+        by = rec.by
+        if by and slices[_WAIT] != rec.run0 - rec.sched:
+            by = {}
         verdict = _verdict(phases)
         self._verdicts[verdict] += 1
         _VERDICTS.labels(verdict=verdict).inc()
@@ -312,6 +396,13 @@ class CritPathLedger:
             "bucket": rec.bucket,
             "stalls": rec.stalls,
             "fallback": bool(fallback),
+            # coalesce_wait cut at the run's begin: wait + prerun is
+            # the phase above, by sums to wait (to the rounding)
+            "hold": {
+                "wait": round(slices[_WAIT], 9),
+                "prerun": round(slices[_PRERUN], 9),
+                "by": {k: round(v, 9) for k, v in sorted(by.items())},
+            },
         })
         self._completed += 1
         _OPEN.set(len(self._recs))
@@ -397,15 +488,12 @@ class CritPathLedger:
                 rec.device_end = now
 
     def note_force(self, eids, edge: str) -> None:
+        if edge != "e":
+            return
         now = profiling.clock()
         for eid in eids:
             rec = self._recs.get(eid)
-            if rec is None:
-                continue
-            if edge == "b":
-                if rec.force0 is None:
-                    rec.force0 = now
-            elif rec.force1 is None or now > rec.force1:
+            if rec is not None and (rec.force1 is None or now > rec.force1):
                 rec.force1 = now
 
     def note_stall(self, eids) -> None:
@@ -508,6 +596,9 @@ class CritPathLedger:
             "completed": self._completed,
             "dropped": self._dropped,
             "sheds": self._sheds,
+            # completed events that went through an SPF run (a marshal
+            # or queue stamp) which no run0 stamp announced
+            "no_run_stamp": self._no_run_stamp,
             "capacity": self.capacity,
             "sketches": len(self._sketches),
             "verdicts": dict(self._verdicts),
@@ -575,10 +666,12 @@ def configure(
         )
         profiling.set_phase_hook(_CP._on_stage)
         convergence.set_critpath_hook(_CP)
+        convergence.set_run_hook(_CP.run_begin)
     else:
         _CP = None
         profiling.set_phase_hook(None)
         convergence.set_critpath_hook(None)
+        convergence.set_run_hook(None)
     return _CP
 
 

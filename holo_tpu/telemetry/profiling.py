@@ -33,6 +33,20 @@ runs ``jit(...).lower(...).compile().cost_analysis()`` and records the
 XLA FLOP / bytes-accessed estimates per dispatch site — the denominator
 that turns a measured device time into achieved-vs-peak utilization.
 
+While armed the module also keeps an exact **account of the host's
+wall by innermost span** (ISSUE 38): every stage edge charges the time
+since the thread's last edge to the span on top of its stack (``-``
+where none is open), so over any interval on one thread the children
+of ``holo_profile_self_seconds_total{span}`` sum to the interval's wall
+and a nested span's time is counted once (:func:`self_seconds` is the
+same account charged up to *now*).  The collector's pauses are a span
+of the program too: arming appends one callback to ``gc.callbacks``
+that brackets every collection as ``runtime.gc`` — an annotation in the
+capture, an edge of the account (the pause is taken *out of* the span
+it interrupted) and an observation of
+``holo_runtime_gc_pause_seconds{generation}`` /
+``holo_runtime_gc_collected_total{generation}``.
+
 Everything is **off by default** (``[telemetry] profile-device-time``
 in holod.toml, :func:`set_device_profiling` programmatically): when
 disabled, :func:`stage` costs one module-global bool check and
@@ -47,6 +61,7 @@ values or reduces arrays on the traced path (holo-lint HL101/HL105).
 
 from __future__ import annotations
 
+import gc
 import logging
 import sys
 import threading
@@ -76,6 +91,27 @@ _COST_BYTES = telemetry.gauge(
     "XLA compile-time bytes-accessed estimate for the last-compiled "
     "shape bucket",
     ("site",),
+)
+
+_SELF_SECONDS = telemetry.counter(
+    "holo_profile_self_seconds_total",
+    "Host wall by innermost armed span, exclusive of the spans nested "
+    "in it (span=<site>.<stage>; '-' = under no armed span; "
+    "'runtime.gc' = a collector pause, taken out of the span it "
+    "interrupted); over an interval on one thread the children sum to "
+    "the interval's wall",
+    ("span",),
+)
+_GC_PAUSE = telemetry.histogram(
+    "holo_runtime_gc_pause_seconds",
+    "Pause of one collection of Python's cyclic collector (gc.callbacks "
+    "start to stop), armed with profile-device-time",
+    ("generation",),
+)
+_GC_COLLECTED = telemetry.counter(
+    "holo_runtime_gc_collected_total",
+    "Objects the cyclic collector freed, by the generation collected",
+    ("generation",),
 )
 
 _enabled = False
@@ -122,6 +158,163 @@ _HOST_SITES = frozenset({"ospf.spf", "loop"})
 _UNRESOLVED = object()
 _annotation = _UNRESOLVED
 
+# The account of the host's wall by innermost span (armed only).  One
+# per thread: ``stage()`` edges and the collector's callback move it,
+# :func:`self_seconds` reads it.  ``_account_epoch`` counts armings: an
+# account of an older one forgets its open spans and its last edge
+# (what went by while disarmed is nobody's), and keeps its totals.
+_GC_SPAN = "runtime.gc"
+_account_local = threading.local()
+_account_epoch = 0
+_self_children: dict = {}  # span -> its child of _SELF_SECONDS
+
+
+class _Account:
+    """One thread's wall by innermost open span, exclusive and exact:
+    every charge is ``now - last`` and moves ``last`` to ``now``, so
+    the totals telescope to the wall whatever the order of the edges."""
+
+    __slots__ = ("epoch", "last", "stack", "totals", "pauses", "gc")
+
+    def __init__(self):
+        self.epoch = 0
+        self.last = 0.0
+        self.stack: list[str] = []  # open armed spans, innermost last
+        self.totals = {"-": 0.0}  # span -> seconds, cumulative
+        # the collection under way on this thread: (begin, what its
+        # begin charged, its annotation); and the finished ones the
+        # registry has not been told yet: (generation, what begin and
+        # end charged, seconds, collected)
+        self.gc = None
+        self.pauses: list = []
+
+
+def _account(now: float) -> _Account:
+    acct = getattr(_account_local, "acct", None)
+    if acct is None:
+        acct = _account_local.acct = _Account()
+    if acct.epoch != _account_epoch:
+        acct.epoch, acct.last = _account_epoch, now
+        del acct.stack[:]
+        acct.gc = None
+    return acct
+
+
+def _charge(acct: _Account, now: float):
+    """Charge the time since the thread's last edge to its innermost
+    open span; returns ``(span, seconds)`` for the registry, or None
+    for an edge that was overtaken (already charged).  The collector's
+    callback runs this too, between any two bytecodes of an edge on the
+    same thread: so nothing here takes a lock, ``last`` moves before
+    anything is called, and the book is updated by a subscript."""
+    last = acct.last
+    if now <= last:
+        return None
+    acct.last = now
+    stack = acct.stack
+    span = stack[-1] if stack else "-"
+    dt = now - last
+    totals = acct.totals
+    try:
+        totals[span] += dt
+    except KeyError:
+        totals[span] = dt
+    return span, dt
+
+
+def _self_child(span: str):
+    child = _self_children.get(span)
+    if child is None:
+        child = _self_children[span] = _SELF_SECONDS.labels(span=span)
+    return child
+
+
+def _publish(acct: _Account, charged) -> None:
+    """Tell the registry what an edge charged, and what the collector's
+    callback left for it: the callback itself never does, because a
+    registry child is updated under its lock, which the code the
+    collector interrupted may hold."""
+    if charged is not None:
+        _self_child(charged[0]).inc(charged[1])
+    if acct.pauses:
+        pauses, acct.pauses = acct.pauses, []
+        for generation, charges, seconds, collected in pauses:
+            for charged in charges:  # at the pause's begin and end
+                if charged is not None:
+                    _self_child(charged[0]).inc(charged[1])
+            _GC_PAUSE.labels(generation=generation).observe(seconds)
+            _GC_COLLECTED.labels(generation=generation).inc(collected)
+
+
+def _span_edge(label: str, begin: bool, now: float) -> None:
+    acct = _account(now)
+    charged = _charge(acct, now)
+    if begin:
+        acct.stack.append(label)
+    elif acct.stack and acct.stack[-1] == label:
+        acct.stack.pop()
+    _publish(acct, charged)
+
+
+def account_at(now: float) -> tuple[float, dict]:
+    """The calling thread's seconds by innermost armed span, charged up
+    to ``now`` first, with the time they stand at: ``now``, or the
+    later edge that overtook it (a collection that began between the
+    caller's clock read and this call).  For a stamp that has to agree
+    with the account to the float (the critical-path ledger's
+    ``sched`` and ``run0``): the difference of two such snapshots sums
+    to the difference of their times.  Disarmed: ``(now, {})``."""
+    if not _enabled:
+        return now, {}
+    acct = _account(now)
+    _publish(acct, _charge(acct, now))
+    return acct.last, dict(acct.totals)
+
+
+def self_seconds() -> dict:
+    """The calling thread's seconds by innermost armed span (the
+    children of ``holo_profile_self_seconds_total`` it fed), charged up
+    to *now* first, so that a snapshot inside an open span is exact:
+    the difference of two snapshots sums to the wall between them.
+    Disarmed: ``{}``, and no clock read."""
+    return account_at(_timer())[1] if _enabled else {}
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` entry while armed: one collection is the span
+    ``runtime.gc`` of the thread it runs on.  Warn-only, as
+    :func:`_phase_guarded`: a bug here must never reach the code that
+    happened to allocate.  Under a swapped stage timer it does nothing:
+    a counter clock counts reads, and when the collector runs is no
+    part of a seeded workload."""
+    if _timer_overridden:
+        return
+    try:
+        now = _timer()
+        acct = _account(now)
+        if phase == "start":
+            before = _charge(acct, now)
+            acct.stack.append(_GC_SPAN)
+            acct.gc = (now, before, None)  # stands if the factory raises
+            if _annotation is not None and _annotation is not _UNRESOLVED:
+                ann = _annotation(_GC_SPAN)
+                ann.__enter__()
+                acct.gc = (now, before, ann)
+        elif acct.gc is not None:  # else: armed inside a collection
+            (t0, before, ann), acct.gc = acct.gc, None
+            charged = (before, _charge(acct, now))
+            if acct.stack and acct.stack[-1] == _GC_SPAN:
+                acct.stack.pop()
+            acct.pauses.append((
+                str(info.get("generation", "-")), charged, now - t0,
+                info.get("collected", 0),
+            ))
+            if ann is not None:
+                ann.__exit__(None, None, None)
+    except Exception:  # noqa: BLE001 — see contract above
+        log.debug("gc callback failed", exc_info=True)
+
+
 # (site, shape signature) -> {"flops": float, "bytes": float}; one entry
 # per compiled shape bucket, exactly mirroring the backends' jit caches.
 _cost_lock = threading.Lock()
@@ -132,8 +325,20 @@ def set_device_profiling(on: bool) -> None:
     """Arm/disarm the per-dispatch breakdown (daemon boot reads
     ``[telemetry] profile-device-time``; the benchmark's ``--trace 1``
     and tests flip it directly)."""
-    global _enabled
-    _enabled = bool(on)
+    global _enabled, _account_epoch
+    on = bool(on)
+    if on != _enabled:
+        if on:
+            _account_epoch += 1  # every thread's account starts anew
+            gc.callbacks.append(_on_gc)
+            _enabled = True
+        elif _on_gc in gc.callbacks:
+            gc.callbacks.remove(_on_gc)
+        # Arming: this thread's account starts here.  Disarming: it is
+        # charged up to here, and what the collector left for the
+        # registry reaches it.
+        self_seconds()
+        _enabled = on
     if _enabled and _annotation is _UNRESOLVED:
         _resolve_annotation()
     # The event loop's per-delivery span (site "loop", stage = actor).
@@ -272,7 +477,8 @@ def stage(site: str, name: str, device: str = "-"):
 
     Armed, the whole stage also sits inside the profiler annotation
     ``<site>.<name>`` (see ``_annotation``): a host span in the same
-    capture as the device operations."""
+    capture as the device operations; and both its edges move the
+    thread's account of its wall by innermost span (``_Account``)."""
     obs = _OBSERVER
     if obs is not None and site in _HOST_SITES:
         obs = None
@@ -294,10 +500,16 @@ def stage(site: str, name: str, device: str = "-"):
         ann = _resolve_annotation()
     label = f"{site}.{name}"
     t0 = _timer()
-    with _NULLCTX if ann is None else ann(label):
-        with telemetry.span(label, stage=name, device=device) as sid:
-            yield sid
-    dt = _timer() - t0
+    _span_edge(label, True, t0)
+    t1 = None
+    try:
+        with _NULLCTX if ann is None else ann(label):
+            with telemetry.span(label, stage=name, device=device) as sid:
+                yield sid
+        t1 = _timer()
+    finally:  # the account closes the span on an exception too
+        _span_edge(label, False, _timer() if t1 is None else t1)
+    dt = t1 - t0
     _STAGE_SECONDS.labels(site=site, stage=name, device=device).observe(
         dt, exemplar={"span_id": sid}
     )

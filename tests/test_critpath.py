@@ -48,8 +48,8 @@ def _reset_critpath_state():
 # -- cut model -----------------------------------------------------------
 
 _STAMPS = (
-    "sched", "enqueue", "launch0", "marshal0", "marshal1",
-    "device_end", "force0", "force1", "spf", "rib", "t_end",
+    "sched", "run0", "enqueue", "launch0", "marshal0", "marshal1",
+    "device_end", "force1", "spf", "rib", "t_end",
 )
 
 
