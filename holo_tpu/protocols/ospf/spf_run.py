@@ -23,6 +23,7 @@ FSM (:class:`SpfDelayFsm`), area address ranges
 from __future__ import annotations
 
 import enum
+import itertools
 import operator
 from dataclasses import dataclass, field
 from ipaddress import IPv4Address, IPv4Network
@@ -348,6 +349,16 @@ _TOPOLOGY_LSAS = telemetry.counter(
     ("path",),
 )
 
+_TOPOLOGY_ROWS = telemetry.counter(
+    "holo_ospf_topology_rows_total",
+    "LoweredLsdbV3.build_topology's link rows (one per link of every "
+    "emitted segment) by how an assembly had them: kept (destination "
+    "vertex and mutual flag are the last call's), or resolved anew (the "
+    "rows of a segment that was replaced, the rows that end at its "
+    "vertex, and every row of a whole assembly)",
+    ("path",),
+)
+
 # What a lowered entry is, and what a lowered link's neighbour id names:
 # a router id (p2p, virtual link, a network-LSA's attached router) or a
 # DR interface address (a router-LSA's transit link).
@@ -375,6 +386,70 @@ def _spliced(old: np.ndarray, runs, parts) -> np.ndarray:
         at = hi
     out.append(old[at:])
     return np.concatenate(out)
+
+
+def _aligned_runs(kept: list, cur: list) -> tuple[list, list]:
+    """``cur`` (an LSDB's entries now) against ``kept`` (as they were),
+    by identity: the runs ``(lo, hi)`` of places in ``kept`` whose
+    entries are gone, ascending and disjoint, and per run the entries
+    of ``cur`` that stand there instead, none of them an object
+    ``kept`` holds.  ``lsdb.entries`` is a dict, so the two are one
+    sequence with replacements in place, removals (``(p, p + 1)`` and
+    nothing) and appends (``(len, len)`` and the new entries)."""
+    n = min(len(kept), len(cur))
+    stale = [
+        i for i, same in enumerate(map(operator.is_, kept, cur)) if not same
+    ]
+    if not stale and len(kept) == len(cur):
+        return [], []
+    gone = [kept[i] for i in stale] + kept[n:]
+    come = [cur[i] for i in stale] + cur[n:]
+    if set(map(id, gone)).isdisjoint(map(id, come)):
+        # Every entry that stayed is at its place: replaced in place,
+        # and appended to or cut short at the end.
+        old_at = new_at = np.array(stale + [n], np.int64)
+        old_end, new_end = old_at + 1, new_at + 1
+        old_end[-1], new_end[-1] = len(kept), len(cur)
+    else:
+        # Entries that stayed have shifted (a removal in front of
+        # them): the stretches between one that stayed and the next,
+        # on either side, from the first difference on.
+        lo = stale[0]
+        old, new = kept[lo:], cur[lo:]
+        old_ids, new_ids = set(map(id, old)), set(map(id, new))
+        stays_old = np.fromiter(
+            map(new_ids.__contains__, map(id, old)), bool, len(old)
+        )
+        stays_new = np.fromiter(
+            map(old_ids.__contains__, map(id, new)), bool, len(new)
+        )
+        if not all(map(
+            operator.is_,
+            itertools.compress(old, stays_old),
+            itertools.compress(new, stays_new),
+        )):
+            # Not a dict's doing (the order of what stayed changed):
+            # all from the first difference on.
+            return [(lo, len(kept))], [new]
+        at_old = lo + np.flatnonzero(stays_old)
+        at_new = lo + np.flatnonzero(stays_new)
+        old_at = np.concatenate(([lo], at_old + 1))
+        new_at = np.concatenate(([lo], at_new + 1))
+        old_end = np.concatenate((at_old, [len(kept)]))
+        new_end = np.concatenate((at_new, [len(cur)]))
+    runs, fresh = [], []
+    some = np.flatnonzero((old_end > old_at) | (new_end > new_at))
+    for a, b, c, d in zip(
+        old_at[some].tolist(), old_end[some].tolist(),
+        new_at[some].tolist(), new_end[some].tolist(),
+    ):
+        if runs and runs[-1][1] == a:
+            runs[-1] = (runs[-1][0], b)
+            fresh[-1] += cur[c:d]
+        else:
+            runs.append((a, b))
+            fresh.append(cur[c:d])
+    return runs, fresh
 
 
 class _VertexIds:
@@ -440,10 +515,19 @@ class LoweredLsdb:
     ``Lsdb.install`` builds a new ``LsaEntry`` per install and nothing
     edits one in place, so what changed since the last call is found by
     identity: :meth:`build_topology` walks ``lsdb.entries`` beside
-    ``entries`` and lowers only the entries that are not the kept
-    object at their place (all from the first difference on, where the
-    length changed).  No journal and no hook in the LSDB: any way of
-    writing ``lsdb.entries`` is seen.
+    ``entries`` and lowers only the entries that are new objects.
+    ``lsdb.entries`` is a dict (a replaced key keeps its place, a
+    removed one closes its gap, a new one goes to the end), so the two
+    are aligned whatever the length did (:func:`_aligned_runs`): a
+    removal in the middle lowers nothing, an append what was appended.
+    No journal and no hook in the LSDB: any way of writing
+    ``lsdb.entries`` is seen.  What is kept is trusted by identity
+    alone: a segment while its entry is the kept object; what follows
+    from the live vertex ids while they are last call's.  Every array a
+    call hands out is the result's own and never written again (the
+    instance holds the previous :class:`SpfTopology` and diffs the next
+    against it); the kept columns are spliced into new arrays, not
+    edited.
 
     ``router_bodies`` is the live router-LSA bodies in vertex order as
     of the last call (``router_bodies[i]`` is vertex ``n_networks + i``).
@@ -543,38 +627,24 @@ class LoweredLsdb:
 
     def _refresh(self, lsdb: Lsdb) -> list:
         """Bring the lowering up to ``lsdb``: lower the entries that are
-        not the kept object at their place, splice their segments in.
-        Returns the ``(lo, hi)`` runs of places that were lowered."""
+        new objects, splice their segments in.  Returns the runs
+        ``(lo, hi, n)``: the kept places ``[lo, hi)`` gave way to ``n``
+        entries lowered anew (a removal has ``n`` 0, an append ``lo ==
+        hi``, the kept length)."""
         cur = list(lsdb.entries.values())
-        kept = self.entries
-        stale = [
-            i for i, same in enumerate(map(operator.is_, kept, cur))
-            if not same
-        ]
-        if len(kept) != len(cur):
-            # A changed length: everything from the first difference on.
-            first = stale[0] if stale else min(len(kept), len(cur))
-            runs = [(first, len(kept))]
-            fresh = [cur[first:]]
-        else:
-            runs = []
-            for i in stale:
-                if runs and runs[-1][1] == i:
-                    runs[-1] = (runs[-1][0], i + 1)
-                else:
-                    runs.append((i, i + 1))
-            fresh = [cur[lo:hi] for lo, hi in runs]
+        runs, fresh = _aligned_runs(self.entries, cur)
         lowered = sum(len(f) for f in fresh)
         _TOPOLOGY_LSAS.labels(path="lowered").inc(lowered)
         _TOPOLOGY_LSAS.labels(path="reused").inc(len(cur) - lowered)
         if not runs:
-            return runs
+            return []
         parts = [self._lower(f) for f in fresh]
         off, offer_off = self._link_off, self._offer_off
-        self._links = _spliced(
-            self._links, [(off[lo], off[hi]) for lo, hi in runs],
-            [links for _cols, links, _bodies, _offers in parts],
-        )
+        link_runs = [(off[lo], off[hi]) for lo, hi in runs]
+        link_parts = [links for _cols, links, _bodies, _offers in parts]
+        if any(hi > lo for lo, hi in link_runs) or any(map(len, link_parts)):
+            # (most entries that come and go are summaries: no link)
+            self._links = _spliced(self._links, link_runs, link_parts)
         offer_runs = [(offer_off[lo], offer_off[hi]) for lo, hi in runs]
         self._offer_metric = _spliced(
             self._offer_metric, offer_runs, [p[3][0] for p in parts]
@@ -591,7 +661,7 @@ class LoweredLsdb:
         self.entries = cur
         self._link_off = np.concatenate(([0], np.cumsum(self._n_links)))
         self._offer_off = np.concatenate(([0], np.cumsum(self._n_offers)))
-        return runs
+        return [(lo, hi, len(f)) for (lo, hi), f in zip(runs, fresh)]
 
     def _vertex_model(self, r_pos, n_pos) -> _VertexModel:
         """The vertex model of the live router and network LSAs at
@@ -860,19 +930,41 @@ class _VertexModelV3:
     seg_vertex: np.ndarray  # and the vertex each leaves from
 
 
+@dataclass
+class _KeptRows:
+    """One assembly's link rows: a row per link of every emitted
+    segment, in the order the edges go (Router-LSAs before Network-LSAs,
+    each in LSDB order, links in LSA order).  Never written once made."""
+
+    model: _VertexModelV3  # the vertex model ``dst`` was resolved under
+    emitted: list  # per segment, the LSDB entry it was lowered from
+    off: np.ndarray  # int64[segments + 1]: a segment's rows
+    src: np.ndarray  # int32: the vertex a row leaves from
+    dst: np.ndarray  # int32: the vertex it ends at, -1 where at none
+    cost: np.ndarray  # int32
+    mutual: np.ndarray  # bool: ends at a vertex, and a row leads back
+
+
 class LoweredLsdbV3(LoweredLsdb):
     """:class:`LoweredLsdb` for an OSPFv3 area (RFC 5340 §4.8.1: the
     vertex model of RFC 2328 §16.1 keyed by router id and by the DR's
     (router id, interface id)).  Router-LSAs of one router are one
     vertex, with the links of the last one in LSDB order, as a dict
     keyed by advertising router holds them; a link row is ``(link type
-    or -1, neighbour router id, metric, neighbour interface id)``."""
+    or -1, neighbour router id, metric, neighbour interface id)``.
+
+    Beside the lowered entries it keeps the last assembly's link rows
+    (:class:`_KeptRows`: per row the vertex it leaves from, the vertex
+    it ends at and whether a row leads back), trusted while the vertex
+    model is last call's OBJECT, so that a flap resolves the rows of
+    the LSAs it replaced and not the area's."""
 
     def __init__(self) -> None:
         super().__init__()
         # (router id << 32 | interface id) needs all 64 bits
         self._vid = np.zeros(0, np.uint64)
         self._model3: _VertexModelV3 | None = None
+        self._rows: _KeptRows | None = None
         # The last call's result, the other inputs it was made from and
         # which of the entries that matter were live: handed out again
         # while all of them stay what they were.
@@ -901,7 +993,8 @@ class LoweredLsdbV3(LoweredLsdb):
                 for rid in body.attached:
                     links.append((_V3_ATTACHED, int(rid), 0, 0))
             elif lsa.type == P.LsaType.INTRA_AREA_PREFIX:
-                k, body = _PREFIX, lsa.body
+                # as ``prefix_lsas`` lists it: made once, here
+                k, body = _PREFIX, (lsa.adv_rtr, lsa.body)
             kind.append(k)
             vid.append(v)
             age.append(lsa.age)
@@ -926,31 +1019,17 @@ class LoweredLsdbV3(LoweredLsdb):
             (np.zeros(0, np.int64), []),
         )
 
-    def _moved(self, was: np.ndarray, before: list, runs: list) -> bool:
+    def _moved(self, was: np.ndarray, runs: list) -> bool:
         """Whether the refresh that lowered ``runs`` replaced, added or
         dropped an entry that matters to the result (a Router-,
-        Network- or Intra-Area-Prefix LSA); ``was`` / ``before``: kinds
-        and entries as they were."""
-        if not runs:
-            return False
-        now = self.entries
-        if len(before) == len(now):
-            return any(
-                was[lo:hi].any() or self._kind[lo:hi].any()
-                for lo, hi in runs
-            )
-        # A changed length re-lowers all from the first difference on:
-        # what came or went is told by identity, not by place.
-        lo = runs[0][0]
-        old_ids = {id(e) for e in before[lo:]}
-        new_ids = {id(e) for e in now[lo:]}
-        return any(
-            was[lo + i] and id(e) not in new_ids
-            for i, e in enumerate(before[lo:])
-        ) or any(
-            self._kind[lo + i] and id(e) not in old_ids
-            for i, e in enumerate(now[lo:])
-        )
+        Network- or Intra-Area-Prefix LSA); ``was``: the kinds as they
+        were."""
+        shift = 0  # of the places now against the places then
+        for lo, hi, n in runs:
+            if was[lo:hi].any() or self._kind[lo + shift:lo + shift + n].any():
+                return True
+            shift += n - (hi - lo)
+        return False
 
     def _vertex_model3(self, r_pos, n_pos) -> _VertexModelV3:
         r_ids, n_ids = self._vid[r_pos], self._vid[n_pos]
@@ -980,6 +1059,85 @@ class LoweredLsdbV3(LoweredLsdb):
             ),
         )
         return m
+
+    def _resolve_rows(self, m: _VertexModelV3, seg: np.ndarray) -> tuple:
+        """The link rows of the entries at places ``seg``, in that
+        order, resolved under ``m``: per row ``_KeptRows``' ``dst`` and
+        ``cost``."""
+        from holo_tpu.protocols.ospf import packet_v3 as P
+
+        count = self._n_links[seg]
+        end = np.cumsum(count)
+        rows = np.repeat(self._link_off[seg] - (end - count), count)
+        rows += np.arange(len(rows))
+        kind, nbr, metric, ifid = self._links[rows].T
+        # A transit link ends at the DR's network vertex, every other
+        # link (and a network's attached router) at a router vertex.
+        nbr64 = nbr.astype(np.uint64)
+        at_r, there_r = lookup_sorted(m.rtr.uniq, nbr64)
+        at_n, there_n = lookup_sorted(
+            m.net.uniq, (nbr64 << np.uint64(32)) | ifid.astype(np.uint64)
+        )
+        dst = np.where(
+            kind == int(P.RouterLinkType.TRANSIT_NETWORK),
+            np.where(there_n, at_n, -1),
+            np.where(there_r, len(m.net.uniq) + at_r, -1),
+        )
+        return dst.astype(np.int32), metric.astype(np.int32)
+
+    def _link_rows(self, m: _VertexModelV3, seg: np.ndarray) -> _KeptRows:
+        """The link rows of the segments emitted from the entries at
+        places ``seg``.  While ``m`` is the last call's OBJECT, a row's
+        destination vertex cannot have changed unless its entry is a new
+        one, nor its mutual flag unless the entry behind either of its
+        ends is: only those rows are resolved, the others are the last
+        call's, copied.  Under another model every row is resolved."""
+        entries = self.entries
+        emitted = [entries[i] for i in seg.tolist()]
+        kept = self._rows
+        count = self._n_links[seg]
+        off = np.concatenate(([0], np.cumsum(count)))
+        src = np.repeat(m.seg_vertex, count).astype(np.int32)
+        if kept is None or kept.model is not m:
+            dst, cost = self._resolve_rows(m, seg)
+            mutual = np.zeros(len(dst), bool)
+            sub = np.flatnonzero(dst >= 0)
+            anew = len(dst)
+        else:
+            moved = np.array([
+                s for s, same in enumerate(
+                    map(operator.is_, kept.emitted, emitted)
+                ) if not same
+            ], np.int64)
+            new = self._resolve_rows(m, seg[moved])
+            anew = len(new[0])
+            runs = list(zip(
+                kept.off[moved].tolist(), kept.off[moved + 1].tolist()
+            ))
+            cuts = np.cumsum(count[moved])[:-1]
+            dst, cost, mutual = (
+                _spliced(old, runs, np.split(part, cuts))
+                for old, part in zip(
+                    (kept.dst, kept.cost, kept.mutual),
+                    (*new, np.zeros(anew, bool)),
+                )
+            )
+            # The rows that leave or reach a vertex whose segment is
+            # new are closed under reversal: their flags among
+            # themselves are their flags among all.  (``behind[-1]``,
+            # where a row ends at no vertex, is False.)
+            behind = np.zeros(len(m.keys) + 1, bool)
+            behind[m.seg_vertex[moved]] = True
+            reach = behind[dst]
+            anew += np.count_nonzero(reach & ~behind[src])
+            for lo, hi in zip(off[moved].tolist(), off[moved + 1].tolist()):
+                reach[lo:hi] = dst[lo:hi] >= 0
+            sub = np.flatnonzero(reach)
+        mutual[sub] = mutual_keep_mask(src[sub], dst[sub])
+        _TOPOLOGY_ROWS.labels(path="kept").inc(len(dst) - anew)
+        _TOPOLOGY_ROWS.labels(path="resolved").inc(anew)
+        self._rows = _KeptRows(m, emitted, off, src, dst, cost, mutual)
+        return self._rows
 
     def build_topology(
         self,
@@ -1011,10 +1169,22 @@ class LoweredLsdbV3(LoweredLsdb):
         ``lan_iface_of``: network vertex key -> our interface on that
         LAN.  Edges come in LSDB order, links in LSA order, Router-LSAs
         before Network-LSAs; next-hop atoms are assigned for the root's
-        own out-edges and for the edges out of its LANs."""
+        own out-edges and for the edges out of its LANs.
+
+        By difference: per link row the last assembly's source and
+        destination vertex, cost and mutual flag are kept
+        (:meth:`_link_rows`) and trusted while ``_vertex_model3`` hands
+        back last call's object (the same live Router- and Network-LSA
+        ids in LSDB order); then only the rows of the segments whose
+        entry is a new object are resolved, and the mutual flags of
+        the rows that leave or reach their vertices.  Another vertex
+        model (an LSA of either type came, went or reached MaxAge)
+        resolves every row.  The ``Topology`` returned holds arrays of
+        its own and is never written after this call returns: the
+        instance keeps it as the next run's delta base."""
         from holo_tpu.protocols.ospf import packet_v3 as P
 
-        was, before = self._kind, self.entries
+        was = self._kind
         runs = self._refresh(lsdb)
         live = ~(self._age + (now - self._installed_at) >= MAX_AGE)
         inputs = (
@@ -1035,7 +1205,7 @@ class LoweredLsdbV3(LoweredLsdb):
         if (
             keep_unchanged
             and kept is not None
-            and not self._moved(was, before, runs)
+            and not self._moved(was, runs)
             and np.array_equal(live_matter, kept[2])
             and inputs == kept[1]
         ):
@@ -1049,52 +1219,45 @@ class LoweredLsdbV3(LoweredLsdb):
         root = index.get(("R", router_id))
         if root is None:
             return None
-        bodies, entries = self._bodies, self.entries
+        bodies = self._bodies
         prefix_lsas = [
-            (entries[i].lsa.adv_rtr, bodies[i])
+            bodies[i]
             for i in np.flatnonzero(live & (self._kind == _PREFIX)).tolist()
         ]
         is_router = np.zeros(n, bool)
         is_router[nn:] = True
 
         seg = np.concatenate((r_pos, n_pos))[m.seg_pos]
-        count = self._n_links[seg]
-        end = np.cumsum(count)
-        rows = np.repeat(self._link_off[seg] - (end - count), count)
-        rows += np.arange(len(rows))
-        kind, nbr, metric, ifid = self._links[rows].T
-        src = np.repeat(m.seg_vertex, count)
-        # A transit link ends at the DR's network vertex, every other
-        # link (and a network's attached router) at a router vertex.
-        transit = kind == int(P.RouterLinkType.TRANSIT_NETWORK)
-        nbr64 = nbr.astype(np.uint64)
-        at_r, there_r = lookup_sorted(m.rtr.uniq, nbr64)
-        at_n, there_n = lookup_sorted(
-            m.net.uniq, (nbr64 << np.uint64(32)) | ifid.astype(np.uint64)
-        )
-        dst = np.where(transit, at_n, nn + at_r)
-        edge = np.flatnonzero(np.where(transit, there_n, there_r))
-        edge = edge[mutual_keep_mask(src[edge], dst[edge])]
-        kind, ifid = kind[edge], ifid[edge]
+        rows = self._link_rows(m, seg)
+        edge = np.flatnonzero(rows.mutual)
+        # Arrays of the result's own: a Topology handed out is never
+        # written again, and the kept rows are not handed out.
+        atom_ids = np.full(len(edge), -1, np.int32)
         topo = Topology(
             n_vertices=n,
             is_router=is_router,
-            edge_src=src[edge].astype(np.int32),
-            edge_dst=dst[edge].astype(np.int32),
-            edge_cost=metric[edge].astype(np.int32),
+            edge_src=rows.src[edge],
+            edge_dst=rows.dst[edge],
+            edge_cost=rows.cost[edge],
+            edge_direct_atom=atom_ids,
             root=root,
         )
 
         # Per-link hop resolution: parallel p2p links to one neighbour
         # are distinct atoms, matched by the neighbour's interface id.
+        # The root's out-edges are the edges of its own segment's rows.
         atoms: list = []
-        atom_ids = np.full(topo.n_edges, -1, np.int32)
         root_lans: list[int] = []
-        for e in np.flatnonzero(topo.edge_src == root).tolist():
+        slot = int(np.flatnonzero(m.seg_vertex == root)[0])
+        own = self._links[self._link_off[seg[slot]]:]
+        first = int(rows.off[slot])
+        lo, hi = np.searchsorted(edge, rows.off[slot:slot + 2]).tolist()
+        for e in range(lo, hi):
+            kind, _nbr, _metric, ifid = own[edge[e] - first].tolist()
             k = keys[int(topo.edge_dst[e])]
             if k[0] == "R":
                 hop = None
-                if kind[e] == int(P.RouterLinkType.VIRTUAL_LINK):
+                if kind == int(P.RouterLinkType.VIRTUAL_LINK):
                     # Virtual link: the borrowed transit-area set only;
                     # a direct adjacency here would pair the vlink
                     # metric with the wrong next hop.
@@ -1103,7 +1266,7 @@ class LoweredLsdbV3(LoweredLsdb):
                         hop = NexthopAtom(None, None, borrowed)
                 else:
                     hop = nbr_hop_by_ifid.get(
-                        (k[1], int(ifid[e]))
+                        (k[1], ifid)
                     ) or nbr_hop.get(k[1])
                 if hop is not None:
                     atom_ids[e] = len(atoms)
@@ -1128,7 +1291,6 @@ class LoweredLsdbV3(LoweredLsdb):
                 if member_nbr is not None:
                     atom_ids[e] = len(atoms)
                     atoms.append((iface.name, member_nbr.src))
-        topo.edge_direct_atom = atom_ids
         if iface_srlg:
             # v3 atoms are NexthopAtom (vlinks) or (ifname, addr) tuples.
             apply_interface_srlg(

@@ -6,17 +6,22 @@ stays here as the oracle: the same ``Topology``, ``keys``, ``atoms``,
 two Router-LSAs of one router, a MaxAge entry), on a fresh lowering and
 on one kept across changes."""
 
+import dataclasses
 from ipaddress import IPv4Address, IPv6Address, IPv6Network
 
 import numpy as np
 import pytest
 
+from holo_tpu import telemetry
 from holo_tpu.ops.graph import Topology, mutual_keep_mask
 from holo_tpu.protocols.ospf import packet_v3 as P
+from holo_tpu.protocols.ospf import spf_run
 from holo_tpu.protocols.ospf.instance_v3 import OspfV3Instance, V3IfConfig
 from holo_tpu.protocols.ospf.interface import IfType
 from holo_tpu.protocols.ospf.neighbor import Neighbor, NsmState
-from holo_tpu.protocols.ospf.spf_run import NexthopAtom
+from holo_tpu.protocols.ospf.spf_run import (
+    LoweredLsdbV3, NexthopAtom, link_spf_delta,
+)
 from holo_tpu.utils.runtime import EventLoop, VirtualClock
 
 ROOT = IPv4Address("10.0.0.1")
@@ -384,3 +389,363 @@ def test_marshal_walks_no_link_in_python(monkeypatch):
     )
     inst._area_marshal(area)
     assert not touched
+
+
+# -- the kept lowering by difference (ISSUE 39): entries followed by
+# identity whatever the LSDB's length does, a moved area's edges from
+# the last call's resolved rows
+
+AREA = IPv4Address(1)
+TOPO_FIELDS = (
+    "is_router", "edge_src", "edge_dst", "edge_cost", "edge_direct_atom",
+)
+
+
+def _counted(family: str, paths: tuple) -> dict:
+    snap = telemetry.snapshot(family)
+    return {
+        path: sum(v for k, v in snap.items() if f"path={path}" in k)
+        for path in paths
+    }
+
+
+def _moved_by(family: str, paths: tuple, call) -> dict:
+    before = _counted(family, paths)
+    call()
+    after = _counted(family, paths)
+    return {p: after[p] - before[p] for p in paths}
+
+
+def _lsas_moved(call) -> tuple:
+    moved = _moved_by(
+        "holo_ospf_topology_lsas_total", ("lowered", "reused"), call
+    )
+    return moved["lowered"], moved["reused"]
+
+
+def _rows_moved(call) -> tuple:
+    moved = _moved_by(
+        "holo_ospf_topology_rows_total", ("kept", "resolved"), call
+    )
+    return moved["kept"], moved["resolved"]
+
+
+def assert_columns_are_a_fresh_lowerings(inst, area):
+    kept, fresh = inst._spf_lowerings[area.area_id], LoweredLsdbV3()
+    fresh._refresh(area.lsdb)
+    for name in ("_kind", "_vid", "_n_links", "_links", "_link_off"):
+        a, b = getattr(kept, name), getattr(fresh, name)
+        assert a.dtype == b.dtype and np.array_equal(a, b), name
+    assert len(kept._bodies) == len(fresh._bodies)
+    for a, b in zip(kept._bodies, fresh._bodies):
+        # the LSA's body, or for an Intra-Area-Prefix LSA its pair
+        assert a is b or (a[0] == b[0] and a[1] is b[1])
+    assert all(map(lambda a, b: a is b, kept.entries, fresh.entries))
+
+
+def _router(area, rid, links, now, seq, lsid=0, age=1):
+    area.lsdb.install(
+        _lsa(P.LsaType.ROUTER, lsid, rid, P.LsaRouterV3(links=list(links)),
+             age=age, seq=seq),
+        now,
+    )
+
+
+class Walk:
+    """A seeded walk over one area's LSDB: every kind of change the
+    kept lowering has a path for, one a step."""
+
+    def __init__(self, seed: int):
+        self.inst, self.area, self.rids, self.links = build(seed, lan=True)
+        self.rng = np.random.default_rng(seed)
+        self.seq = 10
+        self.gone: dict = {}  # adjacencies taken away: (ifname, rid) -> nbr
+        self.others: list = []  # keys of the entries the graph ignores
+        self.new = 0
+        # never replaced by the walk: the root, and the two DRs
+        self.fixed = {ROOT, self.rids[4]} | {
+            r for r in self.rids[9:] if int(r) >= 1 << 31
+        }
+
+    def now(self):
+        return self.inst.loop.clock.now()
+
+    def pick(self):
+        live = [
+            r for r in self.links
+            if r not in self.fixed and P.LsaKey(
+                P.LsaType.ROUTER, IPv4Address(0), r
+            ) in self.area.lsdb.entries
+        ]
+        return live[int(self.rng.integers(len(live)))]
+
+    def install(self, rid, **kw):
+        self.seq += 1
+        _router(self.area, rid, self.links[rid], self.now(), self.seq, **kw)
+
+    # the steps
+
+    def link_less(self):
+        rid = self.pick()
+        if len(self.links[rid]) > 1:
+            self.links[rid].pop(int(self.rng.integers(len(self.links[rid]))))
+        self.install(rid)
+
+    def cost_more(self):
+        rid = self.pick()
+        i = int(self.rng.integers(len(self.links[rid])))
+        l = self.links[rid][i]
+        self.links[rid][i] = P.RouterLinkV3(
+            l.link_type, l.metric + 1, l.iface_id, l.nbr_iface_id,
+            l.nbr_router_id,
+        )
+        self.install(rid)
+
+    def other_comes_or_goes(self):
+        # an Inter-Area-Prefix LSA: installed (a new key at the end, a
+        # known one replaced in the middle) or removed from the middle
+        if self.others and self.rng.random() < 0.5:
+            key = self.others.pop(int(self.rng.integers(len(self.others))))
+            self.area.lsdb.remove(key)
+            return
+        lsid = int(self.rng.integers(100, 110))
+        self.seq += 1
+        lsa = _lsa(
+            P.LsaType.INTER_AREA_PREFIX, lsid, self.rids[2],
+            P.LsaInterAreaPrefix(
+                metric=lsid, prefix=IPv6Network((0x2001 << 112 | lsid << 80, 48))
+            ), seq=self.seq,
+        )
+        self.area.lsdb.install(lsa, self.now())
+        if lsa.key not in self.others:
+            self.others.append(lsa.key)
+
+    def router_goes(self):
+        rid = self.pick()
+        self.area.lsdb.remove(P.LsaKey(P.LsaType.ROUTER, IPv4Address(0), rid))
+        self.area.lsdb.remove(
+            P.LsaKey(P.LsaType.INTRA_AREA_PREFIX, IPv4Address(1), rid)
+        )
+
+    def router_comes(self):
+        self.new += 1
+        rid, peer = IPv4Address((77 << 24) + self.new), self.pick()
+        self.links[rid] = [_p2p(peer, 2, 1, 900 + self.new)]
+        self.links[peer].append(_p2p(rid, 3, 900 + self.new, 1))
+        self.install(rid)
+        self.install(peer)
+
+    def second_router_lsa(self):
+        rid = self.pick()
+        self.seq += 1
+        _router(
+            self.area, rid, self.links[rid][:1], self.now(), self.seq,
+            lsid=int(self.rng.integers(1, 4)),
+        )
+
+    def network_changes(self):
+        nets = [
+            e for e in self.area.lsdb.entries.values()
+            if e.lsa.type == P.LsaType.NETWORK
+        ]
+        lsa = nets[int(self.rng.integers(len(nets)))].lsa
+        attached = list(lsa.body.attached)
+        if len(attached) > 2 and self.rng.random() < 0.5:
+            attached.pop()
+        else:
+            attached.append(self.pick())
+        self.seq += 1
+        self.area.lsdb.install(_lsa(
+            P.LsaType.NETWORK, int(lsa.lsid), lsa.adv_rtr,
+            P.LsaNetworkV3(attached=attached), seq=self.seq,
+        ), self.now())
+
+    def max_age_by_the_clock(self):
+        rid = self.pick()
+        self.install(rid, age=P.MAX_AGE - 5)
+        st = assert_same(self.inst, self.area)
+        assert ("R", rid) in st.index
+        self.inst.loop.advance(10.0)
+
+    def adjacency_goes_or_comes(self):
+        if self.gone and self.rng.random() < 0.5:
+            (ifname, rid), nbr = self.gone.popitem()
+            self.inst.interfaces[ifname].neighbors[rid] = nbr
+            return
+        held = [
+            (name, rid) for name, iface in self.inst.interfaces.items()
+            for rid in iface.neighbors
+        ]
+        if held:
+            name, rid = held[int(self.rng.integers(len(held)))]
+            self.gone[(name, rid)] = (
+                self.inst.interfaces[name].neighbors.pop(rid)
+            )
+
+    STEPS = (
+        link_less, cost_more, other_comes_or_goes, router_goes, router_comes,
+        second_router_lsa, network_changes, max_age_by_the_clock,
+        adjacency_goes_or_comes,
+    )
+    # the steps that keep the vertex model come most often, as they do
+    WEIGHTS = (5, 5, 5, 1, 1, 1, 3, 1, 2)
+
+
+@pytest.mark.parametrize("seed", [11, 12])
+def test_a_walk_of_changes_equals_the_old_body_and_a_fresh_lowering(seed):
+    walk = Walk(seed)
+    assert_same(walk.inst, walk.area)
+    weights = np.array(Walk.WEIGHTS) / sum(Walk.WEIGHTS)
+    taken, prev = set(), None
+    kept_before = _counted("holo_ospf_topology_rows_total", ("kept",))
+    for _ in range(220):
+        step = Walk.STEPS[int(walk.rng.choice(len(Walk.STEPS), p=weights))]
+        taken.add(step.__name__)
+        step(walk)
+        frozen = prev and {
+            f: getattr(prev.topo, f).tobytes() for f in TOPO_FIELDS
+        }
+        st = assert_same(walk.inst, walk.area)
+        assert st is not None
+        assert_columns_are_a_fresh_lowerings(walk.inst, walk.area)
+        if prev is not None and prev is not st:
+            # nothing handed out is written again
+            assert frozen == {
+                f: getattr(prev.topo, f).tobytes() for f in TOPO_FIELDS
+            }
+        prev = st
+    assert taken == {s.__name__ for s in Walk.STEPS}
+    # and the walk went by difference: rows were kept
+    kept_after = _counted("holo_ospf_topology_rows_total", ("kept",))
+    assert kept_after["kept"] > kept_before["kept"]
+
+
+def test_a_removal_lowers_nothing_and_an_append_only_what_came():
+    inst, area, rids, links = build(6, lan=True)
+    now = inst.loop.clock.now
+    n = len(area.lsdb.entries)
+
+    def call():
+        assert_same(inst, area)
+
+    # (the oracle's own walk and the first lowering)
+    assert _lsas_moved(lambda: inst._area_marshal(area)) == (n, 0)
+    assert _lsas_moved(lambda: inst._area_marshal(area)) == (0, n)
+    # a Router-LSA from the middle, outright
+    area.lsdb.remove(P.LsaKey(P.LsaType.ROUTER, IPv4Address(0), rids[20]))
+    assert _lsas_moved(call) == (0, n - 1)
+    assert_columns_are_a_fresh_lowerings(inst, area)
+    # an entry the graph ignores from the middle: nothing either
+    area.lsdb.remove(
+        P.LsaKey(P.LsaType.INTER_AREA_PREFIX, IPv4Address(9), rids[2])
+    )
+    assert _lsas_moved(call) == (0, n - 2)
+    # an append lowers what was appended
+    new = IPv4Address("10.7.7.7")
+    _router(area, new, [_p2p(rids[12], 2, 1, 99)], now(), 2)
+    assert _lsas_moved(call) == (1, n - 2)
+    # a removal, a replacement behind it and an append in one call,
+    # the length what it was
+    area.lsdb.remove(P.LsaKey(P.LsaType.ROUTER, IPv4Address(0), rids[5]))
+    _router(area, rids[30], links[rids[30]][:-1], now(), 3)
+    _router(area, IPv4Address("10.7.7.8"), [_p2p(new, 1)], now(), 2)
+    assert _lsas_moved(call) == (2, n - 3)
+    assert_columns_are_a_fresh_lowerings(inst, area)
+    # removed and installed again: gone from its place, new at the end
+    key = P.LsaKey(P.LsaType.ROUTER, IPv4Address(0), rids[22])
+    area.lsdb.remove(key)
+    _router(area, rids[22], links[rids[22]], now(), 4)
+    assert _lsas_moved(call) == (1, n - 2)
+    assert_columns_are_a_fresh_lowerings(inst, area)
+
+
+def test_entries_in_another_order_are_lowered_from_the_first_difference():
+    """Not a dict's doing, but any way of writing ``lsdb.entries`` is
+    seen: what stayed in another order is lowered again."""
+    inst, area, _rids, _links = build(6)
+    assert_same(inst, area)
+    items = list(area.lsdb.entries.items())
+    n = len(items)
+    items[3], items[9] = items[9], items[3]
+    area.lsdb.entries = dict(items)
+    assert _lsas_moved(lambda: assert_same(inst, area)) == (n - 3, 3)
+    assert_columns_are_a_fresh_lowerings(inst, area)
+
+
+def _flap(area, links, a, seq, now):
+    """Both ends of ``a``'s first link re-originate without it."""
+    b = links[a][0].nbr_router_id
+    for r, other in ((a, b), (b, a)):
+        links[r] = [l for l in links[r] if l.nbr_router_id != other]
+        _router(area, r, links[r], now, seq)
+    return a, b
+
+
+def test_one_flap_resolves_the_replaced_rows_and_keeps_the_rest(monkeypatch):
+    inst, area, rids, links = build(8, n=400)
+    inst._area_marshal(area)
+    lowering = inst._spf_lowerings[AREA]
+    total = len(lowering._rows.dst)
+    assert total >= 1000
+    a, b = _flap(area, links, rids[200], 2, inst.loop.clock.now())
+    # the rows of the two LSAs, and the rows that end at their routers
+    replaced = len(links[a]) + len(links[b])
+    sizes = []
+    for name in ("lookup_sorted", "mutual_keep_mask"):
+        real = getattr(spf_run, name)
+        monkeypatch.setattr(
+            spf_run, name,
+            lambda x, y, real=real, name=name: (
+                sizes.append((name, len(y))) or real(x, y)
+            ),
+        )
+    kept, resolved = _rows_moved(lambda: assert_same(inst, area))
+    assert {name for name, _n in sizes} == {"lookup_sorted", "mutual_keep_mask"}
+    assert max(n for _name, n in sizes) <= 4 * (replaced + 2)
+    assert resolved <= 4 * (replaced + 2) and kept == total - 2 - resolved
+    # a whole assembly (another vertex model) resolves every row
+    _router(area, IPv4Address("10.7.7.7"), [_p2p(rids[12], 2, 1, 99)],
+            inst.loop.clock.now(), 2)
+    sizes.clear()
+    kept, resolved = _rows_moved(lambda: assert_same(inst, area))
+    assert kept == 0 and resolved == total - 2 + 1
+    assert max(n for _name, n in sizes) >= resolved - 1
+
+
+def test_the_previous_topology_is_never_written_and_the_delta_is_a_fresh_ones():
+    inst, area, rids, links = build(9, n=120, lan=True)
+    prev = inst._area_marshal(area)
+    frozen = {f: getattr(prev.topo, f).tobytes() for f in TOPO_FIELDS}
+    _flap(area, links, rids[60], 2, inst.loop.clock.now())
+    r = rids[70]
+    l = links[r][0]
+    links[r][0] = P.RouterLinkV3(
+        l.link_type, l.metric + 3, l.iface_id, l.nbr_iface_id, l.nbr_router_id
+    )
+    _router(area, r, links[r], inst.loop.clock.now(), 2)
+    st = assert_same(inst, area)
+    assert st is not prev
+    assert {f: getattr(prev.topo, f).tobytes() for f in TOPO_FIELDS} == frozen
+    for f in TOPO_FIELDS:
+        assert not np.shares_memory(getattr(prev.topo, f), getattr(st.topo, f))
+    kept_rows = inst._spf_lowerings[AREA]._rows
+    for column in (kept_rows.src, kept_rows.dst, kept_rows.cost,
+                   kept_rows.mutual):
+        for f in TOPO_FIELDS:
+            assert not np.shares_memory(column, getattr(st.topo, f))
+    assert link_spf_delta(prev, st)
+    # two fresh assemblies of the same two LSDBs
+    inst2, area2, rids2, links2 = build(9, n=120, lan=True)
+    prev2 = inst2._area_marshal(area2)
+    del inst2._spf_lowerings[AREA]
+    _flap(area2, links2, rids2[60], 2, inst2.loop.clock.now())
+    links2[r][0] = links[r][0]
+    _router(area2, r, links2[r], inst2.loop.clock.now(), 2)
+    st2 = inst2._area_marshal(area2)
+    assert link_spf_delta(prev2, st2)
+    got, want = st.topo.delta_base, st2.topo.delta_base
+    assert got.n_ops == want.n_ops > 0 and got.kind == want.kind
+    for field in dataclasses.fields(got):
+        if field.name != "base_key":
+            a, b = getattr(got, field.name), getattr(want, field.name)
+            assert np.array_equal(a, b), field.name
