@@ -43,11 +43,11 @@ from holo_tpu.protocols.ospf.lsdb import (
 )
 from holo_tpu.protocols.ospf.spf_run import (
     KeptDerive,
+    KeptInter,
     LoweredLsdbV3,
     NexthopAtom,
     SpfDelayFsm,
     SpfTimers,
-    aggregate_area_ranges,
     atom_bits,
     legacy_spf_timers,
     link_spf_delta,
@@ -283,10 +283,19 @@ class OspfV3Instance(SpfDelayFsm, Actor):
         # run are lowered again).
         self._spf_delta_bases: dict = {}
         self._spf_lowerings: dict = {}
-        # Per area: what its intra-area table advertises into other
-        # areas (ranges applied), kept while the table object is the
-        # last run's.
-        self._advert_cache: dict = {}
+        # The ABR's inter-area stage by difference: what each area's
+        # intra-area table advertises into the others (ranges applied),
+        # the summaries wanted and our LSAs of them, between runs.
+        self._inter_kept = KeptInter(
+            (P.LsaType.INTER_AREA_ROUTER, P.LsaType.INTER_AREA_PREFIX),
+            IPv6Network("::/0"),
+            P.LsaType.ROUTER,
+            self._asbr_of,
+            self._asbr_distance,
+            self._inter_key,
+            self._inter_originate,
+            lambda aid, key: self._flush_self(self.areas[aid], key),
+        )
         self._active_ranges: set = set()
         # An area whose SPF inputs did not change since the last full
         # run keeps its result and its intra-area table and is not
@@ -1772,7 +1781,7 @@ class OspfV3Instance(SpfDelayFsm, Actor):
                     )
 
         with profiling.stage("ospf.spf", "inter"):
-            adverts = self._area_adverts(intra_by_area)
+            self._area_adverts(intra_by_area)
             # 2. inter-area routes from received Inter-Area-Prefix LSAs:
             #    distance = dist(advertising ABR in that area) + metric.
             #    The candidate table covers EVERY advertised prefix
@@ -1794,9 +1803,7 @@ class OspfV3Instance(SpfDelayFsm, Actor):
             #    intra prefixes, ranges applied, into every other area;
             #    default into stub areas).
             if self.is_abr:
-                self._originate_inter_area(
-                    adverts, inter_routes, area_results
-                )
+                self._originate_inter_area(inter_routes, area_results)
 
             self.spf_log.append(
                 {
@@ -2271,10 +2278,9 @@ class OspfV3Instance(SpfDelayFsm, Actor):
                     routes[prefix] = inter_routes[prefix]
             external |= {p for p in intra_set if p not in routes}
             origination_dirty = True
-            # The tables were edited in place: what they advertise has
-            # to be computed again.
-            self._advert_cache.clear()
-        adverts = self._area_adverts(intra_by_area)
+        # (the tables were edited in place: the adverts follow them
+        # prefix by prefix)
+        self._area_adverts(intra_by_area)
 
         if inter_network:
             for prefix in inter_network:
@@ -2313,7 +2319,7 @@ class OspfV3Instance(SpfDelayFsm, Actor):
             )
 
         if origination_dirty and self.is_abr:
-            self._originate_inter_area(adverts, inter_routes, area_results)
+            self._originate_inter_area(inter_routes, area_results)
 
         log_type = (
             "intra" if intra_set
@@ -2338,185 +2344,81 @@ class OspfV3Instance(SpfDelayFsm, Actor):
         with profiling.stage("ospf.spf", "publish"):
             self._publish(routes, area_results)
 
-    def _nh_areas_of(self):
-        """``route -> frozenset of the areas its next hops exit
-        through``, one look at the interfaces per distinct next-hop set."""
-        memo: dict = {}
-        interfaces = self.interfaces
-
-        def nh_areas(route) -> frozenset:
-            areas = memo.get(route.nexthops)
-            if areas is None:
-                areas = memo[route.nexthops] = frozenset(
-                    interfaces[ifname].config.area_id
-                    for ifname, _addr in route.nexthops
-                    if ifname in interfaces
-                )
-            return areas
-
-        return nh_areas
-
-    def _area_adverts(self, intra_by_area: dict) -> dict:
-        """Per area what its intra-area table advertises into the other
-        areas, ``{prefix: (dist, prefix options, exit areas)}``: every
-        prefix as it is, or, under the area's address ranges (RFC 2328
-        §12.4.3), a range at its largest component's distance for its
-        components.  ``exit areas`` are those the next hops (of an
-        aggregate: of any component) leave through: the split horizon.
-        Sets ``_active_ranges``.  An area's entry is kept while its
-        table is the object it was computed from."""
-        nh_areas = self._nh_areas_of()
-        out: dict = {}
-        active: set = set()
-        for aid, intra in intra_by_area.items():
-            area = self.areas.get(aid)
-            if area is None:
-                continue
-            stamp = tuple(
-                (r["prefix"], r.get("advertise", True), r.get("cost"))
-                for r in area.ranges
-            )
-            kept = self._advert_cache.get(aid)
-            if kept is None or kept[0] is not intra or kept[1] != stamp:
-                eff, range_nh_areas, area_active = aggregate_area_ranges(
-                    intra, area.ranges, nh_areas
-                )
-                advert = {}
-                for prefix, dist in eff.items():
-                    exits = range_nh_areas.get(prefix)
-                    if exits is not None:
-                        advert[prefix] = (dist, 0, frozenset(exits))
-                    else:
-                        r = intra[prefix]
-                        advert[prefix] = (
-                            dist, r.prefix_options, nh_areas(r)
-                        )
-                kept = self._advert_cache[aid] = (
-                    intra, stamp, advert, area_active
-                )
-            out[aid] = kept[2]
-            active |= kept[3]
-        for aid in list(self._advert_cache):
-            if aid not in out:
-                del self._advert_cache[aid]
-        self._active_ranges = active
-        return out
+    def _area_adverts(self, intra_by_area: dict) -> None:
+        """Bring what each area's intra-area table advertises into the
+        other areas up to the run's tables (``KeptInter.adverts``: every
+        prefix as it is or, under the area's address ranges, RFC 2328
+        §12.4.3, a range at its largest component's distance), and set
+        ``_active_ranges``."""
+        tables = {
+            aid: intra for aid, intra in intra_by_area.items()
+            if aid in self.areas
+        }
+        self._active_ranges = self._inter_kept.adverts(
+            tables,
+            {aid: self.areas[aid].ranges for aid in tables},
+            {name: i.config.area_id for name, i in self.interfaces.items()},
+        )
 
     def _originate_inter_area(
-        self, adverts: dict, inter_routes: dict, area_results: dict
+        self, inter_routes: dict, area_results: dict
     ) -> None:
-        backbone = IPv4Address(0)
-        wanted: dict[IPv4Address, dict] = {aid: {} for aid in self.areas}
-        nh_areas = self._nh_areas_of()
-        # The reference walks the final RIB (area.rs:602-643): intra
-        # routes summarize everywhere, inter routes into non-backbone
-        # areas only; a route never returns to its own area, nor (split
-        # horizon, area.rs:628-630) into an area its next hops already
-        # exit through.  candidates: prefix -> (dist, prefix options,
-        # source area, is intra, exit areas).
-        candidates: dict = {}
-        for src_aid, advert in adverts.items():
-            for prefix, (dist, popts, exits) in advert.items():
-                cur = candidates.get(prefix)
-                if cur is None or dist < cur[0]:
-                    candidates[prefix] = (dist, popts, src_aid, True, exits)
-        for prefix, route in inter_routes.items():
-            if prefix not in candidates:  # intra always wins
-                candidates[prefix] = (
-                    route.dist, route.prefix_options, route.area_id,
-                    False, nh_areas(route),
+        """ABR duties (reference area.rs:602-643): our intra-area routes
+        summarized everywhere, inter-area routes into the non-backbone
+        areas only, never back into their own area nor (split horizon,
+        area.rs:628-630) into an area their next hops exit through; the
+        default into stub areas; the ASBRs each area reaches into the
+        others; what is no longer wanted flushed."""
+        self._inter_kept.originate(
+            inter_routes,
+            {
+                aid: out for aid, out in area_results.items()
+                if aid in self.areas
+            },
+            {
+                aid: (
+                    area.summary,
+                    area.stub_default_cost if area.stub else None,
+                    area.no_external,
                 )
-        for prefix, (dist, popts, src_aid, intra, exits) in (
-            candidates.items()
+                for aid, area in self.areas.items()
+            },
+            {aid: area.lsdb for aid, area in self.areas.items()},
+            self.router_id,
+        )
+
+    def _asbr_of(self, e):
+        """The router id a Router-LSA's entry names as an ASBR (live,
+        another router's, the E bit set), else None."""
+        lsa = e.lsa
+        if (
+            lsa.is_maxage or P.RouterFlags.E not in lsa.body.flags
+            or lsa.adv_rtr == self.router_id
         ):
-            for dst_aid in self.areas:
-                if src_aid == dst_aid:
-                    continue
-                if not intra and dst_aid == backbone:
-                    continue  # only intra advertises into the backbone
-                if not self.areas[dst_aid].summary:
-                    continue  # totally stubby: default only
-                if dst_aid in exits:
-                    continue
-                cur = wanted[dst_aid].get(prefix)
-                if cur is None or dist < cur[0]:
-                    wanted[dst_aid][prefix] = (dist, popts)
-        default = IPv6Network("::/0")
-        for aid, area in self.areas.items():
-            if area.stub:
-                wanted[aid][default] = (area.stub_default_cost, 0)
-        # ASBR reachability into other areas (Inter-Area-Router LSAs).
-        asbr_wanted: dict[IPv4Address, dict] = {aid: {} for aid in self.areas}
-        for src_aid, (index, keys, res, atoms, _pl) in area_results.items():
-            src_area = self.areas.get(src_aid)
-            if src_area is None:
-                continue
-            for e in src_area.lsdb.all():
-                if e.lsa.type != P.LsaType.ROUTER or e.lsa.is_maxage:
-                    continue
-                if P.RouterFlags.E not in e.lsa.body.flags:
-                    continue
-                if e.lsa.adv_rtr == self.router_id:
-                    continue
-                v = index.get(("R", e.lsa.adv_rtr))
-                if v is None or res.dist[v] >= INF:
-                    continue
-                for dst_aid in self.areas:
-                    if dst_aid == src_aid or self.areas[dst_aid].no_external:
-                        continue
-                    cur = asbr_wanted[dst_aid].get(e.lsa.adv_rtr)
-                    if cur is None or int(res.dist[v]) < cur:
-                        asbr_wanted[dst_aid][e.lsa.adv_rtr] = int(res.dist[v])
-        # Our own LSAs per area, found once for both flush passes.
-        rid = self.router_id
-        own = {
-            aid: [k for k in area.lsdb.entries if k.adv_rtr == rid]
-            for aid, area in self.areas.items()
-        }
-        for aid, asbrs in asbr_wanted.items():
-            area = self.areas[aid]
-            wanted_lsids = set()
-            for rid, dist in asbrs.items():
-                lsid = self._inter_lsid(aid, ("asbr", rid))
-                wanted_lsids.add(lsid)
-                self._originate(
-                    area,
-                    P.LsaType.INTER_AREA_ROUTER,
-                    lsid,
-                    P.LsaInterAreaRouter(metric=dist, dest_router_id=rid),
-                )
-            for key in own[aid]:
-                if (
-                    key.type == P.LsaType.INTER_AREA_ROUTER
-                    and key.lsid not in wanted_lsids
-                ):
-                    e = area.lsdb.entries.get(key)
-                    if e is not None and not e.lsa.is_maxage:
-                        self._flush_self(area, key)
-        for aid, prefixes in wanted.items():
-            area = self.areas[aid]
-            wanted_lsids = set()
-            for prefix, (dist, popts) in prefixes.items():
-                lsid = self._inter_lsid(aid, prefix)
-                wanted_lsids.add(lsid)
-                self._originate(
-                    area,
-                    P.LsaType.INTER_AREA_PREFIX,
-                    lsid,
-                    P.LsaInterAreaPrefix(
-                        metric=dist, prefix=prefix, prefix_options=popts
-                    ),
-                )
-            for key in own[aid]:
-                if (
-                    key.type == P.LsaType.INTER_AREA_PREFIX
-                    and key.lsid not in wanted_lsids
-                ):
-                    # .get: a flush above may have swept drained MaxAge
-                    # entries out of the snapshot already (§14 sweep).
-                    e = area.lsdb.entries.get(key)
-                    if e is not None and not e.lsa.is_maxage:
-                        self._flush_self(area, key)
+            return None
+        return lsa.adv_rtr
+
+    @staticmethod
+    def _asbr_distance(out, rid):
+        index, _keys, res, _atoms, _pl = out
+        v = index.get(("R", rid))
+        if v is None or res.dist[v] >= INF:
+            return None
+        return int(res.dist[v])
+
+    def _inter_key(self, aid, kind, dest) -> P.LsaKey:
+        if kind == P.LsaType.INTER_AREA_ROUTER:
+            dest = ("asbr", dest)
+        return P.LsaKey(kind, self._inter_lsid(aid, dest), self.router_id)
+
+    def _inter_originate(self, aid, key, dest, value) -> None:
+        if key.type == P.LsaType.INTER_AREA_ROUTER:
+            body = P.LsaInterAreaRouter(metric=value, dest_router_id=dest)
+        else:
+            body = P.LsaInterAreaPrefix(
+                metric=value[0], prefix=dest, prefix_options=value[1]
+            )
+        self._originate(self.areas[aid], key.type, key.lsid, body)
 
     def _asbr_via_inter_router(self, area, index, res, atoms, asbr_rid):
         """(dist, nexthops) toward an out-of-area ASBR via the best ABR's

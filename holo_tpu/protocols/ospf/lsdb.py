@@ -47,6 +47,12 @@ class Lsdb:
     entries: dict[LsaKey, LsaEntry] = field(default_factory=dict)
     # Pending (delayed) originations blocked by MinLSInterval.
     pending: dict[LsaKey, Lsa] = field(default_factory=dict)
+    # Kept by ``install`` and ``remove`` (an edit of ``entries`` around
+    # them is not seen), each with a count of the installs and removals
+    # it saw: LSA type -> [count, {key: entry}], and (advertising router,
+    # LSA type) -> [count, {key: None}], in LSDB order.
+    _types: dict = field(default_factory=dict, repr=False, compare=False)
+    _origins: dict = field(default_factory=dict, repr=False, compare=False)
 
     def get(self, key: LsaKey) -> LsaEntry | None:
         return self.entries.get(key)
@@ -61,7 +67,8 @@ class Lsdb:
         differ, or MaxAge transition — the trigger condition for SPF
         (lsdb.rs:457-469).
         """
-        old = self.entries.get(lsa.key)
+        key = lsa.key
+        old = self.entries.get(key)
         changed = True
         if old is not None:
             old_lsa = old.lsa
@@ -73,11 +80,46 @@ class Lsdb:
         entry = LsaEntry(lsa=lsa, installed_at=now, rcvd_time=now)
         if old is not None:
             entry.last_originated = old.last_originated
-        self.entries[lsa.key] = entry
+        self.entries[key] = entry
+        of_type = self._types.get(key.type)
+        if of_type is None:
+            of_type = self._types[key.type] = [0, {}]
+        of_type[0] += 1
+        of_type[1][key] = entry
+        origin = self._origins.get((key.adv_rtr, key.type))
+        if origin is None:
+            origin = self._origins[(key.adv_rtr, key.type)] = [0, {}]
+        origin[0] += 1
+        if old is None:
+            origin[1][key] = None
         return entry, changed
 
     def remove(self, key: LsaKey) -> None:
-        self.entries.pop(key, None)
+        if self.entries.pop(key, None) is not None:
+            for index, at in (
+                (self._types, key.type),
+                (self._origins, (key.adv_rtr, key.type)),
+            ):
+                kept = index.get(at)
+                if kept is not None:
+                    kept[0] += 1
+                    kept[1].pop(key, None)
+
+    def of_type(self, ltype) -> tuple[int, dict]:
+        """The LSAs of one type: how many times one of them was
+        installed or removed (while the count stays, none of them moved)
+        and ``{key: entry}`` in LSDB order (the index itself: copy it to
+        keep it)."""
+        count, entries = self._types.get(ltype, (0, {}))
+        return count, entries
+
+    def originated(self, adv_rtr: IPv4Address, ltype) -> tuple[int, dict]:
+        """The LSAs of one type that ``adv_rtr`` originated: how many
+        times one of them was installed or removed (while the count
+        stays, none of them moved) and their keys in LSDB order (the
+        index itself: copy it to keep it)."""
+        count, keys = self._origins.get((adv_rtr, ltype), (0, {}))
+        return count, keys
 
     def maxage_keys(self, now: float) -> list[LsaKey]:
         return [

@@ -1606,6 +1606,561 @@ class KeptDerive:
         return dict(table)
 
 
+# ===== Inter-area summaries by difference ===============================
+
+_INTER_ADVERTS = telemetry.counter(
+    "holo_ospf_inter_adverts_total",
+    "KeptInter's advert entries (what an area's intra-area routes "
+    "advertise into the other areas: a prefix under no range, or an "
+    "active range) by how a run had them: kept (no route they are made "
+    "from is another object), or rebuilt (the entry of a prefix whose "
+    "route came, went or is another object, of a range with such a "
+    "component, and every entry of a whole recompute)",
+    ("path",),
+)
+
+_INTER_ORIGINATIONS = telemetry.counter(
+    "holo_ospf_inter_originations_total",
+    "KeptInter's wanted summaries (prefix and router) by whether the "
+    "run called their origination: skipped (we want what we last "
+    "originated, and our LSA of it is the entry that origination left), "
+    "or called (every summary of a whole stage)",
+    ("path",),
+)
+
+#: next-hop sets kept with the areas they exit through, while the
+#: interfaces stay in their areas
+_EXITS_MAX = 4096
+
+
+def _ranges_stamp(ranges: list) -> tuple:
+    return tuple(
+        (r["prefix"], r.get("advertise", True), r.get("cost"))
+        for r in ranges
+    )
+
+
+class _AreaAdvert:
+    """One area's advert as :func:`aggregate_area_ranges` makes it,
+    kept between runs: a copy of the table it was made from (a partial
+    run edits the table in place), per range the components, and the
+    entries ``{prefix: (dist, prefix options, exit areas)}``."""
+
+    def __init__(self, ranges: list) -> None:
+        self.stamp = _ranges_stamp(ranges)
+        # range key -> the first range of that key, and the last's cost
+        self.index: dict = {}
+        self.cost: dict = {}
+        self.place: dict = {}  # a range's prefix -> its place in ranges
+        for at, r in enumerate(ranges):
+            key = _range_key(r["prefix"])
+            self.index.setdefault(key, r)
+            self.cost[key] = r.get("cost")
+            self.place.setdefault(r["prefix"], at)
+        self.lengths = sorted({k[0] for k in self.index}, reverse=True)
+        self.table: dict = {}
+        self.comps: dict = {}  # range key -> {prefix: (dist, exit areas)}
+        self.advert: dict = {}
+        self.active: set = set()  # the prefixes of the active ranges
+        self._at: dict | None = None  # prefix -> its place in the table
+        self._under: dict = {}  # prefix -> `_range_of` it
+
+    def _range_of(self, prefix):
+        """The key of the most specific range over ``prefix``, or None."""
+        try:
+            return self._under[prefix]
+        except KeyError:
+            pass
+        key = None
+        plen, bits = prefix.prefixlen, prefix.max_prefixlen
+        net = int(prefix.network_address)
+        for length in self.lengths:
+            probe = (length, net >> (bits - length))
+            if length <= plen and probe in self.index:
+                key = probe
+                break
+        self._under[prefix] = key
+        return key
+
+    def at(self, prefix) -> int:
+        """``prefix``'s place in the table."""
+        if self._at is None:
+            self._at = {p: i for i, p in enumerate(self.table)}
+        return self._at[prefix]
+
+    def follow(self, table: dict, exits) -> tuple[set, int]:
+        """Bring the advert up to ``table``, told from the last one by
+        route identity, prefix for prefix.  Returns the prefixes whose
+        entry came, went or changed, and how many entries were made
+        again."""
+        old = self.table
+        if len(old) == len(table) and all(map(operator.is_, old, table)):
+            moved = list(itertools.compress(
+                table.items(),
+                map(operator.is_not, old.values(), table.values()),
+            ))
+            if not moved:
+                return set(), 0
+        else:
+            moved = [(p, r) for p, r in table.items() if old.get(p) is not r]
+            moved += [(p, None) for p in old if p not in table]
+        self.table, self._at = dict(table), None
+        advert, changed, made = self.advert, set(), 0
+
+        def settle(p, entry) -> None:
+            if advert.get(p) != entry:
+                if entry is None:
+                    del advert[p]
+                else:
+                    advert[p] = entry
+                changed.add(p)
+
+        ranges: set = set()
+        for p, r in moved:
+            key = self._range_of(p)
+            if key is not None:
+                comps = self.comps.setdefault(key, {})
+                if r is not None:
+                    comps[p] = (r.dist, exits(r.nexthops))
+                else:
+                    comps.pop(p, None)
+                ranges.add(key)
+                continue
+            entry = None
+            if r is not None:
+                entry = (r.dist, r.prefix_options, exits(r.nexthops))
+                made += 1
+            settle(p, entry)
+        # A range at its largest advertised component's distance (or its
+        # cost) for the areas any component exits through; the ranges in
+        # their order, as a whole advert lists them after the prefixes.
+        index, place = self.index, self.place
+        for key in sorted(ranges, key=lambda k: place[index[k]["prefix"]]):
+            first = index[key]
+            p, comps, entry = first["prefix"], self.comps[key], None
+            if not comps:
+                del self.comps[key]
+                self.active.discard(p)
+            else:
+                self.active.add(p)
+                if first.get("advertise", True):
+                    cost = self.cost[key]
+                    entry = (
+                        max(map(operator.itemgetter(0), comps.values()))
+                        if cost is None else cost,
+                        0,
+                        frozenset().union(
+                            *{*map(operator.itemgetter(1), comps.values())}
+                        ),
+                    )
+                    made += 1
+            settle(p, entry)
+        return changed, made
+
+
+class KeptInter:
+    """An ABR's inter-area stage by difference between SPF runs (RFC
+    2328 §12.4.3 and §16.2 (3), RFC 5340 §4.4.3.4): what each area's
+    intra-area routes advertise (:class:`_AreaAdvert`), the summaries
+    wanted in each area, and the originations and flushes that bring
+    our summary LSAs to them, each computed again only where an input
+    that moved can change it.  The originations and flushes it calls
+    are those of the whole stage, in the same order; the rest the whole
+    stage calls would find the LSA as it is and return.
+
+    What keys it: an area's advert follows its intra-area table route
+    by route (identity); a candidate (the best advert of a prefix, or
+    its inter-area route where no area advertises it) and the wanted
+    entries made from it are computed again for a prefix whose advert
+    entry or inter-area route moved; a destination area's entries all
+    again where its configuration moved; an area's ASBRs are read again
+    from the Router-LSA entries that are not the last run's objects
+    where the LSDB counted an install or removal of a Router-LSA, and
+    their distances where the area's SPF result is another object; an
+    origination is called where the wanted value is not the one last
+    originated, or where the LSDB counted an install or removal of our
+    summaries of that kind since the last stage and the entry is not
+    the one the last origination left; a flush where a summary is no
+    longer wanted, or, after such a count, where the LSDB's index of
+    our summaries holds one nothing wants.
+
+    Whole (nothing kept, today's stage: every wanted summary originated,
+    our LSAs found by a walk over each LSDB) on the first call, and where
+    the interfaces moved between areas, the advertising areas or the
+    destination areas are others or in another order, an LSDB is
+    another object, or the router id moved; an area's advert is made
+    whole where its ranges moved.
+
+    ``kinds``: the LSA types of a router summary and a prefix summary;
+    ``default``: the default route, wanted in a stub area at its cost;
+    ``routers``: the Router-LSA's type; ``asbr_of(entry)``: the ASBR a
+    Router-LSA's entry names, or None;
+    ``distance(result, router id)``: its distance in an area's SPF
+    result, or None where unreached; ``key_of(area, kind,
+    dest)``: our summary's LSDB key (its id made on first use, so called
+    in the whole stage's order); ``originate(area, key, dest, value)``
+    and ``flush(area, key)`` do what the instance does with them."""
+
+    def __init__(
+        self, kinds, default, routers, asbr_of, distance, key_of,
+        originate, flush,
+    ):
+        self._kinds = kinds
+        self._default = default
+        self._routers = routers
+        self._asbr_of, self._distance = asbr_of, distance
+        self._key_of, self._originate, self._flush = key_of, originate, flush
+        self.reset()
+
+    def reset(self) -> None:
+        """Drop what is kept: the next calls are the whole stage."""
+        self._ifaces: dict | None = None  # interface name -> its area
+        self._exits: dict = {}  # next-hop set -> the areas it exits through
+        self._areas: dict = {}  # advertising area -> _AreaAdvert, in order
+        self._moved: set = set()  # advert entries moved since `originate`
+        self._frame: tuple = ()  # (destinations, LSDBs, router id)
+        self._cand: dict | None = None  # prefix -> candidate; None: whole
+        self._inter: dict = {}  # prefix -> its inter-area route's entry
+        self._inter_at: dict | None = None  # prefix -> its place in _inter
+        self._dsts: dict = {}  # area -> its configuration
+        self._wanted: dict = {}  # area -> {prefix: (dist, options)}
+        self._contrib: dict = {}  # area -> see `_reached`
+        self._asbrs: dict = {}  # area -> {router id: dist}
+        self._left: dict = {}  # (area, kind) -> {dest: (key, entry, value)}
+        self._marks: dict = {}  # (area, kind) -> the LSDB's count after us
+
+    def _exits_of(self, nexthops) -> frozenset:
+        areas = self._exits.get(nexthops)
+        if areas is None:
+            ifaces = self._ifaces
+            areas = self._exits[nexthops] = frozenset(
+                ifaces[name] for name, _addr in nexthops if name in ifaces
+            )
+        return areas
+
+    def adverts(self, tables: dict, ranges: dict, ifaces: dict) -> set:
+        """Bring each area's advert up to its intra-area table.
+        ``tables``: ``{area: {prefix: route}}`` in the order the areas
+        advertise; ``ranges``: ``{area: [range]}``; ``ifaces``:
+        ``{interface name: its area}``, what a next hop exits through.
+        Returns the prefixes of every area's active ranges."""
+        if (
+            ifaces != self._ifaces
+            or len(tables) != len(self._areas)
+            or any(a != b for a, b in zip(tables, self._areas))
+        ):
+            self.reset()
+            self._ifaces = dict(ifaces)
+        if len(self._exits) > _EXITS_MAX:
+            self._exits = {}
+        kept = made = 0
+        for aid, table in tables.items():
+            st = self._areas.get(aid)
+            if st is None or st.stamp != _ranges_stamp(ranges[aid]):
+                if st is not None:
+                    self._moved |= st.advert.keys()
+                st = self._areas[aid] = _AreaAdvert(ranges[aid])
+                self._moved |= st.follow(table, self._exits_of)[0]
+                made += len(st.advert)
+                continue
+            changed, n = st.follow(table, self._exits_of)
+            self._moved |= changed
+            made += n
+            kept += len(st.advert) - n
+        _INTER_ADVERTS.labels(path="kept").inc(kept)
+        _INTER_ADVERTS.labels(path="rebuilt").inc(made)
+        return set().union(*[st.active for st in self._areas.values()])
+
+    def _candidate(self, prefix):
+        """``(dist, options, source area, intra, exit areas)``: the
+        best advert of ``prefix`` (the first of equal ones), else its
+        inter-area route, else None."""
+        best = None
+        for aid, st in self._areas.items():
+            e = st.advert.get(prefix)
+            if e is not None and (best is None or e[0] < best[0]):
+                best = (e[0], e[1], aid, True, e[2])
+        if best is None:
+            e = self._inter.get(prefix)
+            if e is not None:
+                best = (e[0], e[1], e[2], False, e[3])
+        return best
+
+    @staticmethod
+    def _want(cand, aid, conf):
+        """What ``cand`` makes wanted in area ``aid``: an intra-area
+        candidate everywhere, an inter-area one into the non-backbone
+        areas only; never into its own area nor (split horizon) into an
+        area its next hops exit through; nothing into a totally stubby
+        area."""
+        if cand is None:
+            return None
+        dist, opts, src, intra, exits = cand
+        if (
+            src == aid or (not intra and int(aid) == 0) or not conf[0]
+            or aid in exits
+        ):
+            return None
+        return (dist, opts)
+
+    def _want_in(self, prefix, cand, aid, conf):
+        """``_want``, and the default route into a stub area at its cost."""
+        if conf[1] is not None and prefix == self._default:
+            return (conf[1], 0)
+        return self._want(cand, aid, conf)
+
+    def _whole(self, inter_routes: dict, dsts: dict) -> None:
+        cand: dict = {}
+        for src, st in self._areas.items():
+            for prefix, (dist, opts, exits) in st.advert.items():
+                cur = cand.get(prefix)
+                if cur is None or dist < cur[0]:
+                    cand[prefix] = (dist, opts, src, True, exits)
+        self._inter, self._inter_at = {}, None
+        for prefix, r in inter_routes.items():
+            e = self._inter[prefix] = (
+                r.dist, r.prefix_options, r.area_id,
+                self._exits_of(r.nexthops),
+            )
+            if prefix not in cand:  # intra always wins
+                cand[prefix] = (e[0], e[1], e[2], False, e[3])
+        wanted: dict = {aid: {} for aid in dsts}
+        for prefix, c in cand.items():
+            for aid, conf in dsts.items():
+                v = self._want(c, aid, conf)
+                if v is not None:
+                    wanted[aid][prefix] = v
+        for aid, conf in dsts.items():
+            if conf[1] is not None:
+                wanted[aid][self._default] = (conf[1], 0)
+        self._cand, self._wanted = cand, wanted
+
+    def _by_difference(self, inter_routes: dict, dsts: dict) -> dict:
+        """Candidates and wanted entries again for what moved.
+        Returns ``{area: prefixes whose wanted entry moved}``."""
+        moved, self._moved = self._moved, set()
+        old = self._inter
+        if len(old) == len(inter_routes) and all(
+            map(operator.is_, old, inter_routes)
+        ):
+            for prefix, was, r in zip(
+                inter_routes, list(old.values()), inter_routes.values()
+            ):
+                e = (r.dist, r.prefix_options, r.area_id,
+                     self._exits_of(r.nexthops))
+                if e != was:
+                    old[prefix] = e
+                    moved.add(prefix)
+        else:
+            self._inter_at = None
+            self._inter = {
+                prefix: (r.dist, r.prefix_options, r.area_id,
+                         self._exits_of(r.nexthops))
+                for prefix, r in inter_routes.items()
+            }
+            moved |= {p for p, e in self._inter.items() if old.get(p) != e}
+            moved |= old.keys() - self._inter.keys()
+        cand, wanted = self._cand, self._wanted
+        changed: dict = {aid: set() for aid in dsts}
+
+        def settle(prefix, c, aid, conf):
+            v = self._want_in(prefix, c, aid, conf)
+            w = wanted[aid]
+            if w.get(prefix) != v:
+                if v is None:
+                    del w[prefix]
+                else:
+                    w[prefix] = v
+                changed[aid].add(prefix)
+
+        for prefix in moved:
+            c = self._candidate(prefix)
+            if c == cand.get(prefix):
+                continue
+            if c is None:
+                del cand[prefix]
+            else:
+                cand[prefix] = c
+            for aid, conf in dsts.items():
+                settle(prefix, c, aid, conf)
+        for aid, conf in dsts.items():
+            if conf != self._dsts[aid]:
+                for prefix in [*wanted[aid], *cand, self._default]:
+                    settle(prefix, cand.get(prefix), aid, conf)
+        return changed
+
+    def _rank(self, prefix, aid, conf) -> tuple:
+        """Where ``prefix`` stands in area ``aid``'s wanted entries as the
+        whole stage lists them: in the candidates' order (the advertising
+        areas in order, in each its prefixes in table order, then its
+        ranges in theirs; then the inter-area routes in theirs), a
+        default route that is no candidate there last."""
+        if conf[1] is not None and prefix == self._default and self._want(
+            self._cand.get(prefix), aid, conf
+        ) is None:
+            return (2, 0)
+        for i, st in enumerate(self._areas.values()):
+            if prefix in st.advert:
+                at = st.place.get(prefix)
+                if at is not None:
+                    return (0, i, 1, at)
+                return (0, i, 0, st.at(prefix))
+        if self._inter_at is None:
+            self._inter_at = {p: i for i, p in enumerate(self._inter)}
+        return (1, self._inter_at[prefix])
+
+    def _reached(self, kept, db, out) -> tuple:
+        """One area's ASBRs and their distances: ``(the LSDB's count of
+        its Router-LSAs, their entries, per entry the ASBR it names or
+        None, the ASBRs in LSDB order, the result, {router id: dist})``.
+        The entries are read again where the count moved, told from
+        ``kept``'s by identity (:func:`_aligned_runs`); the distances
+        where the result is another object."""
+        count, routers = db.of_type(self._routers)
+        if kept is None or kept[0] != count:
+            cur = list(routers.values())
+            entries, named = kept[1:3] if kept is not None else ([], [])
+            parts, at = [], 0
+            for (lo, hi), come in zip(*_aligned_runs(entries, cur)):
+                parts += named[at:lo]
+                parts += map(self._asbr_of, come)
+                at = hi
+            named = parts + named[at:]
+            kept = (count, cur, named, [r for r in named if r is not None])
+        if len(kept) == 4 or kept[4] is not out:
+            reached: dict = {}
+            for rid in kept[3]:
+                if rid not in reached:
+                    dist = self._distance(out, rid)
+                    if dist is not None:
+                        reached[rid] = dist
+            kept = (*kept[:4], out, reached)
+        return kept
+
+    def _asbrs_of(self, results: dict, dsts: dict, lsdbs: dict) -> dict:
+        """``{area: {ASBR router id: dist}}`` wanted in each area: what
+        the other areas reach, at the least distance, in the order the
+        whole stage meets them."""
+        contrib = {
+            aid: self._reached(self._contrib.get(aid), lsdbs[aid], out)
+            for aid, out in results.items()
+        }
+        self._contrib = contrib
+        asbrs: dict = {aid: {} for aid in dsts}
+        for src, (*_kept, reached) in contrib.items():
+            for rid, dist in reached.items():
+                for aid, conf in dsts.items():
+                    if aid == src or conf[2]:
+                        continue
+                    cur = asbrs[aid].get(rid)
+                    if cur is None or dist < cur:
+                        asbrs[aid][rid] = dist
+        return asbrs
+
+    def originate(
+        self, inter_routes: dict, results: dict, dsts: dict, lsdbs: dict,
+        router_id,
+    ) -> None:
+        """Originate and flush our summaries in every area, as the
+        whole stage does.  ``inter_routes``: ``{prefix: route}``;
+        ``results``: ``{area: SPF result}`` in the areas' order;
+        ``dsts``: ``{area: (summary, the default's cost where the area is
+        stub else None, no external)}`` in the areas' order; ``lsdbs``:
+        ``{area: Lsdb}``."""
+        frame = (tuple(dsts), router_id)
+        whole = (
+            self._cand is None or frame != self._frame[:2]
+            or not all(map(operator.is_, lsdbs.values(), self._frame[2:]))
+        )
+        if whole:
+            self._whole(inter_routes, dsts)
+            self._moved = set()
+            changed = None
+        else:
+            changed = self._by_difference(inter_routes, dsts)
+        self._frame = (*frame, *lsdbs.values())
+        self._dsts = dict(dsts)
+        asbrs = self._asbrs_of(results, dsts, lsdbs)
+        router, prefix_kind = self._kinds
+        # What the LSDBs hold of ours as the stage begins: where one
+        # counted an install or removal of our summaries since the last
+        # stage, its index of them (the whole stage: a walk over it).
+        start: dict = {}
+        for aid, db in lsdbs.items():
+            if whole:
+                own = [k for k in db.entries if k.adv_rtr == router_id]
+            for kind in self._kinds:
+                count, keys = db.originated(router_id, kind)
+                if whole:
+                    start[aid, kind] = own
+                elif count != self._marks.get((aid, kind)):
+                    start[aid, kind] = list(keys)
+        called = total = 0
+        for kind in self._kinds:
+            for aid, conf in dsts.items():
+                db = lsdbs[aid]
+                want = asbrs[aid] if kind == router else self._wanted[aid]
+                left = {} if whole else self._left.get((aid, kind), {})
+                snapshot = start.get((aid, kind))
+                if whole:
+                    calls, gone = list(want), []
+                else:
+                    if kind == router:
+                        moved = {
+                            d for d, v in want.items()
+                            if d not in left or left[d][2] != v
+                        }
+                        gone = [d for d in left if d not in want]
+                    else:
+                        moved = {d for d in changed[aid] if d in want}
+                        gone = [
+                            d for d in changed[aid]
+                            if d not in want and d in left
+                        ]
+                    if snapshot is not None:
+                        moved |= {
+                            d for d, (key, entry, _v) in left.items()
+                            if d in want and db.entries.get(key) is not entry
+                        }
+                    if kind == router:
+                        calls = [d for d in want if d in moved]
+                    else:
+                        calls = sorted(
+                            moved,
+                            key=lambda d: self._rank(d, aid, conf),
+                        )
+                for dest in calls:
+                    key = self._key_of(aid, kind, dest)
+                    self._originate(aid, key, dest, want[dest])
+                    left[dest] = (key, db.entries.get(key), want[dest])
+                called += len(calls)
+                total += len(want)
+                if snapshot is not None:
+                    lsids = {left[d][0].lsid for d in want}
+                    flush = [
+                        k for k in snapshot
+                        if k.type == kind and k.lsid not in lsids
+                    ]
+                else:
+                    flush = [left[d][0] for d in gone]
+                    if len(flush) > 1:  # in LSDB order
+                        ours = set(flush)
+                        _count, keys = db.originated(router_id, kind)
+                        flush = [k for k in keys if k in ours]
+                for key in flush:
+                    e = db.entries.get(key)
+                    if e is not None and not e.lsa.is_maxage:
+                        self._flush(aid, key)
+                for dest in gone:
+                    del left[dest]
+                self._left[aid, kind] = left
+        for aid, db in lsdbs.items():
+            for kind in self._kinds:
+                self._marks[aid, kind] = db.originated(router_id, kind)[0]
+        _INTER_ORIGINATIONS.labels(path="called").inc(called)
+        _INTER_ORIGINATIONS.labels(path="skipped").inc(total - called)
+
+
 def build_topology(
     lsdb: Lsdb,
     router_id: IPv4Address,
