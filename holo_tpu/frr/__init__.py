@@ -5,10 +5,11 @@ primary SPF the protocol layer hands its :class:`~holo_tpu.ops.graph.Topology`
 to an :class:`~holo_tpu.frr.manager.FrrEngine`, which runs ONE batched device
 dispatch computing
 
-1. the all-roots distance matrix (one-to-all SPF from every LSDB vertex —
-   the multi-root workload ``spf_multiroot`` was built for),
-2. per protected link, the post-convergence SPF (what-if batch with the
-   link's edges masked), and
+1. the distance rows and reversed-graph columns the selection reads —
+   from the root, its first routers, the far ends, the PQ nodes and the
+   TI-LFA successors — in blocks of fixed width (no all-roots matrix),
+2. per protected link, the post-convergence SPF (the protected edge's
+   slot masked), and
 3. the vectorized RFC 5286 LFA inequalities, RFC 7490 remote-LFA P/Q-space
    intersection, and TI-LFA P/Q repair-segment selection over those
    distance planes.
@@ -29,20 +30,18 @@ flood-and-SPF reconvergence replaces the repair with the new primaries.
 from holo_tpu.frr.inputs import FrrInputs, marshal_frr
 from holo_tpu.frr.kernel import BackupTable
 from holo_tpu.frr.manager import (
-    BackupEntry,
     FrrConfig,
     FrrEngine,
-    repair_map,
-    resolve_backup,
+    repair_picks,
+    repair_plan,
 )
 
 __all__ = [
-    "BackupEntry",
     "BackupTable",
     "FrrConfig",
     "FrrEngine",
     "FrrInputs",
     "marshal_frr",
-    "repair_map",
-    "resolve_backup",
+    "repair_picks",
+    "repair_plan",
 ]

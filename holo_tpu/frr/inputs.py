@@ -59,6 +59,15 @@ class FrrInputs:
     adj_atom: np.ndarray  # int32[Ap] direct next-hop atom id
     adj_valid: np.ndarray  # bool[Ap]
     adj_srlg: np.ndarray  # uint32[Ap] SRLG bitmask of the candidate edge
+    # Direct next-hop atom of the protected edge (-1: none; -1 pad): with
+    # the source and cost it finds the edge's slot in the resident graph.
+    link_atom: np.ndarray  # int32[Lp]
+    # Sources of the kernel's distance rows (pads: n_vertices).  Forward
+    # base rows: the root first, then every router one hop or one
+    # network from it (the release neighbours and LFA candidates) and
+    # every far end; reversed-graph rows: the root, then far end l at 1 + l.
+    base_src: np.ndarray  # int32[Bb]
+    rev_src: np.ndarray  # int32[round_up(1 + Lp)]
     n_links: int  # unpadded L
     n_adj: int  # unpadded A
     # next-hop atom id -> protected link index (which interface an
@@ -70,40 +79,36 @@ class FrrInputs:
         return (
             self.link_valid.shape[0],
             self.adj_valid.shape[0],
-            self.edge_masks.shape[1],
+            self.base_src.shape[0],
         )
 
 
 def marshal_frr(topo: Topology, pad_multiple: int = 8) -> FrrInputs:
     """Build the padded FRR tables for ``topo.root``."""
     root = int(topo.root)
+    n = int(topo.n_vertices)
     e_src = topo.edge_src
     e_dst = topo.edge_dst
     e_cost = topo.edge_cost
     atom = topo.edge_direct_atom
     is_router = topo.is_router
+    srlg = topo.edge_srlg
     n_edges = topo.n_edges
 
-    pair_of: dict[tuple[int, int], int] = {}
-    for e in range(n_edges):
-        pair_of.setdefault((int(e_src[e]), int(e_dst[e])), e)
-
     # Protected links: root out-edges, in edge order.
-    link_edge: list[int] = [
-        e for e in range(n_edges) if int(e_src[e]) == root
-    ]
-    link_of_edge = {e: l for l, e in enumerate(link_edge)}
+    link_edge = np.flatnonzero(e_src == root)
     nlinks = len(link_edge)
+    into_root = np.flatnonzero(e_dst == root)
 
     masks = np.ones((nlinks, n_edges), bool)
-    for l, e in enumerate(link_edge):
+    for l, e in enumerate(link_edge.tolist()):
         masks[l, e] = False
-        rev = pair_of.get((int(e_dst[e]), int(e_src[e])))
-        if rev is not None:
-            masks[l, rev] = False
+        rev = into_root[e_src[into_root] == e_dst[e]]
+        if len(rev):
+            masks[l, int(rev[0])] = False
 
-    # Repair candidates + atom→link map.
-    srlg = topo.edge_srlg
+    # Repair candidates + atom→link map; the first routers (one hop or
+    # one network from the root) are the forward base rows' sources.
     adj_edge: list[int] = []
     adj_nbr: list[int] = []
     adj_cost: list[int] = []
@@ -111,11 +116,13 @@ def marshal_frr(topo: Topology, pad_multiple: int = 8) -> FrrInputs:
     adj_atom: list[int] = []
     adj_srlg: list[int] = []
     atom_link: dict[int, int] = {}
-    for l, e in enumerate(link_edge):
+    first: set[int] = set()
+    for l, e in enumerate(link_edge.tolist()):
         far = int(e_dst[e])
         if int(atom[e]) >= 0:
             atom_link.setdefault(int(atom[e]), l)
         if is_router[far]:
+            first.add(far)
             if int(atom[e]) >= 0:
                 adj_edge.append(e)
                 adj_nbr.append(far)
@@ -126,11 +133,12 @@ def marshal_frr(topo: Topology, pad_multiple: int = 8) -> FrrInputs:
         else:
             # LAN: members reachable through this interface are candidates
             # (and their atoms ride this link for the failure fanout).
-            for e2 in range(n_edges):
-                if int(e_src[e2]) != far or int(atom[e2]) < 0:
-                    continue
+            for e2 in np.flatnonzero(e_src == far).tolist():
                 member = int(e_dst[e2])
                 if member == root or not is_router[member]:
+                    continue
+                first.add(member)
+                if int(atom[e2]) < 0:
                     continue
                 atom_link.setdefault(int(atom[e2]), l)
                 adj_edge.append(e2)
@@ -164,14 +172,17 @@ def marshal_frr(topo: Topology, pad_multiple: int = 8) -> FrrInputs:
     # SPF, and every output row is masked by link_valid anyway.
     masks_p = np.ones((lp, n_edges), bool)
     masks_p[:nlinks] = masks
+    fars = [int(e_dst[e]) for e in link_edge.tolist()]
+    base = [root, *sorted((first | set(fars)) - {root})]
+    nbase = max(_round_up(1 + lp + ap, 8), _round_up(len(base), 8))
 
     return FrrInputs(
         link_edge=pad_i32(link_edge, lp, -1),
-        link_far=pad_i32([int(e_dst[e]) for e in link_edge], lp, 0),
-        link_cost=pad_i32([int(e_cost[e]) for e in link_edge], lp, 1),
+        link_far=pad_i32(fars, lp, 0),
+        link_cost=pad_i32(e_cost[link_edge], lp, 1),
         link_valid=link_valid,
         edge_masks=masks_p,
-        link_srlg=pad_u32([int(srlg[e]) for e in link_edge], lp),
+        link_srlg=pad_u32(srlg[link_edge], lp),
         adj_edge=pad_i32(adj_edge, ap, -1),
         adj_nbr=pad_i32(adj_nbr, ap, 0),
         adj_cost=pad_i32(adj_cost, ap, 1),
@@ -179,7 +190,67 @@ def marshal_frr(topo: Topology, pad_multiple: int = 8) -> FrrInputs:
         adj_atom=pad_i32(adj_atom, ap, -1),
         adj_valid=adj_valid,
         adj_srlg=pad_u32(adj_srlg, ap),
+        link_atom=pad_i32(atom[link_edge], lp, -1),
+        base_src=pad_i32(base, nbase, n),
+        rev_src=pad_i32([root, *fars], _round_up(1 + lp, 8), n),
         n_links=nlinks,
         n_adj=nadj,
         atom_link=atom_link,
+    )
+
+
+#: in-edges of every vertex in the first plane of a :class:`RowsGraph`
+ROWS_K_LO = 8
+
+
+def rows_graph_shape(topo: Topology, k_lo: int = ROWS_K_LO) -> tuple[int, int]:
+    """(heavy vertices, their extra in-edges) the two directions of
+    ``topo`` need beyond ``k_lo`` slots a vertex."""
+    n = int(topo.n_vertices)
+    need_h, need_k = 0, 0
+    for row in (topo.edge_dst, topo.edge_src):
+        counts = np.bincount(row, minlength=n)
+        heavy = counts > k_lo
+        need_h = max(need_h, int(heavy.sum()))
+        need_k = max(need_k, int(counts.max(initial=0)) - k_lo)
+    return need_h, need_k
+
+
+def rows_graphs(
+    topo: Topology, h_pad: int, k_hi: int, k_lo: int = ROWS_K_LO
+) -> tuple[tuple, tuple]:
+    """The forward and the reversed :class:`~holo_tpu.frr.kernel.RowsGraph`
+    planes of ``topo`` as numpy tuples ``(lo_src, lo_cost, hi_vtx,
+    hi_src, hi_cost)``: a vertex's first ``k_lo`` in-edges (forward) or
+    out-edges (reversed) in the low plane, the rest, for the at most
+    ``h_pad`` vertices that have more, in rows of ``k_hi``.  An empty
+    slot's source, and a pad row's vertex, is ``n_vertices``."""
+    n = int(topo.n_vertices)
+
+    def planes(row, other):
+        order = np.argsort(row, kind="stable")
+        r, o, c = row[order], other[order], topo.edge_cost[order]
+        rank = np.arange(len(r)) - np.searchsorted(r, r, side="left")
+        lo_src = np.full((n, k_lo), n, np.int32)
+        lo_cost = np.zeros((n, k_lo), np.int32)
+        low = rank < k_lo
+        lo_src[r[low], rank[low]] = o[low]
+        lo_cost[r[low], rank[low]] = c[low]
+        heavy = np.flatnonzero(np.bincount(row, minlength=n) > k_lo)
+        if len(heavy) > h_pad:
+            raise ValueError(f"{len(heavy)} heavy vertices, {h_pad} rows")
+        hi_vtx = np.full(h_pad, n, np.int32)
+        hi_vtx[: len(heavy)] = heavy
+        at = np.full(n, -1, np.int64)
+        at[heavy] = np.arange(len(heavy))
+        hi_src = np.full((h_pad, k_hi), n, np.int32)
+        hi_cost = np.zeros((h_pad, k_hi), np.int32)
+        high = ~low
+        hi_src[at[r[high]], rank[high] - k_lo] = o[high]
+        hi_cost[at[r[high]], rank[high] - k_lo] = c[high]
+        return lo_src, lo_cost, hi_vtx, hi_src, hi_cost
+
+    return (
+        planes(topo.edge_dst, topo.edge_src),
+        planes(topo.edge_src, topo.edge_dst),
     )

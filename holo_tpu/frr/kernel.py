@@ -1,12 +1,29 @@
-"""Batched FRR kernel: all-roots SPF + vectorized LFA/rLFA/TI-LFA selection.
+"""Batched FRR kernel: the distance rows the selection reads, in blocks,
+and vectorized LFA/rLFA/TI-LFA selection over them.
 
 One jitted device program per (N, K, L, A) shape bucket computes
 
-1. ``D`` — the all-roots distance matrix int32[N, N], a single vmapped
-   dispatch of the lean distance relaxation (``sssp_distances``) over
-   every vertex (no per-root Python loop);
-2. the post-convergence SPF per protected link (``spf_whatif_batch``
-   over the per-link failure masks — dist/parent/next-hop planes);
+1. the **distance rows the selection reads**, and no others, each as a
+   column of an ``int32[N, B]`` plane relaxed to its fixpoint in blocks
+   of fixed width (:func:`rows_block`, over the compact
+   :class:`RowsGraph` the engine builds from the topology's edges):
+
+   - forward rows ``D[x, :]`` for the *base* sources: the root, every
+     router the root reaches in one hop or through one attached
+     network (the release neighbours ``n1`` of TI-LFA and the LFA
+     candidates are among them) and every protected link's far end;
+   - reversed-graph rows, i.e. columns ``D[:, t]``, for ``t`` = the root
+     and every far end (costs may differ by direction, so a column is
+     a run of its own on the reversed edges);
+   - forward rows for the vertices known only after selection — each
+     link's remote-LFA PQ node and every distinct TI-LFA successor
+     ``S`` — in a loop of ``DYN_BLOCK``-wide blocks whose count the
+     device decides (one compiled program whatever the count);
+
+2. the post-convergence SPF per protected link: the resident graph with
+   the slot that holds the protected edge masked (an edge INTO the
+   root never carries a path from it, so the reverse direction needs
+   no mask);
 3. the repair selection tables (all int32[L, N], ``-1`` = none):
 
    - **LFA** (RFC 5286): candidate ``a`` protects ``(l, d)`` iff it does
@@ -28,17 +45,21 @@ One jitted device program per (N, K, L, A) shape bucket computes
      two-segment repair is emitted only when normal forwarding from
      ``Q`` cannot loop back (``D[q, d] < D[q, root] + D[root, d]`` —
      sufficient here because every failure plane cuts through the
-     root).  The per-destination P/S/release values propagate down the
-     post SPT Jacobi-style: one gather per round, vmap-friendly, no
-     host walk.
+     root).  Release neighbour, P and S are each a nearest ancestor of
+     a kind on the post SPT's router tree, found by pointer doubling:
+     log2(depth) gathers, no host walk.
 
 All comparisons are exact int32 with INF-guarded sums (finite operands
 are < 2**30, so a single sum cannot wrap).  Every table is bit-compared
 against :mod:`holo_tpu.frr.scalar` in tests/test_frr_parity.py.
 
-Memory note: the LFA stage materializes [L, A, N] bool intermediates and
-``D`` is [N, N] int32 — size the batch like the 10k what-if batch, not
-the 50k single-SPF path.
+The program also returns ``stats`` (int32[len(STATS)]): the rows it
+relaxed by direction, the relaxation rounds summed over its blocks,
+each block's valid rows times its rounds (the reads a roofline counts),
+the distinct successors and dynamic blocks, and two guards that must
+read 0 (a protected edge not found in the resident graph, a release
+neighbour without a base row) — the engine refuses a table with either
+set, and the breaker serves the oracle.
 """
 
 from __future__ import annotations
@@ -52,11 +73,41 @@ import numpy as np
 
 from holo_tpu.frr.inputs import FrrInputs
 from holo_tpu.ops.graph import INF
-from holo_tpu.ops.spf_engine import (
-    DeviceGraph,
-    spf_whatif_batch,
-    sssp_distances,
+from holo_tpu.ops.spf_engine import DeviceGraph, spf_one
+
+#: width of one block of the rows known only on the device (PQ, S)
+DYN_BLOCK = 128
+#: names of the ``stats`` vector's entries, in order
+STATS = (
+    "rows_forward",
+    "rows_reverse",
+    "rounds",
+    "row_rounds_forward",
+    "row_rounds_reverse",
+    "successors",
+    "dyn_blocks",
+    "unmatched_links",
+    "missing_rows",
 )
+
+
+class RowsGraph(NamedTuple):
+    """One direction of the graph for the distance rows, in two ELL
+    planes: the first ``Klo`` in-edges of every vertex, and the rest of
+    the in-edges of the few vertices with more (``hi_vtx``; pad rows
+    carry ``N``).  An empty slot's source is ``N``, the row of the
+    relaxation plane that stays INF.  Built on the host from the
+    topology's edge list (:func:`holo_tpu.frr.inputs.rows_graph`)."""
+
+    lo_src: jax.Array  # int32[N, Klo]
+    lo_cost: jax.Array  # int32[N, Klo]
+    hi_vtx: jax.Array  # int32[H]
+    hi_src: jax.Array  # int32[H, Khi]
+    hi_cost: jax.Array  # int32[H, Khi]
+
+    @property
+    def slots(self) -> int:
+        return int(self.lo_src.size + self.hi_src.size)
 
 
 class FrrTensors(NamedTuple):
@@ -69,6 +120,7 @@ class FrrTensors(NamedTuple):
     tilfa_q: jax.Array  # int32[L, N] Q vertex or -1 (single-segment)
     post_dist: jax.Array  # int32[L, N]
     post_nh: jax.Array  # uint32[L, N, W] post-convergence atom words
+    stats: jax.Array  # int32[len(STATS)]
 
 
 def _fadd(a, b):
@@ -76,13 +128,86 @@ def _fadd(a, b):
     return jnp.where((a < INF) & (b < INF), a + b, INF)
 
 
+def rows_block(rg: RowsGraph, srcs: jax.Array, limit: int):
+    """Distances from each of ``srcs`` (int32[B]; a value outside
+    ``[0, N)`` is no source and its column stays INF) as the columns of
+    an int32[N, B] plane, relaxed until no entry moves.  Returns the
+    plane and the rounds it took.  The gathers share their indices
+    across the block (``dist[src]`` of whole rows), the layout the
+    what-if batch runs fast in."""
+    n = rg.lo_src.shape[0]
+    b = srcs.shape[0]
+    cols = jnp.arange(b)
+    start = jnp.where((srcs >= 0) & (srcs < n), srcs, n + 1)
+    dist0 = (
+        jnp.full((n + 1, b), INF, jnp.int32)
+        .at[start, cols]
+        .set(0, mode="drop")
+    )
+
+    def relax(dist, src, cost):
+        d = dist[src]  # [rows, slots, B]
+        return jnp.where(d < INF, d + cost[:, :, None], INF).min(axis=1)
+
+    def cond(carry):
+        _, changed, it = carry
+        return changed & (it < limit)
+
+    def body(carry):
+        dist, _, it = carry
+        cand = relax(dist, rg.lo_src, rg.lo_cost)
+        cand = cand.at[rg.hi_vtx].min(
+            relax(dist, rg.hi_src, rg.hi_cost), mode="drop"
+        )
+        new = jnp.minimum(dist[:n], cand)
+        changed = jnp.any(new != dist[:n])
+        return dist.at[:n].set(new), changed, it + 1
+
+    dist, _, rounds = jax.lax.while_loop(
+        cond, body, (dist0, jnp.bool_(True), jnp.int32(0))
+    )
+    return dist[:n], rounds
+
+
+def _link_slots(g: DeviceGraph, root, link_far, link_cost, link_atom, link_valid):
+    """(row, col) of the resident slot holding each protected edge
+    ``root -> far`` (the first slot with that source, cost and direct
+    atom: slots alike in all three are the same edge to the SPF), N for
+    a pad link; and the count of valid links with no such slot."""
+    n, k = g.in_src.shape
+    far = jnp.clip(link_far, 0, n - 1)
+    src = g.in_src[far]  # [L, K]
+    words = g.direct_nh_words[far]  # [L, K, W]
+    atom = link_atom[:, None]
+    word = jnp.take_along_axis(
+        words, jnp.clip(atom >> 5, 0, words.shape[2] - 1)[:, :, None], axis=2
+    )[:, :, 0]
+    bit = (word >> (jnp.clip(atom, 0, None) & 31).astype(jnp.uint32)) & 1
+    atom_ok = jnp.where(atom >= 0, bit == 1, (words == 0).all(axis=2))
+    match = (
+        g.in_valid[far]
+        & (src == root)
+        & (g.in_cost[far] == link_cost[:, None])
+        & atom_ok
+    )
+    found = match.any(axis=1) & link_valid
+    col = jnp.argmax(match, axis=1).astype(jnp.int32)
+    row = jnp.where(found, link_far, n).astype(jnp.int32)
+    unmatched = (link_valid & ~found).sum().astype(jnp.int32)
+    return row, col, unmatched
+
+
 def frr_batch(
     g: DeviceGraph,
     root,
+    fwd: RowsGraph,
+    rev: RowsGraph,
+    base_src: jax.Array,
+    rev_src: jax.Array,
     link_far: jax.Array,
     link_cost: jax.Array,
+    link_atom: jax.Array,
     link_valid: jax.Array,
-    edge_masks: jax.Array,
     adj_nbr: jax.Array,
     adj_cost: jax.Array,
     adj_link: jax.Array,
@@ -92,7 +217,11 @@ def frr_batch(
     require_np: jax.Array | bool = False,
     max_iters: int | None = None,
 ) -> FrrTensors:
-    """``link_srlg``/``adj_srlg`` (uint32 SRLG bitmasks, ISSUE 10): a
+    """``base_src``: the forward base sources (root first; pads outside
+    ``[0, N)``), ``rev_src``: the reversed-graph sources (root, then the
+    far end of link ``l`` at ``1 + l``).
+
+    ``link_srlg``/``adj_srlg`` (uint32 SRLG bitmasks): a
     repair candidate sharing ANY risk group with the protected link is
     excluded from the usable plane — all-zero planes (the default, and
     the disarmed policy) exclude nothing, so the mask costs one
@@ -103,19 +232,40 @@ def frr_batch(
     n = g.in_src.shape[0]
     nlinks = link_far.shape[0]
     nadj = adj_nbr.shape[0]
+    nbase = base_src.shape[0]
     vidx = jnp.arange(n)
+    limit = n if max_iters is None else max_iters
 
-    # ---- 1. all-roots distance matrix: ONE vmapped dispatch.
-    D = jax.vmap(lambda r: sssp_distances(g, r, None, max_iters))(vidx)
+    # ---- 1. base forward rows and reversed-graph columns.
+    rows_f, rounds_f = rows_block(fwd, base_src, limit)  # [N, Bb]
+    rows_r, rounds_r = rows_block(rev, rev_src, limit)  # [N, Br]
+    base_ok = (base_src >= 0) & (base_src < n)
+    col_of = (
+        jnp.full((n,), nbase, jnp.int32)
+        .at[jnp.where(base_ok, base_src, n)]
+        .set(jnp.arange(nbase, dtype=jnp.int32), mode="drop")
+    )
+
+    def base_rows(xs):
+        """D[xs, :] as [len(xs), N] (xs must be base sources)."""
+        return rows_f[:, jnp.clip(col_of[xs], 0, nbase - 1)].T
 
     # ---- 2. post-convergence SPF per protected link (one batch).
-    post = spf_whatif_batch(g, root, edge_masks, max_iters)
+    link_row, link_col, unmatched = _link_slots(
+        g, root, link_far, link_cost, link_atom, link_valid
+    )
+    post = jax.vmap(
+        lambda r, c: spf_one(
+            g._replace(in_valid=g.in_valid.at[r, c].set(False, mode="drop")),
+            root, None, max_iters,
+        )
+    )(link_row, link_col)
 
-    droot = D[root]  # int32[N] primary distances
+    droot = rows_f[:, 0]  # int32[N] primary distances
     valid_d = (droot < INF) & (vidx != root)  # destinations worth protecting
 
     # ---- 3a. LFA inequalities + lexicographic selection.
-    dn = D[adj_nbr]  # [A, N] from each candidate neighbor
+    dn = base_rows(adj_nbr)  # [A, N] from each candidate neighbor
     dn_root = dn[:, root]  # [A]
     loopfree = adj_valid[:, None] & (
         dn < _fadd(dn_root[:, None], droot[None, :])
@@ -130,8 +280,8 @@ def frr_batch(
         usable = usable & (
             (link_srlg[:, None] & adj_srlg[None, :]) == jnp.uint32(0)
         )
-    dfar = D[link_far]  # [L, N]
-    dn_far = dn[:, link_far].T  # [L, A]: D[nbr_a, far_l]
+    dfar = base_rows(link_far)  # [L, N]
+    dn_far = dn[:, jnp.clip(link_far, 0, n - 1)].T  # [L, A]: D[nbr_a, far_l]
     nodeprot = dn[None, :, :] < _fadd(
         dn_far[:, :, None], dfar[:, None, :]
     )  # [L, A, N]
@@ -160,87 +310,63 @@ def frr_batch(
     pspace = droot[None, :] < _fadd(link_cost[:, None], dfar)  # [L, N]
     ext_any = (usable[:, :, None] & loopfree[None, :, :]).any(axis=1)
     extp = (pspace | ext_any) & link_valid[:, None]
-    dto_far = D[:, link_far].T  # [L, N]: D[v, far_l]
-    dto_root = D[:, root]  # [N]
+    dto_far = rows_r[:, 1 : 1 + nlinks].T  # [L, N]: D[v, far_l]
+    dto_root = rows_r[:, 0]  # [N]: D[v, root]
     qspace = dto_far < _fadd(dto_root[None, :], link_cost[:, None])
     pq_cand = extp & qspace & g.is_router[None, :] & (vidx != root)[None, :]
     kq = jnp.where(pq_cand, droot[None, :], INF)
     mq = kq.min(axis=1)  # [L]
     vq = jnp.where(pq_cand & (kq == mq[:, None]), vidx[None, :], n).min(axis=1)
     pq = jnp.where(mq < INF, vq, -1).astype(jnp.int32)  # [L]
-    pqc = jnp.clip(pq, 0, n - 1)
-    dpq = D[pqc]  # [L, N]
-    rlfa_ok = (
-        (pq >= 0)[:, None]
-        & (dpq < _fadd(dpq[:, root][:, None], droot[None, :]))
-        & valid_d[None, :]
-    )
-    rlfa_pq = jnp.where(rlfa_ok, pq[:, None], -1).astype(jnp.int32)
 
     # ---- 3c. TI-LFA: release-neighbor (n1) + last-loop-free-router (P)
-    # + successor (S) propagated down the post SPT.
-    par = post.parent  # [L, N], n = no parent
-    parc = jnp.clip(par, 0, n - 1)
-    has_par = par < n
+    # + successor (S) along the post SPT, by pointer doubling over the
+    # router tree (a router's router parent skips the one network a path
+    # may cross): each value is a nearest ancestor of a kind, found in
+    # log2(depth) gathers instead of one propagation round per hop.
+    has_par = post.parent < n  # [L, N], n = no parent
+    par = jnp.where(has_par, post.parent, n).astype(jnp.int32)
     is_rtr = g.is_router
-    limit = (2 * n + 4) if max_iters is None else (2 * max_iters + 4)
+    isr = is_rtr[None, :]
+    at_root = (vidx == root)[None, :]
+    vv = jnp.broadcast_to(vidx.astype(jnp.int32), (nlinks, n))
+    rtr_ext = jnp.concatenate([is_rtr, jnp.zeros((1,), bool)])
+    rpar = jnp.where(rtr_ext[par], par, _up(par, par))  # router parent
 
-    n1_0 = jnp.full((nlinks, n), n, jnp.int32)  # n = none yet
-    p_0 = jnp.where(vidx == root, root, -1)[None, :].repeat(nlinks, 0)
-    s_0 = jnp.full((nlinks, n), -1, jnp.int32)
+    def on_nets(x):
+        """A network takes its parent's value (the parent is a router)."""
+        return jnp.where(isr, x, _up(x, par))
 
-    def cond(carry):
-        _, _, _, changed, it = carry
-        return changed & (it < limit)
-
-    def body(carry):
-        n1, p, s, _, it = carry
-        n1_u = jnp.take_along_axis(n1, parc, axis=1)
-        p_u = jnp.take_along_axis(p, parc, axis=1)
-        s_u = jnp.take_along_axis(s, parc, axis=1)
-        # First router on the path (the repair's release neighbor).
-        n1_new = jnp.where(
-            (vidx == root)[None, :] | ~has_par,
-            n,
-            jnp.where(
-                n1_u < n, n1_u, jnp.where(is_rtr[None, :], vidx[None, :], n)
-            ),
-        ).astype(jnp.int32)
-        # v is loop-free reachable from its release neighbor: the P mark.
-        n1c = jnp.clip(n1_new, 0, n - 1)
-        d_n1_v = D[n1c, vidx[None, :]]  # [L, N]
-        d_n1_root = D[n1c, root]
-        pmark = (
-            (n1_new < n)
-            & is_rtr[None, :]
-            & (d_n1_v < _fadd(d_n1_root, droot[None, :]))
-        )
-        p_new = jnp.where(
-            (vidx == root)[None, :],
-            root,
-            jnp.where(~has_par, -1, jnp.where(pmark, vidx[None, :], p_u)),
-        ).astype(jnp.int32)
-        s_new = jnp.where(
-            (vidx == root)[None, :] | ~has_par,
-            -1,
-            jnp.where(
-                ~is_rtr[None, :],
-                s_u,
-                jnp.where(
-                    pmark, -1, jnp.where(s_u >= 0, s_u, vidx[None, :])
-                ),
-            ),
-        ).astype(jnp.int32)
-        changed = (
-            jnp.any(n1_new != n1)
-            | jnp.any(p_new != p)
-            | jnp.any(s_new != s)
-        )
-        return n1_new, p_new, s_new, changed, it + 1
-
-    _, p_fix, s_fix, _, _ = jax.lax.while_loop(
-        cond, body, (n1_0, p_0, s_0, jnp.bool_(True), 0)
+    # First router on the path: the topmost router below the root.
+    step = jnp.where(isr & has_par, jnp.where(rpar == root, vv, rpar), n)
+    n1_fix = on_nets(_nearest(step))
+    # v is loop-free reachable from its release neighbor: the P mark.
+    c1 = jnp.clip(col_of[jnp.clip(n1_fix, 0, n - 1)], 0, nbase - 1)
+    d_n1_v = rows_f[vidx[None, :], c1]  # [L, N]: D[n1, v]
+    d_n1_root = rows_f[root, c1]
+    pmark = (
+        (n1_fix < n) & isr & has_par
+        & (d_n1_v < _fadd(d_n1_root, droot[None, :]))
     )
+    # P: the nearest marked router at or above v (the root counts).
+    marked = pmark | at_root
+    step = jnp.where(
+        at_root, root, jnp.where(isr & has_par, jnp.where(pmark, vv, rpar), n)
+    )
+    p_near = on_nets(_nearest(step))
+    p_fix = jnp.where(p_near < n, p_near, -1).astype(jnp.int32)
+    # S: the topmost unmarked router of the run that ends at v.
+    step = jnp.where(
+        isr & has_par & ~pmark, jnp.where(_up(marked, rpar), vv, rpar), n
+    )
+    s_near = on_nets(_nearest(step))
+    s_fix = jnp.where(s_near < n, s_near, -1).astype(jnp.int32)
+    # A release neighbour is a router one hop (or one network) from the
+    # root, so it has a base row; the guard says when it had none.
+    n1_seen = n1_fix < n
+    missing = (
+        n1_seen & (col_of[jnp.clip(n1_fix, 0, n - 1)] >= nbase)
+    ).sum().astype(jnp.int32)
 
     ok = (
         link_valid[:, None]
@@ -248,14 +374,84 @@ def frr_batch(
         & (post.dist < INF)
         & (p_fix >= 0)
     )
-    sc = jnp.clip(s_fix, 0, n - 1)
-    d_s = D[sc, vidx[None, :]]  # D[S, d]
-    d_s_root = D[sc, root]
+
+    # ---- 4. the rows known only now: each PQ node and every distinct
+    # successor S, compacted and relaxed DYN_BLOCK at a time.
+    pq_need = (pq >= 0) & link_valid
+    s_need = ok & (s_fix >= 0)
+    need = (
+        jnp.zeros((n + 1,), bool)
+        .at[jnp.where(pq_need, pq, n)]
+        .set(True)
+        .at[jnp.where(s_need, s_fix, n)]
+        .set(True)[:n]
+    )
+    n_need = need.sum().astype(jnp.int32)
+    slot = jnp.cumsum(need).astype(jnp.int32) - 1  # slot of v if needed
+    order = (
+        jnp.full((n + DYN_BLOCK,), n, jnp.int32)
+        .at[jnp.where(need, slot, n + DYN_BLOCK)]
+        .set(vidx.astype(jnp.int32), mode="drop")
+    )
+    pq_slot = jnp.where(pq_need, slot[jnp.clip(pq, 0, n - 1)], -1)
+    s_slot = jnp.where(s_need, slot[jnp.clip(s_fix, 0, n - 1)], -1)
+
+    def dcond(carry):
+        return carry[0] * DYN_BLOCK < n_need
+
+    def dbody(carry):
+        b, dpq, dpq_root, d_s, d_s_root, rounds, rr = carry
+        lo = b * DYN_BLOCK
+        srcs = jax.lax.dynamic_slice(order, (lo,), (DYN_BLOCK,))
+        rows_b, r_b = rows_block(fwd, srcs, limit)  # [N, DYN_BLOCK]
+        in_pq = (pq_slot >= lo) & (pq_slot < lo + DYN_BLOCK)
+        cpq = jnp.clip(pq_slot - lo, 0, DYN_BLOCK - 1)
+        dpq = jnp.where(in_pq[:, None], rows_b[:, cpq].T, dpq)
+        dpq_root = jnp.where(in_pq, rows_b[root, cpq], dpq_root)
+        in_s = (s_slot >= lo) & (s_slot < lo + DYN_BLOCK)
+        cs = jnp.clip(s_slot - lo, 0, DYN_BLOCK - 1)
+        d_s = jnp.where(in_s, rows_b[vidx[None, :], cs], d_s)
+        d_s_root = jnp.where(in_s, rows_b[root, cs], d_s_root)
+        valid_rows = jnp.minimum(n_need - lo, DYN_BLOCK)
+        return (
+            b + 1, dpq, dpq_root, d_s, d_s_root, rounds + r_b,
+            rr + valid_rows * r_b,
+        )
+
+    inf_ln = jnp.full((nlinks, n), INF, jnp.int32)
+    inf_l = jnp.full((nlinks,), INF, jnp.int32)
+    blocks, dpq, dpq_root, d_s, d_s_root, rounds_d, rr_d = jax.lax.while_loop(
+        dcond, dbody,
+        (jnp.int32(0), inf_ln, inf_l, inf_ln, inf_ln, jnp.int32(0),
+         jnp.int32(0)),
+    )
+
+    rlfa_ok = (
+        pq_need[:, None]
+        & (dpq < _fadd(dpq_root[:, None], droot[None, :]))
+        & valid_d[None, :]
+    )
+    rlfa_pq = jnp.where(rlfa_ok, pq[:, None], -1).astype(jnp.int32)
+
     tail_ok = d_s < _fadd(d_s_root, droot[None, :])
     single = s_fix < 0
     double = (s_fix >= 0) & tail_ok
     tilfa_p = jnp.where(ok & (single | double), p_fix, -1).astype(jnp.int32)
     tilfa_q = jnp.where(ok & double, s_fix, -1).astype(jnp.int32)
+
+    n_base = base_ok.sum().astype(jnp.int32)
+    n_rev = ((rev_src >= 0) & (rev_src < n)).sum().astype(jnp.int32)
+    stats = jnp.stack([
+        n_base + n_need,
+        n_rev,
+        rounds_f + rounds_r + rounds_d,
+        n_base * rounds_f + rr_d,
+        n_rev * rounds_r,
+        _distinct(s_fix, s_need, n),
+        blocks,
+        unmatched,
+        missing,
+    ]).astype(jnp.int32)
 
     return FrrTensors(
         lfa_adj=lfa_adj,
@@ -265,7 +461,39 @@ def frr_batch(
         tilfa_q=tilfa_q,
         post_dist=post.dist,
         post_nh=post.nexthops,
+        stats=stats,
     )
+
+
+def _up(x, idx):
+    """``x`` at each row's ``idx`` ([L, N] each; ``idx == N`` reads the
+    pad: N for ints, False for masks)."""
+    pad = jnp.full((x.shape[0], 1), x.shape[1] if x.dtype != bool else False,
+                   x.dtype)
+    return jnp.take_along_axis(jnp.concatenate([x, pad], axis=1), idx, axis=1)
+
+
+def _nearest(step):
+    """Pointer doubling to the fixed points of ``step`` ([L, N] in
+    ``[0, N]``; a fixed point points at itself, N at nothing): every
+    vertex's nearest ancestor of the kind the fixed points are."""
+
+    def cond(carry):
+        return carry[1]
+
+    def body(carry):
+        ptr, _ = carry
+        nxt = _up(ptr, ptr)
+        return nxt, jnp.any(nxt != ptr)
+
+    ptr, _ = jax.lax.while_loop(cond, body, (step, jnp.bool_(True)))
+    return ptr
+
+
+def _distinct(values, mask, n):
+    """Count of distinct ``values`` (in ``[0, n)``) where ``mask``."""
+    seen = jnp.zeros((n + 1,), bool).at[jnp.where(mask, values, n)].set(True)
+    return seen[:n].sum().astype(jnp.int32)
 
 
 @dataclass
@@ -282,6 +510,8 @@ class BackupTable:
     tilfa_q: np.ndarray  # int32[L, N]
     post_dist: np.ndarray  # int32[L, N]
     post_nh: np.ndarray  # uint32[L, N, W]
+    # What the device kernel did (kernel.STATS by name); None from the oracle.
+    stats: dict | None = None
 
     @property
     def n_links(self) -> int:

@@ -7,12 +7,15 @@ per shape bucket like ``TpuSpfBackend``) or the scalar oracle.  Both are
 bit-identical; 'scalar' is the default for the same reason it is for
 SPF — zero marshaling latency on small LSDBs.
 
-``resolve_backup`` applies the configured protection policy to one
-(protected link, destination vertex) query: direct LFA first (cheapest —
-no extra encapsulation), then remote-LFA PQ tunnel, then the TI-LFA
-segment repair.  The result is symbolic (atoms + repair vertices); the
-protocol layer maps atoms to (interface, address) next hops and repair
-vertices to SR labels via its own SID tables.
+``repair_plan`` applies the configured protection policy to every
+(protected link, destination vertex) at once: direct LFA first
+(cheapest — no extra encapsulation), then remote-LFA PQ tunnel, then the
+TI-LFA segment repair.  ``repair_picks`` reads the plan for each
+(destination, primary next-hop atom) of an SPF result — the one
+selection every protocol (OSPFv2/v3, IS-IS) attaches from.  The result
+is symbolic (atoms + repair vertices); the protocol layer maps atoms to
+(interface, address) next hops and repair vertices to SR labels via its
+own SID tables.
 """
 
 from __future__ import annotations
@@ -26,7 +29,7 @@ from holo_tpu import telemetry
 from holo_tpu.analysis.runtime import sanctioned_transfer
 from holo_tpu.frr.inputs import marshal_frr
 from holo_tpu.frr.kernel import BackupTable
-from holo_tpu.ops.graph import Topology
+from holo_tpu.ops.graph import INF, Topology
 from holo_tpu.resilience import faults
 from holo_tpu.resilience.breaker import CircuitBreaker
 from holo_tpu.telemetry import convergence, profiling
@@ -56,6 +59,33 @@ _FRR_PAD_OCCUPANCY = telemetry.gauge(
     "holo_frr_pad_occupancy",
     "Valid fraction of the padded FRR plane (last dispatch)",
     ("plane",),
+)
+# What the blocked rows kernel did, per dispatch (kernel.STATS): the
+# distance rows it relaxed by direction, its relaxation rounds summed
+# over its blocks, and each block's rows times its rounds (the reads a
+# roofline of the rows counts, benchmark/frrcost.py).
+_FRR_ROWS = telemetry.counter(
+    "holo_frr_rows_total",
+    "Distance rows the FRR kernel relaxed (forward rows, reversed-graph columns)",
+    ("dir",),
+)
+_FRR_ROUNDS = telemetry.counter(
+    "holo_frr_rounds_total",
+    "Relaxation rounds of the FRR kernel's row blocks, summed over blocks",
+)
+_FRR_ROW_ROUNDS = telemetry.counter(
+    "holo_frr_row_rounds_total",
+    "Sum over the FRR kernel's row blocks of valid rows times rounds",
+    ("dir",),
+)
+_FRR_SLOTS = telemetry.gauge(
+    "holo_frr_rows_slots",
+    "Slots of one direction of the FRR kernel's compact rows graph (last dispatch)",
+)
+_FRR_REPAIRS = telemetry.counter(
+    "holo_frr_repairs_total",
+    "Repairs attached, per (protected link, protectable destination) per attach",
+    ("kind",),
 )
 # Same family the SPF backend increments (registry get-or-create by
 # name): one process-wide series of mesh-sharded dispatches, split by
@@ -116,90 +146,113 @@ class FrrConfig:
         return False
 
 
-@dataclass(frozen=True)
-class BackupEntry:
-    """One resolved repair for (protected link, destination vertex)."""
-
-    kind: str  # 'lfa' | 'rlfa' | 'ti-lfa'
-    atom: int | None  # release next-hop atom (None: caller falls back
-    # to its primary next hop toward via[0])
-    via: tuple[int, ...] = ()  # repair vertices: () | (pq,) | (p[, q])
-    node_protecting: bool = False
+#: the kinds of ``repair_plan``, by code, as ``holo_frr_repairs_total`` labels them
+REPAIR_KINDS = ("none", "lfa", "rlfa", "ti-lfa")
 
 
-def first_atom(words: np.ndarray) -> int | None:
-    """Lowest set atom id in a uint32 bitmask row (deterministic pick)."""
-    for wi, word in enumerate(np.asarray(words, np.uint32)):
-        w = int(word)
-        if w:
-            return wi * 32 + (w & -w).bit_length() - 1
-    return None
+def _lowest_atoms(words: np.ndarray) -> np.ndarray:
+    """int32[...]: the lowest set atom id of each uint32 bitmask row of
+    ``words`` ([..., W]), -1 where none (the deterministic pick)."""
+    words = np.asarray(words, np.uint32)
+    nz = words != 0
+    wi = np.argmax(nz, axis=-1)
+    w = np.take_along_axis(words, wi[..., None], axis=-1)[..., 0]
+    low = (w & (~w + np.uint32(1))).astype(np.float64)
+    bit = np.log2(np.where(low > 0, low, 1.0)).astype(np.int32)
+    return np.where(nz.any(axis=-1), wi.astype(np.int32) * 32 + bit, -1)
 
 
-def resolve_backup(
-    table: BackupTable, cfg: FrrConfig, link: int, dest: int
-) -> BackupEntry | None:
-    """Pick the repair for (link, dest) under ``cfg``; None = unprotected."""
-    if not cfg.enabled or link < 0 or link >= table.n_links:
-        return None
+def repair_plan(table: BackupTable, cfg: FrrConfig) -> tuple:
+    """The repair of every (protected link, destination) under ``cfg``:
+    ``(kind, release, via0, via1)``, int[L, N] each — ``kind`` the
+    :data:`REPAIR_KINDS` index (0 none), ``release`` the release
+    next-hop atom, ``via0``/``via1`` the repair vertices (-1 past the
+    repair's own: an LFA's neighbour, a remote LFA's PQ node, TI-LFA's
+    P and Q).
+
+    Direct LFA first; else the remote-LFA PQ node, released through the
+    PQ node's own LFA (the plain route to it may cross the failed link)
+    — a PQ node without one protects nothing and does not fall through;
+    else TI-LFA released on the lowest post-convergence next-hop atom.
+    Only repairs with a release next hop are kept: nothing can forward
+    on the others."""
+    nl, n = table.lfa_adj.shape
     fin = table.inputs
-    a = int(table.lfa_adj[link, dest])
-    if a >= 0:
-        return BackupEntry(
-            kind="lfa",
-            atom=int(fin.adj_atom[a]),
-            via=(int(fin.adj_nbr[a]),),
-            node_protecting=bool(table.lfa_nodeprot[link, dest]),
-        )
+    kind = np.zeros((nl, n), np.int8)
+    release = np.full((nl, n), -1, np.int32)
+    via0 = np.full((nl, n), -1, np.int32)
+    via1 = np.full((nl, n), -1, np.int32)
+    if not cfg.enabled or nl == 0:
+        return kind, release, via0, via1
+    adj_atom = np.append(fin.adj_atom[: fin.n_adj], -1).astype(np.int32)
+    adj_nbr = np.append(fin.adj_nbr[: fin.n_adj], -1).astype(np.int32)
+    lfa = table.lfa_adj >= 0
+    kind[lfa] = 1
+    release[lfa] = adj_atom[table.lfa_adj[lfa]]
+    via0[lfa] = adj_nbr[table.lfa_adj[lfa]]
+    open_ = ~lfa
     if cfg.remote_lfa:
-        pq = int(table.rlfa_pq[link, dest])
-        if pq >= 0:
-            # Release toward the PQ node: its own LFA pick when the
-            # plain P-space route would still cross the failed link.
-            rel = int(table.lfa_adj[link, pq])
-            atom = int(fin.adj_atom[rel]) if rel >= 0 else None
-            return BackupEntry(kind="rlfa", atom=atom, via=(pq,))
+        pq = table.rlfa_pq
+        has = open_ & (pq >= 0)
+        rel = table.lfa_adj[np.arange(nl)[:, None], np.clip(pq, 0, n - 1)]
+        rel = np.where(rel >= 0, adj_atom[rel], -1)
+        got = has & (rel >= 0)
+        kind[got] = 2
+        release[got] = rel[got]
+        via0[got] = pq[got]
+        open_ &= ~has  # a PQ without a release protects nothing
     if cfg.ti_lfa:
-        p = int(table.tilfa_p[link, dest])
-        if p >= 0:
-            q = int(table.tilfa_q[link, dest])
-            atom = first_atom(table.post_nh[link, dest])
-            via = (p,) if q < 0 else (p, q)
-            return BackupEntry(kind="ti-lfa", atom=atom, via=via)
-    return None
+        rel = _lowest_atoms(table.post_nh)
+        got = open_ & (table.tilfa_p >= 0) & (rel >= 0)
+        kind[got] = 3
+        release[got] = rel[got]
+        via0[got] = table.tilfa_p[got]
+        via1[got] = table.tilfa_q[got]
+    return kind, release, via0, via1
 
 
-def repair_map(
-    table: BackupTable | None,
-    cfg: FrrConfig,
-    words: np.ndarray,
-    vertex: int,
-) -> dict[int, BackupEntry]:
-    """{primary next-hop atom id -> repair} for one destination vertex.
+def repair_picks(table: BackupTable, plan: tuple, words: np.ndarray) -> tuple:
+    """``plan`` (:func:`repair_plan`) read for each destination vertex's
+    primary next hops: every (vertex, primary atom) of ``words``
+    (uint32[N, W], an SPF result's next-hop words) whose atom rides a
+    protected link (``atom_link``) and has a repair there, lowest atom
+    first per vertex.  Returns ``(vertex, atom, kind, release, via0,
+    via1)``, int arrays of one length — the repair a router flips to
+    when that next hop's link, BFD session or carrier drops."""
+    kind, release, via0, via1 = plan
+    n = kind.shape[1]
+    words = np.ascontiguousarray(np.asarray(words, np.uint32)[:n])
+    bits = np.unpackbits(
+        words.view(np.uint8).reshape(n, -1, 4)[:, :, ::-1], axis=2,
+    )[:, :, ::-1].reshape(n, -1)
+    vs, ats = np.nonzero(bits)
+    link_of = np.full(bits.shape[1], -1, np.int64)
+    for a, link in table.inputs.atom_link.items():
+        if a < bits.shape[1]:
+            link_of[a] = link
+    lk = link_of[ats]
+    keep = lk >= 0
+    vs, ats, lk = vs[keep], ats[keep], lk[keep]
+    k = kind[lk, vs]
+    got = k > 0
+    vs, ats, lk = vs[got], ats[got], lk[got]
+    return (
+        vs, ats, k[got], release[lk, vs], via0[lk, vs], via1[lk, vs],
+    )
 
-    The shared protocol-side consumption step (OSPFv2/v3, IS-IS): each
-    primary atom rides exactly one protected link (``atom_link``), and
-    the repair for (that link, this destination) is what the router
-    flips to when the link's BFD session or carrier drops.  Entries
-    whose repair has no release atom (an unreachable tunnel release) are
-    omitted — the caller cannot build a forwarding entry from them."""
-    out: dict[int, BackupEntry] = {}
-    if table is None or not cfg.active():
-        return out
-    n_words = np.asarray(words, np.uint32)
-    for wi, word in enumerate(n_words):
-        w = int(word)
-        while w:
-            low = w & -w
-            a = wi * 32 + low.bit_length() - 1
-            w ^= low
-            link = table.link_of_atom(a)
-            if link is None:
-                continue
-            entry = resolve_backup(table, cfg, link, vertex)
-            if entry is not None and entry.atom is not None:
-                out[a] = entry
-    return out
+
+def note_repairs(table: BackupTable, cfg: FrrConfig, plan=None) -> None:
+    """Count one attach of ``table`` into ``holo_frr_repairs_total``:
+    one increment per (protected link, protectable destination) — the
+    root and destinations the failure cuts off are not protectable — by
+    the kind :func:`repair_plan` keeps (``none`` for no repair)."""
+    kind = (plan if plan is not None else repair_plan(table, cfg))[0]
+    eligible = table.post_dist < INF
+    eligible[:, table.root] = False
+    counts = np.bincount(kind[eligible].ravel(), minlength=len(REPAIR_KINDS))
+    for code, name in enumerate(REPAIR_KINDS):
+        if counts[code]:
+            _FRR_REPAIRS.labels(kind=name).inc(int(counts[code]))
 
 
 def ensure_engine(current, cfg: FrrConfig) -> "FrrEngine":
@@ -242,9 +295,13 @@ class FrrEngine:
         )
         self._jit = None  # built lazily (jax import on first TPU compute)
         self._compiled_shapes: set[tuple] = set()
-        # Mesh-sharded all-roots programs, one per mesh identity
-        # (outputs pinned to the batch sharding over protected links).
+        # Mesh-sharded programs, one per mesh identity (outputs pinned
+        # to the batch sharding over protected links).
         self._shard_jits: dict[tuple, object] = {}
+        # Shapes of the compact rows graphs per vertex count: they only
+        # grow (a failed hub would otherwise narrow them, and a narrower
+        # graph is a new program) — DeviceGraphCache._k_pad_floor's rule.
+        self._rows_floor: dict[int, tuple[int, int]] = {}
         # Protection policy (node-protection / SRLG-disjoint masks) —
         # traced kernel inputs, so a policy flip never recompiles.
         self.policy = FrrConfig()
@@ -253,30 +310,33 @@ class FrrEngine:
         """Adopt the instance's protection policy (ensure_engine seam)."""
         self.policy = cfg
 
+    def _step(self, g, root, *args):
+        from holo_tpu.frr.kernel import frr_batch
+
+        fwd, rev, base, rsrc, lf, lc, la, lv, an, ac, al, av, lsr, asr, rnp = args
+        return frr_batch(
+            g, root, fwd, rev, base, rsrc, lf, lc, la, lv, an, ac, al, av,
+            link_srlg=lsr, adj_srlg=asr, require_np=rnp,
+            max_iters=self.max_iters,
+        )
+
     def _sharded_jit(self, mesh):
         if mesh.size == 1:
             # Degenerate mesh: the plain program is the sharded program
-            # (built by _compute_tpu before dispatch branches).
+            # (built by _launch_tpu before dispatch branches).
             return self._jit
         import jax
 
-        from holo_tpu.frr.kernel import frr_batch
         from holo_tpu.parallel.mesh import constrain_batch, mesh_cache_key
 
         key = mesh_cache_key(mesh)
         fn = self._shard_jits.get(key)
         if fn is None:
 
-            @jax.jit
-            def step(g, root, lf, lc, lv, em, an, ac, al, av, lsr, asr, rnp):
-                out = frr_batch(
-                    g, root, lf, lc, lv, em, an, ac, al, av,
-                    link_srlg=lsr, adj_srlg=asr, require_np=rnp,
-                    max_iters=self.max_iters,
-                )
-                return constrain_batch(mesh, out)
+            def frr_batch_sharded(g, root, *args):
+                return constrain_batch(mesh, self._step(g, root, *args))
 
-            fn = self._shard_jits[key] = step
+            fn = self._shard_jits[key] = jax.jit(frr_batch_sharded)
         return fn
 
     def _policy_args(self, fin) -> tuple:
@@ -291,71 +351,83 @@ class FrrEngine:
             asr = np.zeros_like(fin.adj_srlg)
         return lsr, asr, np.bool_(self.policy.node_protection)
 
-    def _shard_args(self, mesh, fin):
-        """Place the FRR planes per the mesh layout contract: the
-        per-protected-link planes (the all-roots/what-if batch axis)
-        sharded over ``batch`` — padded to the axis size with
-        valid=False links whose scenario masks fail nothing — and the
-        repair-candidate adjacency planes replicated."""
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
+    def _rows_args(self, topo: Topology) -> tuple:
+        """The forward and reversed compact rows graphs of ``topo``
+        (kernel.RowsGraph of host arrays), at no less than the widest
+        shapes this engine built for the same vertex count."""
+        from holo_tpu.frr.inputs import rows_graph_shape, rows_graphs
+        from holo_tpu.frr.kernel import RowsGraph
 
-        nb = mesh.shape["batch"]
-        lf, lc, lv, em = (
-            fin.link_far, fin.link_cost, fin.link_valid, fin.edge_masks,
-        )
+        need_h, need_k = rows_graph_shape(topo)
+        floor_h, floor_k = self._rows_floor.get(topo.n_vertices, (0, 0))
+        h_pad = max(floor_h, -(-max(need_h, 1) // 64) * 64)
+        k_hi = max(floor_k, -(-max(need_k, 1) // 8) * 8)
+        self._rows_floor[topo.n_vertices] = (h_pad, k_hi)
+        fwd, rev = rows_graphs(topo, h_pad, k_hi)
+        return RowsGraph(*fwd), RowsGraph(*rev)
+
+    def _kernel_args(self, topo: Topology, fin, nb: int = 1) -> tuple:
+        """Host planes of one dispatch, the per-link ones padded to a
+        multiple of ``nb`` (the mesh batch axis) with valid=False links."""
+        fwd, rev = self._rows_args(topo)
+        lf, lc, la, lv = fin.link_far, fin.link_cost, fin.link_atom, fin.link_valid
         lsr, asr, rnp = self._policy_args(fin)
+        rsrc = fin.rev_src
         pad = (-lf.shape[0]) % nb
         if pad:
             lf = np.concatenate([lf, np.zeros(pad, lf.dtype)])
             lc = np.concatenate([lc, np.ones(pad, lc.dtype)])
+            la = np.concatenate([la, np.full(pad, -1, la.dtype)])
             lv = np.concatenate([lv, np.zeros(pad, bool)])
-            em = np.concatenate([em, np.ones((pad, em.shape[1]), bool)])
             lsr = np.concatenate([lsr, np.zeros(pad, lsr.dtype)])
+            want = -(-(1 + lf.shape[0]) // 8) * 8
+            rsrc = np.concatenate([
+                rsrc, np.full(want - rsrc.shape[0], topo.n_vertices, np.int32)
+            ])
+        return (
+            fwd, rev, fin.base_src, rsrc, lf, lc, la, lv,
+            fin.adj_nbr, fin.adj_cost, fin.adj_link, fin.adj_valid,
+            lsr, asr, rnp,
+        )
+
+    def _shard_args(self, mesh, args: tuple) -> tuple:
+        """Place the FRR planes per the mesh layout contract: the
+        per-protected-link planes (the post-convergence batch axis)
+        sharded over ``batch`` and everything else replicated."""
         if mesh.size == 1:
             # Nothing to shard: the jit commits host arrays itself
             # (see mesh.shard_scenarios).
-            return (
-                lf, lc, lv, em,
-                fin.adj_nbr, fin.adj_cost, fin.adj_link, fin.adj_valid,
-                lsr, asr, rnp,
-            )
+            return args
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
         link = NamedSharding(mesh, P("batch"))
-        mask = NamedSharding(mesh, P("batch", None))
         rep = NamedSharding(mesh, P())
+        fwd, rev, base, rsrc, lf, lc, la, lv, an, ac, al, av, lsr, asr, rnp = args
+        put = jax.device_put
         return (
-            jax.device_put(lf, link),
-            jax.device_put(lc, link),
-            jax.device_put(lv, link),
-            jax.device_put(em, mask),
-            jax.device_put(fin.adj_nbr, rep),
-            jax.device_put(fin.adj_cost, rep),
-            jax.device_put(fin.adj_link, rep),
-            jax.device_put(fin.adj_valid, rep),
-            jax.device_put(lsr, link),
-            jax.device_put(asr, rep),
-            np.bool_(rnp),
+            put(fwd, rep), put(rev, rep), put(base, rep), put(rsrc, rep),
+            put(lf, link), put(lc, link), put(la, link), put(lv, link),
+            put(an, rep), put(ac, rep), put(al, rep), put(av, rep),
+            put(lsr, link), put(asr, rep), np.bool_(rnp),
         )
 
     # -- device path
 
     def _prepare(self, topo: Topology):
         # Shared with TpuSpfBackend.prepare (ROADMAP cleanup): an
-        # instance running SPF + FRR now marshals its DeviceGraph once —
+        # instance running SPF + FRR marshals its DeviceGraph once —
         # the holo_spf_marshal_cache_total hit/miss/delta triple makes
         # the dedup visible, while this engine's series stays alive.
-        #
-        # Incremental-vs-full choice (DeltaPath): the FRR kernel
-        # gathers its per-protected-link scenario masks through
-        # ``in_edge_id``, so it can ride a delta-updated resident graph
-        # only while edge ids stay valid — pure weight-change chains
-        # within depth/padding headroom.  ``need_edge_ids`` makes the
-        # cache rebuild (full path) for structurally-updated entries;
-        # every disposition lands in holo_spf_delta_total{kind,path}.
+        # The kernel finds each protected edge's slot by its source,
+        # cost and atom (kernel._link_slots), never through
+        # ``in_edge_id``, so a structurally-updated resident serves it
+        # as it is: FRR never forces the SPF's DeltaPath chain into a
+        # re-marshal.
         from holo_tpu.ops.spf_engine import shared_graph_cache
 
         g, how = shared_graph_cache().get(
-            topo, max(self.n_atoms, topo.n_atoms()), need_edge_ids=True
+            topo, max(self.n_atoms, topo.n_atoms())
         )
         _FRR_GRAPH_CACHE.labels(result=how).inc()
         return g
@@ -379,19 +451,14 @@ class FrrEngine:
             faults.crashpoint("frr.shard")
         import jax
 
-        from holo_tpu.frr.kernel import frr_batch
         from holo_tpu.parallel.mesh import mesh_cache_key
 
         if self._jit is None:
-            self._jit = jax.jit(
-                lambda g, root, lf, lc, lv, em, an, ac, al, av, lsr, asr, rnp: (
-                    frr_batch(
-                        g, root, lf, lc, lv, em, an, ac, al, av,
-                        link_srlg=lsr, adj_srlg=asr, require_np=rnp,
-                        max_iters=self.max_iters,
-                    )
-                )
-            )
+
+            def frr_batch_step(g, root, *args):
+                return self._step(g, root, *args)
+
+            self._jit = jax.jit(frr_batch_step)
         # The FRR analog of the SPF backend's sanctioned boundary: the
         # padded planes move host->device here, results device->host
         # in _finish_tpu, and nowhere else.
@@ -402,23 +469,18 @@ class FrrEngine:
             with sanctioned_transfer("frr.batch.marshal"):
                 g = self._prepare(topo)
                 if mesh is not None:
-                    args = self._shard_args(mesh, fin)
+                    args = self._shard_args(
+                        mesh,
+                        self._kernel_args(topo, fin, mesh.shape["batch"]),
+                    )
                     step = self._sharded_jit(mesh)
                 else:
-                    args = (
-                        fin.link_far,
-                        fin.link_cost,
-                        fin.link_valid,
-                        fin.edge_masks,
-                        fin.adj_nbr,
-                        fin.adj_cost,
-                        fin.adj_link,
-                        fin.adj_valid,
-                        *self._policy_args(fin),
-                    )
+                    args = self._kernel_args(topo, fin)
                     step = self._jit
+                _FRR_SLOTS.set(args[0].slots)
                 sig = (
-                    args[0].shape, args[3].shape, args[4].shape,
+                    g.in_src.shape,
+                    tuple(np.shape(a) for a in (*args[0], *args[2:12])),
                     mesh_cache_key(mesh),
                 )
                 if sig in self._compiled_shapes:
@@ -444,8 +506,8 @@ class FrrEngine:
     @staticmethod
     def _obs_bucket(topo):
         """The observatory shape key for this FRR batch (the SPF
-        tuner's quantization, batch = the all-roots plane) — computed
-        ONCE per dispatch at launch and carried through the handle."""
+        tuner's quantization) — computed ONCE per dispatch at launch
+        and carried through the handle."""
         from holo_tpu.parallel.mesh import mesh_cache_key
         from holo_tpu.pipeline.tuner import shape_bucket
 
@@ -465,6 +527,8 @@ class FrrEngine:
 
     def _finish_tpu(self, handle: tuple) -> BackupTable:
         """Phase 2: device completion + readback + accounting."""
+        from holo_tpu.frr.kernel import STATS
+
         out, fin, topo, sharded, obucket = handle
         with self._obs_ctx(obucket), profiling.stage(
             "frr.batch", "device"
@@ -474,17 +538,20 @@ class FrrEngine:
                 profiling.sync(out)
         nl = fin.n_links
         n = int(topo.n_vertices)
-        if sharded:
-            _FRR_SHARD_DISPATCHES.labels(kind="frr").inc()
-        convergence.note_dispatch("frr", "device")
         with self._obs_ctx(obucket), profiling.stage(
             "frr.batch", "readback"
         ):
             with sanctioned_transfer("frr.batch.unmarshal"):
+                stats = dict(zip(STATS, np.asarray(out.stats).tolist()))
+                if stats["unmatched_links"] or stats["missing_rows"]:
+                    # The resident graph does not hold a protected edge,
+                    # or a release neighbour had no row: the table would
+                    # be wrong, so the breaker serves the oracle.
+                    raise RuntimeError(f"frr.batch: inconsistent planes {stats}")
                 # [:nl] drops the link-plane pad (marshal bucket + mesh
                 # batch-axis pad); [:n] drops the node-sharded row pad
                 # on the vertex axis — both no-ops single-device.
-                return BackupTable(
+                table = BackupTable(
                     inputs=fin,
                     root=int(topo.root),
                     lfa_adj=np.asarray(out.lfa_adj)[:nl, :n],
@@ -495,6 +562,16 @@ class FrrEngine:
                     post_dist=np.asarray(out.post_dist)[:nl, :n],
                     post_nh=np.asarray(out.post_nh)[:nl, :n],
                 )
+        if sharded:
+            _FRR_SHARD_DISPATCHES.labels(kind="frr").inc()
+        convergence.note_dispatch("frr", "device")
+        _FRR_ROWS.labels(dir="forward").inc(stats["rows_forward"])
+        _FRR_ROWS.labels(dir="reverse").inc(stats["rows_reverse"])
+        _FRR_ROUNDS.inc(stats["rounds"])
+        _FRR_ROW_ROUNDS.labels(dir="forward").inc(stats["row_rounds_forward"])
+        _FRR_ROW_ROUNDS.labels(dir="reverse").inc(stats["row_rounds_reverse"])
+        table.stats = stats
+        return table
 
     def marshal_inputs(self, topo: Topology):
         """Marshal the FRR planes + pad-occupancy gauges (the shared
@@ -562,27 +639,41 @@ class FrrEngine:
 # themselves — they only ever run when the audit arms.
 from holo_tpu.analysis.kernels import register_kernel as _register_kernel  # noqa: E402
 
-_AUDIT_LINKS, _AUDIT_ADJ = 8, 16
+_AUDIT_LINKS, _AUDIT_ADJ, _AUDIT_HEAVY = 8, 16, 64
 
 
 def _audit_frr_specs() -> tuple:
     import jax
     import jax.numpy as jnp
 
-    from holo_tpu.ops.spf_engine import _AUDIT_E, audit_graph_spec
+    from holo_tpu.frr.inputs import ROWS_K_LO
+    from holo_tpu.frr.kernel import RowsGraph
+    from holo_tpu.ops.spf_engine import _AUDIT_N, audit_graph_spec
 
     s = jax.ShapeDtypeStruct
-    lk, ad = _AUDIT_LINKS, _AUDIT_ADJ
+    lk, ad, hv = _AUDIT_LINKS, _AUDIT_ADJ, _AUDIT_HEAVY
+    i32 = jnp.int32
+    rows = RowsGraph(
+        lo_src=s((_AUDIT_N, ROWS_K_LO), i32),
+        lo_cost=s((_AUDIT_N, ROWS_K_LO), i32),
+        hi_vtx=s((hv,), i32),
+        hi_src=s((hv, 8), i32),
+        hi_cost=s((hv, 8), i32),
+    )
     return (
         audit_graph_spec(),
-        s((), jnp.int32),  # root
-        s((lk,), jnp.int32),  # link_far
-        s((lk,), jnp.int32),  # link_cost
+        s((), i32),  # root
+        rows,  # forward rows graph
+        rows,  # reversed rows graph
+        s((1 + lk + ad,), i32),  # base_src (rounded to 8 by marshal_frr)
+        s((16,), i32),  # rev_src
+        s((lk,), i32),  # link_far
+        s((lk,), i32),  # link_cost
+        s((lk,), i32),  # link_atom
         s((lk,), jnp.bool_),  # link_valid
-        s((lk, _AUDIT_E), jnp.bool_),  # edge_masks
-        s((ad,), jnp.int32),  # adj_nbr
-        s((ad,), jnp.int32),  # adj_cost
-        s((ad,), jnp.int32),  # adj_link
+        s((ad,), i32),  # adj_nbr
+        s((ad,), i32),  # adj_cost
+        s((ad,), i32),  # adj_link
         s((ad,), jnp.bool_),  # adj_valid
         s((lk,), jnp.uint32),  # link_srlg
         s((ad,), jnp.uint32),  # adj_srlg
@@ -593,34 +684,21 @@ def _audit_frr_specs() -> tuple:
 def _audit_frr_builder():
     import jax
 
-    from holo_tpu.frr.kernel import frr_batch
-
-    return jax.jit(
-        lambda g, root, lf, lc, lv, em, an, ac, al, av, lsr, asr, rnp: (
-            frr_batch(
-                g, root, lf, lc, lv, em, an, ac, al, av,
-                link_srlg=lsr, adj_srlg=asr, require_np=rnp,
-                max_iters=None,
-            )
-        )
-    )
+    return jax.jit(FrrEngine()._step)
 
 
 def _audit_frr_sharded_builder(mesh):
     import jax
 
-    from holo_tpu.frr.kernel import frr_batch
     from holo_tpu.parallel.mesh import constrain_batch
 
-    @jax.jit
-    def step(g, root, lf, lc, lv, em, an, ac, al, av, lsr, asr, rnp):
-        out = frr_batch(
-            g, root, lf, lc, lv, em, an, ac, al, av,
-            link_srlg=lsr, adj_srlg=asr, require_np=rnp, max_iters=None,
-        )
-        return constrain_batch(mesh, out)
+    step = FrrEngine()._step
 
-    return step
+    @jax.jit
+    def frr_batch_sharded(g, root, *args):
+        return constrain_batch(mesh, step(g, root, *args))
+
+    return frr_batch_sharded
 
 
 _register_kernel(

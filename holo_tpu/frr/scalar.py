@@ -1,9 +1,13 @@
 """Scalar FRR oracle: the kernel's selection semantics in plain Python.
 
-Independent implementation (loops + the reference Dijkstra oracle, no
-shared vectorized code) of the exact rules documented in
-:mod:`holo_tpu.frr.kernel`; tests require the two to be bit-identical.
-The all-roots matrix and per-link post-convergence runs use
+Independent implementation (loops + Dijkstra, no shared vectorized
+code) of the exact rules documented in :mod:`holo_tpu.frr.kernel`;
+tests require the two to be bit-identical.  Like the kernel it computes
+only the distances its selection reads: a row ``D[x, :]`` by one
+Dijkstra from ``x`` and a column ``D[:, t]`` by one Dijkstra from ``t``
+over the reversed edges, each the first time it is read
+(:class:`LazyDistances`), so that a 10,000-vertex table takes tens of
+Dijkstra runs and not 10,000.  The per-link post-convergence runs use
 ``spf_reference`` — whose dist/parent planes are already bit-parity
 gated against the device engines — so any divergence localizes to the
 selection logic itself.
@@ -11,7 +15,7 @@ selection logic itself.
 
 from __future__ import annotations
 
-import copy
+import heapq
 
 import numpy as np
 
@@ -27,15 +31,61 @@ def _fadd(a: int, b: int) -> int:
     return a + b if a < _INF and b < _INF else _INF
 
 
-def all_roots_dist(topo: Topology) -> np.ndarray:
-    """int32[N, N] distance matrix via per-root reference Dijkstra."""
-    n = topo.n_vertices
-    out = np.empty((n, n), np.int32)
-    for r in range(n):
-        t = copy.copy(topo)
-        t.root = r
-        out[r] = spf_reference(t).dist
-    return out
+class LazyDistances:
+    """``D[x, y]`` of ``topo`` (INF: unreachable), each row and each
+    column computed by a distance-only Dijkstra the first time it is
+    read.  ``rows`` and ``cols`` count the runs made."""
+
+    def __init__(self, topo: Topology):
+        n = topo.n_vertices
+        self._out: list[list[tuple[int, int]]] = [[] for _ in range(n)]
+        self._in: list[list[tuple[int, int]]] = [[] for _ in range(n)]
+        for a, b, c in zip(
+            topo.edge_src.tolist(), topo.edge_dst.tolist(),
+            topo.edge_cost.tolist(),
+        ):
+            self._out[a].append((b, c))
+            self._in[b].append((a, c))
+        self._rows: dict[int, list[int]] = {}
+        self._cols: dict[int, list[int]] = {}
+
+    @staticmethod
+    def _dijkstra(adj, src: int) -> list[int]:
+        dist = [_INF] * len(adj)
+        dist[src] = 0
+        heap = [(0, src)]
+        while heap:
+            d, u = heapq.heappop(heap)
+            if d != dist[u]:
+                continue
+            for v, c in adj[u]:
+                nd = d + c
+                if nd < dist[v]:
+                    dist[v] = nd
+                    heapq.heappush(heap, (nd, v))
+        return dist
+
+    def row(self, x: int) -> list[int]:
+        """``D[x, :]``."""
+        out = self._rows.get(x)
+        if out is None:
+            out = self._rows[x] = self._dijkstra(self._out, x)
+        return out
+
+    def col(self, t: int) -> list[int]:
+        """``D[:, t]``: distances to ``t``, over the reversed edges."""
+        out = self._cols.get(t)
+        if out is None:
+            out = self._cols[t] = self._dijkstra(self._in, t)
+        return out
+
+    @property
+    def rows(self) -> int:
+        return len(self._rows)
+
+    @property
+    def cols(self) -> int:
+        return len(self._cols)
 
 
 def frr_reference(
@@ -55,9 +105,9 @@ def frr_reference(
     n = topo.n_vertices
     root = int(topo.root)
     nl, na = fin.n_links, fin.n_adj
-    is_router = topo.is_router
-    d = all_roots_dist(topo)
-    droot = d[root]
+    is_router = topo.is_router.tolist()
+    dd = LazyDistances(topo)
+    droot = dd.row(root)
     w = max((max(n_atoms, topo.n_atoms()) + 31) // 32, 1)
 
     lfa_adj = np.full((nl, n), -1, np.int32)
@@ -71,9 +121,10 @@ def frr_reference(
     nbr = [int(x) for x in fin.adj_nbr[:na]]
     acost = [int(x) for x in fin.adj_cost[:na]]
     alink = [int(x) for x in fin.adj_link[:na]]
+    rown = [dd.row(x) for x in nbr]  # D[nbr_a, :]
 
     def valid_d(dst: int) -> bool:
-        return dst != root and int(droot[dst]) < _INF
+        return dst != root and droot[dst] < _INF
 
     for l in range(nl):
         far = int(fin.link_far[l])
@@ -81,6 +132,9 @@ def frr_reference(
         post = spf_reference(topo, fin.edge_masks[l])
         post_dist[l] = post.dist
         post_nh[l] = post.nexthop_words(max(n_atoms, topo.n_atoms()))
+        post_d = post.dist.tolist()
+        post_par = post.parent.tolist()
+        rfar = dd.row(far)
 
         usable = [
             alink[a] != l
@@ -99,10 +153,10 @@ def frr_reference(
             for a in range(na):
                 if not usable[a]:
                     continue
-                dn_d = int(d[nbr[a], dst])
-                if not dn_d < _fadd(int(d[nbr[a], root]), int(droot[dst])):
+                dn_d = rown[a][dst]
+                if not dn_d < _fadd(rown[a][root], droot[dst]):
                     continue
-                nprot = dn_d < _fadd(int(d[nbr[a], far]), int(d[far, dst]))
+                nprot = dn_d < _fadd(rown[a][far], rfar[dst])
                 alt = _fadd(acost[a], dn_d)
                 if alt < _INF:
                     cands.append((nprot, alt, nbr[a], a))
@@ -118,17 +172,17 @@ def frr_reference(
 
         # -- remote LFA (RFC 7490 P/Q intersection)
         def in_extp(v: int) -> bool:
-            if int(droot[v]) < _fadd(lcost, int(d[far, v])):
+            if droot[v] < _fadd(lcost, rfar[v]):
                 return True
             return any(
                 usable[a]
-                and int(d[nbr[a], v])
-                < _fadd(int(d[nbr[a], root]), int(droot[v]))
+                and rown[a][v]
+                < _fadd(rown[a][root], droot[v])
                 for a in range(na)
             )
 
         def in_qspace(v: int) -> bool:
-            return int(d[v, far]) < _fadd(int(d[v, root]), lcost)
+            return dd.col(far)[v] < _fadd(dd.col(root)[v], lcost)
 
         pq = -1
         best_key = (_INF, n)
@@ -136,26 +190,25 @@ def frr_reference(
             if v == root or not is_router[v]:
                 continue
             if in_extp(v) and in_qspace(v):
-                key = (int(droot[v]), v)
+                key = (droot[v], v)
                 if key < best_key:
                     best_key, pq = key, v
         if pq >= 0:
+            rpq = dd.row(pq)
             for dst in range(n):
-                if valid_d(dst) and int(d[pq, dst]) < _fadd(
-                    int(d[pq, root]), int(droot[dst])
-                ):
+                if valid_d(dst) and rpq[dst] < _fadd(rpq[root], droot[dst]):
                     rlfa_pq[l, dst] = pq
 
         # -- TI-LFA along the post-convergence path
         for dst in range(n):
-            if not valid_d(dst) or int(post.dist[dst]) >= _INF:
+            if not valid_d(dst) or post_d[dst] >= _INF:
                 continue
             # parent walk dst → root (acyclic SPT; sentinel n = none)
             path = []
             v = dst
             while v != root:
                 path.append(v)
-                v = int(post.parent[v])
+                v = post_par[v]
                 if v >= n:
                     path = None
                     break
@@ -170,8 +223,8 @@ def frr_reference(
                 pmark = (
                     n1 is not None
                     and is_router[v]
-                    and int(d[n1, v])
-                    < _fadd(int(d[n1, root]), int(droot[v]))
+                    and dd.row(n1)[v]
+                    < _fadd(dd.row(n1)[root], droot[v])
                 )
                 if not is_router[v]:
                     pass  # pseudo-node: transparent for P and S
@@ -183,8 +236,8 @@ def frr_reference(
                 continue
             if s_node < 0:
                 tilfa_p[l, dst] = p_node
-            elif int(d[s_node, dst]) < _fadd(
-                int(d[s_node, root]), int(droot[dst])
+            elif dd.row(s_node)[dst] < _fadd(
+                dd.row(s_node)[root], droot[dst]
             ):
                 tilfa_p[l, dst] = p_node
                 tilfa_q[l, dst] = s_node

@@ -2313,12 +2313,36 @@ class IsisInstance(SpfDelayFsm, Actor):
         table = self.frr_tables.get(0)
         if frr_cfg is None or not frr_cfg.active() or table is None:
             return
-        from holo_tpu.frr.manager import repair_map
+        from holo_tpu.frr.manager import repair_picks, repair_plan
 
-        atoms4 = lt.spf[0].atoms
-        atoms6 = lt.spf.get(MT_IPV6, lt.spf[0]).atoms
-        # Prefixes sharing a terminating vertex share the repair map.
-        memo: dict[tuple, dict] = {}
+        plan = repair_plan(table, frr_cfg)
+        # per address family: vertex -> {primary hop: (its LFA's hop, ())}
+        lfas: dict = {}
+
+        def lfas_of(want_v6: bool) -> dict:
+            got = lfas.get(want_v6)
+            if got is not None:
+                return got
+            res_ = res6 if want_v6 else res4
+            atoms_ = lt.spf.get(MT_IPV6, lt.spf[0]).atoms if want_v6 else (
+                lt.spf[0].atoms
+            )
+            vs, ats, kind, release, _v0, _v1 = repair_picks(
+                table, plan, res_.nexthop_words
+            )
+            got = lfas[want_v6] = {}
+            lfa = kind == 1
+            for v, a, r in zip(
+                vs[lfa].tolist(), ats[lfa].tolist(), release[lfa].tolist()
+            ):
+                ifn, p4, p6 = atoms_[a]
+                bifn, b4, b6 = atoms_[r]
+                paddr, baddr = (p6, b6) if want_v6 else (p4, b4)
+                if paddr is None or baddr is None:
+                    continue
+                got.setdefault(v, {})[(ifn, paddr)] = ((bifn, baddr), ())
+            return got
+
         for prefix, r in routes.items():
             v = r.vertex
             if v < 0 or v == root:
@@ -2326,24 +2350,9 @@ class IsisInstance(SpfDelayFsm, Actor):
             want_v6 = prefix.version == 6
             if want_v6 and mt6:
                 continue
-            res_, atoms_ = (res6, atoms6) if want_v6 else (res4, atoms4)
-            repairs = memo.get((want_v6, v))
-            if repairs is None:
-                repairs = memo[(want_v6, v)] = repair_map(
-                    table, frr_cfg, res_.nexthop_words[v], v
-                )
-            backups = {}
-            for a, entry in repairs.items():
-                if entry.kind != "lfa":
-                    continue
-                ifn, p4, p6 = atoms_[a]
-                bifn, b4, b6 = atoms_[entry.atom]
-                paddr, baddr = (p6, b6) if want_v6 else (p4, b4)
-                if paddr is None or baddr is None:
-                    continue
-                backups[(ifn, paddr)] = ((bifn, baddr), ())
+            backups = lfas_of(want_v6).get(v)
             if backups:
-                self.frr_backups[prefix] = backups
+                self.frr_backups[prefix] = dict(backups)
 
     def installable_routes(self) -> dict:
         """The RIB-feed view of :attr:`routes` (route.rs:285-301):

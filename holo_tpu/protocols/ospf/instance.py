@@ -17,6 +17,7 @@ retransmission lists.
 
 from __future__ import annotations
 
+from contextlib import nullcontext
 from dataclasses import dataclass, field
 from ipaddress import IPv4Address, IPv4Network
 
@@ -399,6 +400,9 @@ class OspfInstance(SpfDelayFsm, Actor):
         # compile cache.
         self.frr_tables: dict = {}
         self._frr_engine = None
+        # SR: each Extended Prefix LSA's prefix-SID as last decoded
+        # (_resolve_sr_labels), by LSA key.
+        self._sr_decoded: dict = {}
         # ISSUE 10 satellite: deferred FRR-backup attach (pipelined
         # tables are forced on the worker, never on the SPF path) and
         # advisory what-if tickets + counters per area.
@@ -2879,14 +2883,15 @@ class OspfInstance(SpfDelayFsm, Actor):
 
         # IP-FRR: one batched backup-table dispatch per area right after
         # the primary SPF (the reference hangs TI-LFA off the same
-        # moment) — all-roots distance matrix + per-link post-convergence
-        # planes + vectorized LFA/rLFA/TI-LFA selection.
+        # moment) — the distance rows the selection reads + per-link
+        # post-convergence planes + vectorized LFA/rLFA/TI-LFA selection.
         engine = self._frr_engine_for()
         if engine is not None:
-            self.frr_tables = {
-                aid: engine.compute(st.topo)
-                for aid, (st, _res) in area_results.items()
-            }
+            with profiling.stage("ospf.spf", "frr"):
+                self.frr_tables = {
+                    aid: engine.compute(st.topo)
+                    for aid, (st, _res) in area_results.items()
+                }
         else:
             self.frr_tables = {}
 
@@ -3557,16 +3562,26 @@ class OspfInstance(SpfDelayFsm, Actor):
 
         now = self.loop.clock.now()
         out = {}
+        # An LSA's prefix-SID is decoded once, when a new copy arrives:
+        # {key: (the LSA decoded, what it said)}, pruned to the live keys.
+        memo = self._sr_decoded
+        seen = set()
         for area in self.areas.values():
-            for e in area.lsdb.all():
+            _count, opaque = area.lsdb.of_type(LsaType.OPAQUE_AREA)
+            for key, e in opaque.items():
                 lsa = e.lsa
                 if (
-                    lsa.type != LsaType.OPAQUE_AREA
-                    or (int(lsa.lsid) >> 24) != 7
+                    (int(lsa.lsid) >> 24) != 7
                     or e.current_age(now) >= MAX_AGE
                 ):
                     continue
-                parsed = decode_ext_prefix_sid(lsa.body.data)
+                seen.add(key)
+                kept = memo.get(key)
+                if kept is None or kept[0] is not lsa:
+                    kept = memo[key] = (
+                        lsa, decode_ext_prefix_sid(lsa.body.data)
+                    )
+                parsed = kept[1]
                 if parsed is None:
                     continue
                 prefix, sid_index, _flags = parsed
@@ -3574,6 +3589,9 @@ class OspfInstance(SpfDelayFsm, Actor):
                 route = all_routes.get(prefix)
                 if label is not None and route is not None:
                     out[prefix] = (label, route)
+        if len(memo) > len(seen):
+            for key in [k for k in memo if k not in seen]:
+                del memo[key]
         return out
 
     def _alloc_ext_prefix_opaque_id(self, key: tuple) -> int:
@@ -3862,10 +3880,14 @@ class OspfInstance(SpfDelayFsm, Actor):
                 for t in pending:
                     t.on_done(_one_done)
                 return
+        import numpy as np
+
+        from holo_tpu.frr.manager import note_repairs, repair_plan
         from holo_tpu.protocols.ospf.spf_run import attach_frr_backups
 
-        # Per-area vertex -> node-SID label maps (vertex ids are area
-        # scoped; the SID of a router's host prefix stands for the node).
+        # Per-area vertex -> node-SID label arrays (vertex ids are area
+        # scoped; the SID of a router's host prefix stands for the node,
+        # the first such prefix where a vertex has several).
         vlabels: dict = {}
         for _prefix, (label, route) in self.sr_labels.items():
             v = getattr(route, "vertex", -1)
@@ -3875,9 +3897,17 @@ class OspfInstance(SpfDelayFsm, Actor):
             table = self.frr_tables.get(aid)
             if table is None:
                 continue
-            label_of = vlabels.get(aid, {}).get if cfg.ti_lfa or cfg.remote_lfa else None
+            labels = None
+            if cfg.ti_lfa or cfg.remote_lfa:
+                labels = np.full(st.topo.n_vertices, -1, np.int64)
+                got = vlabels.get(aid, {})
+                if got:
+                    labels[list(got)] = list(got.values())
+            plan = repair_plan(table, cfg)
+            note_repairs(table, cfg, plan)
             attach_frr_backups(
-                st, res, all_routes, table, cfg, label_of, area_id=aid
+                st, res, all_routes, table, cfg, labels, area_id=aid,
+                plan=plan,
             )
 
     def _finish_spf(self, all_routes: dict) -> None:
@@ -3893,8 +3923,15 @@ class OspfInstance(SpfDelayFsm, Actor):
         self._originate_prefix_sids()
         self._originate_bier()
         self.bier_routes = self._resolve_bier(all_routes)
-        self.sr_labels = self._resolve_sr_labels(all_routes)
-        self._attach_frr_backups(all_routes)
+        # Span ``ospf.spf.backups`` (inside ``publish``) where SR or FRR
+        # is configured: neither does any work otherwise.
+        sr, frr = self.config.sr, self.config.frr
+        armed = (sr is not None and sr.enabled) or (
+            frr is not None and frr.active()
+        )
+        with profiling.stage("ospf.spf", "backups") if armed else nullcontext():
+            self.sr_labels = self._resolve_sr_labels(all_routes)
+            self._attach_frr_backups(all_routes)
         old = self.routes
         self.routes = all_routes
         if self.route_cb is not None:

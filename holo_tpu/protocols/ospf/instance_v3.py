@@ -2028,35 +2028,35 @@ class OspfV3Instance(SpfDelayFsm, Actor):
         cfg = self.frr
         if cfg is None or not cfg.active() or not self.frr_tables:
             return
-        from holo_tpu.frr.manager import repair_map
+        from holo_tpu.frr.manager import repair_picks, repair_plan
         from holo_tpu.protocols.ospf.spf_run import NexthopAtom
 
-        # Prefixes sharing a terminating vertex share the repair map.
-        memo: dict[tuple, dict] = {}
-        for route in routes.values():
-            v = getattr(route, "vertex", -1)
-            out = area_results.get(route.area_id)
-            table = self.frr_tables.get(route.area_id)
-            if v < 0 or out is None or table is None:
+        # area -> vertex -> {primary atom: (its LFA's atom, ())}
+        lfas: dict = {}
+        for aid, table in self.frr_tables.items():
+            out = area_results.get(aid)
+            if out is None:
                 continue
             _index, _keys, res, atoms, _pl = out
-            repairs = memo.get((route.area_id, v))
-            if repairs is None:
-                repairs = memo[(route.area_id, v)] = repair_map(
-                    table, cfg, res.nexthop_words[v], v
-                )
-            backups = {}
-            for a, entry in repairs.items():
-                if entry.kind != "lfa":
-                    continue
-                atom, batom = atoms[a], atoms[entry.atom]
+            vs, ats, kind, release, _v0, _v1 = repair_picks(
+                table, repair_plan(table, cfg), res.nexthop_words
+            )
+            per_vertex = lfas[aid] = {}
+            lfa = kind == 1
+            for v, a, r in zip(
+                vs[lfa].tolist(), ats[lfa].tolist(), release[lfa].tolist()
+            ):
+                atom, batom = atoms[a], atoms[r]
                 if isinstance(atom, NexthopAtom) or isinstance(
                     batom, NexthopAtom
                 ):
                     continue  # vlink bundles: no single protected link
-                backups[atom] = (batom, ())
+                per_vertex.setdefault(v, {})[atom] = (batom, ())
+        for route in routes.values():
+            v = getattr(route, "vertex", -1)
+            backups = lfas.get(route.area_id, {}).get(v) if v >= 0 else None
             if backups:
-                route.backups = backups
+                route.backups = dict(backups)
 
     def _derive_inter_area(
         self, area_results: dict, inter_routes: dict, only: set | None = None
@@ -2525,7 +2525,7 @@ class OspfV3Instance(SpfDelayFsm, Actor):
 
     def _area_frr(self, area_id, topo) -> None:
         """IP-FRR: the area's backup-table batch rides the same SPF
-        moment (all-roots matrix + per-link post-convergence planes)."""
+        moment (distance rows + per-link post-convergence planes)."""
         cfg = self.frr
         if cfg is not None and cfg.active():
             from holo_tpu.frr.manager import ensure_engine

@@ -2522,58 +2522,67 @@ def attach_frr_backups(
     routes: dict,
     table,
     cfg,
-    label_of_vertex=None,
+    labels=None,
     area_id=None,
+    plan=None,
 ) -> int:
     """Attach precomputed repairs to routes derived from ``st``/``res``.
 
     For every route whose winning path ends at an SPT vertex, each
     primary next-hop atom maps (via the backup table's ``atom_link``) to
-    its protected link, and ``resolve_backup`` picks the repair.  Direct
-    LFAs attach as plain next hops; remote-LFA / TI-LFA repairs need a
-    tunnel to their release vertex, so they attach only when
-    ``label_of_vertex`` resolves a segment (node-SID label) for every
+    its protected link, and the repair is the one
+    ``frr.manager.repair_plan`` picks (``plan`` passes one already
+    made; ``repair_picks`` reads it per vertex).  Direct LFAs attach as
+    plain next hops; remote-LFA / TI-LFA repairs need a tunnel to their
+    release vertex, so they attach only when ``labels`` (int[N]: a
+    vertex's node-SID label, -1 for none) resolves a segment for every
     repair vertex — without SR there is no loop-free encapsulation and
-    the destination stays unprotected (RFC 7490 §2 applies).  Returns
-    the number of routes that gained at least one backup."""
-    from holo_tpu.frr.manager import repair_map
+    the destination stays unprotected (RFC 7490 §2 applies).  The picks
+    and labels of every vertex are gathered at once; routes ending at
+    one vertex share one backups dict.  Returns the number of routes
+    that gained at least one backup."""
+    from holo_tpu.frr.manager import repair_picks, repair_plan
 
     n = st.topo.n_vertices
+    if not cfg.active() or table is None:
+        return 0
+    vs, ats, k, rel, v0, v1 = repair_picks(
+        table, plan if plan is not None else repair_plan(table, cfg),
+        res.nexthop_words,
+    )
+    atoms = st.atoms
+    # Labels: a remote repair's stack, every label resolved or none.
+    lab = (
+        np.append(np.asarray(labels, np.int64), -1) if labels is not None
+        else np.full(n + 1, -1, np.int64)
+    )
+    l0, l1 = lab[np.where(v0 >= 0, v0, n)], lab[np.where(v1 >= 0, v1, n)]
+    ok = (k == 1) | (
+        (k > 1) & (l0 >= 0) & ((v1 < 0) | (l1 >= 0)) & (labels is not None)
+    )
+    hop = [
+        None if atom.expand is not None else RouteNexthop(atom.ifname, atom.addr)
+        for atom in atoms
+    ]
+    per_vertex: dict[int, dict] = {}
+    for v, a, kk, r, x0, x1, y1 in zip(
+        vs[ok].tolist(), ats[ok].tolist(), k[ok].tolist(), rel[ok].tolist(),
+        l0[ok].tolist(), l1[ok].tolist(), v1[ok].tolist(),
+    ):
+        primary, backup = hop[a], hop[r]
+        if primary is None or backup is None:
+            continue  # vlink bundles have no single protected link
+        stack = () if kk == 1 else (x0,) if y1 < 0 else (x0, x1)
+        per_vertex.setdefault(v, {})[primary] = (backup, stack)
+
     attached = 0
-    # All prefixes terminating at the same SPT vertex share one repair
-    # map — memoize per vertex (O(reachable vertices), not O(routes)).
-    memo: dict[int, dict] = {}
     for route in routes.values():
         if area_id is not None and route.area_id != area_id:
             continue
         if not cfg.protects_prefix(route.prefix):
             continue  # per-prefix protection filtering (policy scope)
         v = getattr(route, "vertex", -1)
-        if v < 0 or v >= n:
-            continue
-        repairs = memo.get(v)
-        if repairs is None:
-            repairs = memo[v] = repair_map(
-                table, cfg, res.nexthop_words[v], v
-            )
-        backups = {}
-        for a, entry in repairs.items():
-            atom = st.atoms[a]
-            batom = st.atoms[entry.atom]
-            if atom.expand is not None or batom.expand is not None:
-                continue  # vlink bundles have no single protected link
-            labels: tuple = ()
-            if entry.kind != "lfa":
-                if label_of_vertex is None:
-                    continue
-                resolved = [label_of_vertex(p) for p in entry.via]
-                if any(l is None for l in resolved):
-                    continue
-                labels = tuple(resolved)
-            backups[RouteNexthop(atom.ifname, atom.addr)] = (
-                RouteNexthop(batom.ifname, batom.addr),
-                labels,
-            )
+        backups = per_vertex.get(v) if 0 <= v < n else None
         if backups:
             route.backups = backups
             attached += 1

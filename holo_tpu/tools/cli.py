@@ -245,7 +245,7 @@ def _explain_workload(k: int, batch: int, reps: int, seed: int) -> None:
     """The explain CLI's seeded dispatch mix: repeated single-SPF runs
     (the tuner's explore rounds), what-if batches, the multipath
     k ∈ {1,2,4,8} sweep (the A-lane gather cost the ROADMAP carries),
-    and one FRR all-roots batch.  With the default ``reps`` the tuner
+    and one FRR backup-table batch.  With the default ``reps`` the tuner
     stays inside its deterministic explore phase, so a deterministic
     stage timer makes the whole run byte-identical."""
     from holo_tpu.frr.manager import FrrEngine

@@ -10,7 +10,13 @@ random, with and without LAN pseudo-nodes).
 import numpy as np
 import pytest
 
-from holo_tpu.frr.manager import FrrConfig, FrrEngine, resolve_backup
+from holo_tpu.frr.manager import (
+    REPAIR_KINDS,
+    FrrConfig,
+    FrrEngine,
+    repair_picks,
+    repair_plan,
+)
 from holo_tpu.ops.graph import INF
 from holo_tpu.spf.synth import (
     fat_tree_topology,
@@ -80,26 +86,67 @@ def test_ring_uniform_cost_needs_remote_repair():
 
 
 def test_resolve_backup_policy_order():
+    """``repair_plan``'s order: LFA first (released on the candidate's
+    own atom), remote LFA only where no LFA exists, nothing under a
+    disarmed or LFA-only policy where only remote repairs exist."""
     topo = ring_topology(8, max_cost=1, seed=0)
     table = FrrEngine("scalar", N_ATOMS).compute(topo)
-    cfg_all = FrrConfig(enabled=True, remote_lfa=True, ti_lfa=True)
-    cfg_lfa = FrrConfig(enabled=True)
+    fin = table.inputs
+    kind, release, via0, _via1 = repair_plan(
+        table, FrrConfig(enabled=True, remote_lfa=True, ti_lfa=True)
+    )
+    lfa_kind = repair_plan(table, FrrConfig(enabled=True))[0]
+    assert not repair_plan(table, FrrConfig())[0].any()
     got_kinds = set()
     for l in range(table.n_links):
         for d in range(topo.n_vertices):
             if d == topo.root:
                 continue
-            e = resolve_backup(table, cfg_all, l, d)
-            if e is not None:
-                got_kinds.add(e.kind)
-                if e.kind == "lfa":
-                    assert e.atom is not None and e.atom >= 0
-                else:
-                    # LFA-disabled policy must yield nothing where only
-                    # remote repairs exist.
-                    assert resolve_backup(table, cfg_lfa, l, d) is None
-            assert resolve_backup(table, FrrConfig(), l, d) is None
+            k = REPAIR_KINDS[kind[l, d]]
+            if k == "none":
+                continue
+            got_kinds.add(k)
+            assert release[l, d] >= 0
+            a = int(table.lfa_adj[l, d])
+            if k == "lfa":
+                assert release[l, d] == fin.adj_atom[a]
+                assert via0[l, d] == fin.adj_nbr[a]
+            else:
+                # LFA-disabled policy must yield nothing where only
+                # remote repairs exist.
+                assert a < 0 and lfa_kind[l, d] == 0
+            if k == "rlfa":
+                assert via0[l, d] == table.rlfa_pq[l, d]
     assert "rlfa" in got_kinds or "ti-lfa" in got_kinds
+
+
+@pytest.mark.parametrize("shape", ["ring", "grid", "random"])
+def test_repair_picks_read_the_plan(shape):
+    """``repair_picks``: one entry per (vertex, primary atom on a
+    protected link) that the plan gives a repair, the plan's own."""
+    from holo_tpu.spf.backend import ScalarSpfBackend
+
+    topo = _topos(0)[shape]
+    table = FrrEngine("scalar", N_ATOMS).compute(topo)
+    plan = repair_plan(
+        table, FrrConfig(enabled=True, remote_lfa=True, ti_lfa=True)
+    )
+    words = ScalarSpfBackend().compute(topo).nexthop_words
+    got = {
+        (v, a): (k, r, x, y)
+        for v, a, k, r, x, y in zip(*(p.tolist() for p in repair_picks(
+            table, plan, words
+        )))
+    }
+    want = {}
+    for v in range(topo.n_vertices):
+        for a in range(32 * words.shape[1]):
+            link = table.link_of_atom(a)
+            if not (int(words[v, a // 32]) >> (a % 32)) & 1 or link is None:
+                continue
+            if plan[0][link, v]:
+                want[(v, a)] = tuple(int(p[link, v]) for p in plan)
+    assert want and got == want
 
 
 def test_padding_is_result_neutral():
@@ -148,3 +195,123 @@ def test_lfa_never_uses_protected_interface():
             picks = table.lfa_adj[l]
             for a in picks[picks >= 0]:
                 assert int(fin.adj_link[a]) != l
+
+
+# -- the blocked distance rows (the kernel computes only the rows its
+# selection reads; the oracle only the Dijkstra runs it reads) ----------
+
+
+def _reversed_costs(topo, seed):
+    """The same links with a cost of their own in each direction."""
+    from holo_tpu.spf.synth import clone_topology
+
+    rng = np.random.default_rng(seed)
+    out = clone_topology(topo)
+    out.edge_cost = rng.integers(1, 20, topo.n_edges).astype(np.int32)
+    out.touch()
+    return out
+
+
+def _srlg(topo, seed):
+    rng = np.random.default_rng(seed)
+    topo.edge_srlg = rng.integers(0, 6, topo.n_edges).astype(np.uint32)
+    topo.touch()
+    return topo
+
+
+def _petals(k: int, m: int, seed: int):
+    """The root on ``k`` uniform-cost cycles of ``m`` routers each: every
+    protected link has destinations only a remote repair reaches, and
+    its own TI-LFA successor — ``2 k`` links, as many distinct S."""
+    from holo_tpu.ops.graph import Topology
+    from holo_tpu.spf.synth import assign_direct_atoms
+
+    rng = np.random.default_rng(seed)
+    src, dst = [], []
+    for p in range(k):
+        ring = [0, *range(1 + p * m, 1 + (p + 1) * m)]
+        for a, b in zip(ring, ring[1:] + ring[:1]):
+            src += [a, b]
+            dst += [b, a]
+    topo = Topology(
+        n_vertices=1 + k * m, is_router=np.ones(1 + k * m, bool),
+        edge_src=np.array(src, np.int32), edge_dst=np.array(dst, np.int32),
+        edge_cost=rng.integers(1, 3, len(src)).astype(np.int32), root=0,
+    )
+    assign_direct_atoms(topo)
+    return topo
+
+
+BLOCKED_CASES = {
+    # case: (topology, srlg_disjoint, node_protection, dyn block width)
+    "srlg-policy": (lambda: _srlg(grid_topology(5, 5, seed=2), 2),
+                    True, False, None),
+    "node-protection-policy": (
+        lambda: random_ospf_topology(n_routers=14, n_networks=3, seed=6),
+        False, True, None),
+    "successors-past-one-block": (lambda: _petals(4, 9, seed=5),
+                                  False, False, 2),
+    "reversed-costs": (
+        lambda: _reversed_costs(
+            random_ospf_topology(n_routers=16, extra_p2p=14, seed=8), 8),
+        False, False, None),
+    "reversed-costs-lan-srlg": (
+        lambda: _srlg(_reversed_costs(
+            random_ospf_topology(n_routers=12, n_networks=4, seed=9), 9), 9),
+        True, True, 3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BLOCKED_CASES))
+def test_blocked_rows_tables_bit_identical_to_oracle(case, monkeypatch):
+    from holo_tpu.frr import kernel
+    from holo_tpu.frr.scalar import frr_reference
+
+    make, srlg, nodeprot, block = BLOCKED_CASES[case]
+    if block is not None:
+        # A narrower dynamic block: the successors then span several
+        # blocks of the device's loop (a fresh engine compiles anew).
+        monkeypatch.setattr(kernel, "DYN_BLOCK", block)
+    topo = make()
+    eng = FrrEngine("tpu", N_ATOMS)
+    eng.set_policy(FrrConfig(
+        enabled=True, engine="tpu", srlg_disjoint=srlg,
+        node_protection=nodeprot,
+    ))
+    got = eng.compute(topo)
+    ref = frr_reference(
+        topo, N_ATOMS, srlg_disjoint=srlg, node_protection=nodeprot
+    )
+    assert_table_parity(ref, got)
+    stats = got.stats
+    assert stats["unmatched_links"] == 0 and stats["missing_rows"] == 0
+    # Only the rows the selection reads: far fewer than the vertices
+    # once the graph is larger than its root's neighbourhood.
+    assert stats["rows_forward"] <= 1 + 2 * ref.n_links + ref.inputs.n_adj + (
+        stats["successors"]
+    )
+    if case.startswith("successors"):
+        assert stats["successors"] > block and stats["dyn_blocks"] > 1
+
+
+def test_oracle_reads_rows_not_the_matrix():
+    """The oracle's Dijkstra runs are the rows and columns it reads:
+    at 60 ring routers a handful, not 60."""
+    from holo_tpu.frr import scalar
+
+    topo = ring_topology(60, max_cost=3, seed=1)
+    runs = []
+    real = scalar.LazyDistances
+
+    class Counting(real):
+        def __init__(self, t):
+            super().__init__(t)
+            runs.append(self)
+
+    scalar.LazyDistances = Counting
+    try:
+        scalar.frr_reference(topo, N_ATOMS)
+    finally:
+        scalar.LazyDistances = real
+    [dd] = runs
+    assert 0 < dd.rows + dd.cols < 20

@@ -613,3 +613,26 @@ def test_yang_notification_auth_failure():
     fails = [n for n in notifs if "ietf-isis:authentication-failure" in n]
     assert fails, notifs
     assert "raw-pdu" in fails[0]["ietf-isis:authentication-failure"]
+
+
+def test_isis_frr_attaches_the_lfa():
+    """IP-FRR on IS-IS: the route to the r2-r3 subnet leaves on e0
+    (cost 1 + 2); r3 is an LFA for it (D(r3, r2) = 2 < D(r3, r1) +
+    D(r1, r2) = 3), so losing e0 flips to e1."""
+    from holo_tpu.frr.manager import FrrConfig
+
+    loop, fabric, (r1, r2, r3) = mk_net(3)
+    link(loop, fabric, r1, "e0", "10.0.12.1", r2, "e0", "10.0.12.2", "10.0.12.0/30", 1)
+    link(loop, fabric, r2, "e1", "10.0.23.1", r3, "e0", "10.0.23.2", "10.0.23.0/30", 2)
+    link(loop, fabric, r1, "e1", "10.0.13.1", r3, "e1", "10.0.13.2", "10.0.13.0/30", 2)
+    r1.frr = FrrConfig(enabled=True)
+    for r in (r1, r2, r3):
+        for ifname in r.interfaces:
+            loop.send(r.name, IsisIfUpMsg(ifname))
+    loop.advance(30)
+    assert r1.routes[N("10.0.23.0/30")][0] == 3
+    assert r1.frr_backups == {
+        N("10.0.23.0/30"): {
+            ("e0", A("10.0.12.2")): (("e1", A("10.0.13.2")), ()),
+        },
+    }

@@ -230,7 +230,7 @@ def test_delta_chain_on_sharded_resident_stays_incremental():
     assert stats["mesh"] == {"batch": 4, "node": 2}
 
 
-# -- FRR all-roots plane --------------------------------------------------
+# -- FRR planes -----------------------------------------------------------
 
 
 def test_sharded_frr_bit_identical_to_plain_and_oracle():
