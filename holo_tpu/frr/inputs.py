@@ -221,21 +221,19 @@ def rows_graphs(
 ) -> tuple[tuple, tuple]:
     """The forward and the reversed :class:`~holo_tpu.frr.kernel.RowsGraph`
     planes of ``topo`` as numpy tuples ``(lo_src, lo_cost, hi_vtx,
-    hi_src, hi_cost)``: a vertex's first ``k_lo`` in-edges (forward) or
-    out-edges (reversed) in the low plane, the rest, for the at most
-    ``h_pad`` vertices that have more, in rows of ``k_hi``.  An empty
-    slot's source, and a pad row's vertex, is ``n_vertices``."""
+    hi_src, hi_cost)``, the forward one followed by ``(lo_atom,
+    hi_atom)``, each slot's direct next-hop atom (-1 none): a vertex's
+    first ``k_lo`` in-edges (forward) or out-edges (reversed) in the low
+    plane, the rest, for the at most ``h_pad`` vertices that have more,
+    in rows of ``k_hi``.  An empty slot's source, and a pad row's
+    vertex, is ``n_vertices``."""
     n = int(topo.n_vertices)
 
-    def planes(row, other):
+    def planes(row, other, atom=None):
         order = np.argsort(row, kind="stable")
-        r, o, c = row[order], other[order], topo.edge_cost[order]
+        r = row[order]
         rank = np.arange(len(r)) - np.searchsorted(r, r, side="left")
-        lo_src = np.full((n, k_lo), n, np.int32)
-        lo_cost = np.zeros((n, k_lo), np.int32)
         low = rank < k_lo
-        lo_src[r[low], rank[low]] = o[low]
-        lo_cost[r[low], rank[low]] = c[low]
         heavy = np.flatnonzero(np.bincount(row, minlength=n) > k_lo)
         if len(heavy) > h_pad:
             raise ValueError(f"{len(heavy)} heavy vertices, {h_pad} rows")
@@ -243,14 +241,21 @@ def rows_graphs(
         hi_vtx[: len(heavy)] = heavy
         at = np.full(n, -1, np.int64)
         at[heavy] = np.arange(len(heavy))
-        hi_src = np.full((h_pad, k_hi), n, np.int32)
-        hi_cost = np.zeros((h_pad, k_hi), np.int32)
         high = ~low
-        hi_src[at[r[high]], rank[high] - k_lo] = o[high]
-        hi_cost[at[r[high]], rank[high] - k_lo] = c[high]
-        return lo_src, lo_cost, hi_vtx, hi_src, hi_cost
+
+        def place(vals, fill):
+            lo = np.full((n, k_lo), fill, np.int32)
+            lo[r[low], rank[low]] = vals[order][low]
+            hi = np.full((h_pad, k_hi), fill, np.int32)
+            hi[at[r[high]], rank[high] - k_lo] = vals[order][high]
+            return lo, hi
+
+        lo_src, hi_src = place(other, n)
+        lo_cost, hi_cost = place(topo.edge_cost, 0)
+        out = (lo_src, lo_cost, hi_vtx, hi_src, hi_cost)
+        return out if atom is None else (*out, *place(atom, -1))
 
     return (
-        planes(topo.edge_dst, topo.edge_src),
+        planes(topo.edge_dst, topo.edge_src, topo.edge_direct_atom),
         planes(topo.edge_src, topo.edge_dst),
     )

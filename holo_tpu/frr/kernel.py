@@ -20,10 +20,12 @@ One jitted device program per (N, K, L, A) shape bucket computes
      ``S`` — in a loop of ``DYN_BLOCK``-wide blocks whose count the
      device decides (one compiled program whatever the count);
 
-2. the post-convergence SPF per protected link: the resident graph with
-   the slot that holds the protected edge masked (an edge INTO the
-   root never carries a path from it, so the reverse direction needs
-   no mask);
+2. the post-convergence SPF per protected link, every link a column
+   of one batch (:func:`post_spf_block`) on the same compact forward
+   graph, its slots carrying their direct next-hop atoms: the slot
+   that holds the protected edge masked in its link's column (an edge
+   INTO the root never carries a path from it, so the reverse
+   direction needs no mask), with ``spf_one``'s semantics bit for bit;
 3. the repair selection tables (all int32[L, N], ``-1`` = none):
 
    - **LFA** (RFC 5286): candidate ``a`` protects ``(l, d)`` iff it does
@@ -56,10 +58,12 @@ against :mod:`holo_tpu.frr.scalar` in tests/test_frr_parity.py.
 The program also returns ``stats`` (int32[len(STATS)]): the rows it
 relaxed by direction, the relaxation rounds summed over its blocks,
 each block's valid rows times its rounds (the reads a roofline counts),
-the distinct successors and dynamic blocks, and two guards that must
-read 0 (a protected edge not found in the resident graph, a release
+the distinct successors and dynamic blocks, two guards that must
+read 0 (a protected edge not found in the compact graph, a release
 neighbour without a base row) — the engine refuses a table with either
-set, and the breaker serves the oracle.
+set, and the breaker serves the oracle — and the post-convergence
+batch's rounds (its fixpoints' summed) and slots (the compact graph's
+slots times its link columns, what one of its rounds reads).
 """
 
 from __future__ import annotations
@@ -73,7 +77,7 @@ import numpy as np
 
 from holo_tpu.frr.inputs import FrrInputs
 from holo_tpu.ops.graph import INF
-from holo_tpu.ops.spf_engine import DeviceGraph, spf_one
+from holo_tpu.ops.spf_engine import DeviceGraph
 
 #: width of one block of the rows known only on the device (PQ, S)
 DYN_BLOCK = 128
@@ -88,6 +92,8 @@ STATS = (
     "dyn_blocks",
     "unmatched_links",
     "missing_rows",
+    "post_rounds",
+    "post_slots",
 )
 
 
@@ -96,14 +102,19 @@ class RowsGraph(NamedTuple):
     planes: the first ``Klo`` in-edges of every vertex, and the rest of
     the in-edges of the few vertices with more (``hi_vtx``; pad rows
     carry ``N``).  An empty slot's source is ``N``, the row of the
-    relaxation plane that stays INF.  Built on the host from the
-    topology's edge list (:func:`holo_tpu.frr.inputs.rows_graph`)."""
+    relaxation plane that stays INF.  The forward graph also carries
+    each slot's direct next-hop atom (-1 none), which the
+    post-convergence SPFs read; the reversed graph has none.  Built on
+    the host from the topology's edge list
+    (:func:`holo_tpu.frr.inputs.rows_graphs`)."""
 
     lo_src: jax.Array  # int32[N, Klo]
     lo_cost: jax.Array  # int32[N, Klo]
     hi_vtx: jax.Array  # int32[H]
     hi_src: jax.Array  # int32[H, Khi]
     hi_cost: jax.Array  # int32[H, Khi]
+    lo_atom: jax.Array | None = None  # int32[N, Klo]
+    hi_atom: jax.Array | None = None  # int32[H, Khi]
 
     @property
     def slots(self) -> int:
@@ -128,6 +139,14 @@ def _fadd(a, b):
     return jnp.where((a < INF) & (b < INF), a + b, INF)
 
 
+def _relax(dist, src, cost, ok=True):
+    """min over each row's slots of ``dist[src] + cost`` (INF where the
+    source is unreachable or the slot not ``ok``): ``[rows, B]`` from a
+    ``[N + 1, B]`` plane, the gather's indices shared by the B columns."""
+    d = dist[src]  # [rows, slots, B]
+    return jnp.where(ok & (d < INF), d + cost[:, :, None], INF).min(axis=1)
+
+
 def rows_block(rg: RowsGraph, srcs: jax.Array, limit: int):
     """Distances from each of ``srcs`` (int32[B]; a value outside
     ``[0, N)`` is no source and its column stays INF) as the columns of
@@ -145,19 +164,15 @@ def rows_block(rg: RowsGraph, srcs: jax.Array, limit: int):
         .set(0, mode="drop")
     )
 
-    def relax(dist, src, cost):
-        d = dist[src]  # [rows, slots, B]
-        return jnp.where(d < INF, d + cost[:, :, None], INF).min(axis=1)
-
     def cond(carry):
         _, changed, it = carry
         return changed & (it < limit)
 
     def body(carry):
         dist, _, it = carry
-        cand = relax(dist, rg.lo_src, rg.lo_cost)
+        cand = _relax(dist, rg.lo_src, rg.lo_cost)
         cand = cand.at[rg.hi_vtx].min(
-            relax(dist, rg.hi_src, rg.hi_cost), mode="drop"
+            _relax(dist, rg.hi_src, rg.hi_cost), mode="drop"
         )
         new = jnp.minimum(dist[:n], cand)
         changed = jnp.any(new != dist[:n])
@@ -169,32 +184,211 @@ def rows_block(rg: RowsGraph, srcs: jax.Array, limit: int):
     return dist[:n], rounds
 
 
-def _link_slots(g: DeviceGraph, root, link_far, link_cost, link_atom, link_valid):
-    """(row, col) of the resident slot holding each protected edge
-    ``root -> far`` (the first slot with that source, cost and direct
-    atom: slots alike in all three are the same edge to the SPF), N for
-    a pad link; and the count of valid links with no such slot."""
-    n, k = g.in_src.shape
+class PostTensors(NamedTuple):
+    """The post-convergence SPF of every protected link
+    (:func:`post_spf_block`), a row per link."""
+
+    dist: jax.Array  # int32[L, N]; INF unreachable
+    parent: jax.Array  # int32[L, N]; first parent, N if none
+    nexthops: jax.Array  # uint32[L, N, W] atom bitmask
+    rounds: jax.Array  # int32: every fixpoint's rounds, summed
+    unmatched: jax.Array  # int32: valid links with no slot
+
+
+def _fold(lo, hi, hi_vtx, op):
+    """``lo`` ([N, L]) with each overflow row of ``hi`` ([H, L]) combined
+    into its vertex's row by ``op`` (pad rows, vertex N, dropped)."""
+    at = jnp.clip(hi_vtx, 0, lo.shape[0] - 1)
+    return lo.at[hi_vtx].set(op(lo[at], hi), mode="drop")
+
+
+def _or_k(x):
+    """Bitwise OR over the slot axis (1) of a uint32 plane."""
+    return jax.lax.reduce(x, jnp.uint32(0), jax.lax.bitwise_or, (1,))
+
+
+def post_spf_block(
+    pg: RowsGraph,
+    is_router: jax.Array,
+    root,
+    link_far: jax.Array,
+    link_cost: jax.Array,
+    link_atom: jax.Array,
+    link_valid: jax.Array,
+    n_words: int,
+    max_iters: int | None = None,
+) -> PostTensors:
+    """``spf_one`` for every protected link at once, each a column of
+    ``[N + 1, L]`` planes over the compact forward graph ``pg`` (its
+    atoms set), with the slot that holds link ``l``'s edge masked in
+    column ``l``.  A round's gathers are ``x[src]`` of whole rows, their
+    indices shared by the L columns; the overflow rows fold into their
+    vertices by scatter.  Row N is the empty slot's source: INF, no
+    hops, no words.
+
+    The protected edge's slot is the first of the far end's slots (low
+    plane, then overflow) with source ``root``, cost ``link_cost`` and
+    atom ``link_atom`` — slots alike in all three are the same edge to
+    the SPF.  A pad link masks nothing; a valid link with no such slot
+    masks nothing and is counted in ``unmatched``.
+
+    Bit for bit ``spf_one``'s result on the ELL of the same edges with
+    the same slot masked: the same INF-guarded relaxation, the same
+    rounds under ``max_iters``, the DAG without the root, the first
+    parent minimising ``(dist[u], u)`` over both planes, hops along it
+    counting routers, and per word the direct atoms of DAG slots whose
+    source has no hops OR the inherited words of the rest."""
+    n, klo = pg.lo_src.shape
+    nh, khi = pg.hi_src.shape
+    nl = link_far.shape[0]
+    lanes = jnp.arange(nl)
+    limit = n if max_iters is None else max_iters
+    big = jnp.int32(n + 1)
+    hi_vtx = pg.hi_vtx
+    hv = jnp.clip(hi_vtx, 0, n - 1)  # a pad row reads a row; _fold drops it
+
+    # -- the protected edge's slot in each link's column.
     far = jnp.clip(link_far, 0, n - 1)
-    src = g.in_src[far]  # [L, K]
-    words = g.direct_nh_words[far]  # [L, K, W]
-    atom = link_atom[:, None]
-    word = jnp.take_along_axis(
-        words, jnp.clip(atom >> 5, 0, words.shape[2] - 1)[:, :, None], axis=2
-    )[:, :, 0]
-    bit = (word >> (jnp.clip(atom, 0, None) & 31).astype(jnp.uint32)) & 1
-    atom_ok = jnp.where(atom >= 0, bit == 1, (words == 0).all(axis=2))
-    match = (
-        g.in_valid[far]
-        & (src == root)
-        & (g.in_cost[far] == link_cost[:, None])
-        & atom_ok
+    hi_row = (
+        jnp.full((n,), nh, jnp.int32)
+        .at[hi_vtx]
+        .set(jnp.arange(nh, dtype=jnp.int32), mode="drop")[far]
+    )  # [L], H: far has no overflow row
+    hr = jnp.clip(hi_row, 0, nh - 1)
+
+    def match(src, cost, atom):  # [L, K]
+        return (
+            (src == root)
+            & (cost == link_cost[:, None])
+            & (atom == link_atom[:, None])
+        )
+
+    m_lo = match(pg.lo_src[far], pg.lo_cost[far], pg.lo_atom[far])
+    m_hi = match(pg.hi_src[hr], pg.hi_cost[hr], pg.hi_atom[hr]) & (
+        hi_row < nh
+    )[:, None]
+    in_lo = m_lo.any(axis=1) & link_valid
+    in_hi = m_hi.any(axis=1) & link_valid & ~in_lo
+    unmatched = (link_valid & ~in_lo & ~in_hi).sum().astype(jnp.int32)
+    lo_ok = (
+        jnp.ones((n, klo, nl), bool)
+        .at[jnp.where(in_lo, far, n), jnp.argmax(m_lo, axis=1), lanes]
+        .set(False, mode="drop")
     )
-    found = match.any(axis=1) & link_valid
-    col = jnp.argmax(match, axis=1).astype(jnp.int32)
-    row = jnp.where(found, link_far, n).astype(jnp.int32)
-    unmatched = (link_valid & ~found).sum().astype(jnp.int32)
-    return row, col, unmatched
+    hi_ok = (
+        jnp.ones((nh, khi, nl), bool)
+        .at[jnp.where(in_hi, hi_row, nh), jnp.argmax(m_hi, axis=1), lanes]
+        .set(False, mode="drop")
+    )
+
+    def loop(step, x0):
+        """``step`` from ``x0`` ([N + 1, L]; row N stays) until no entry
+        moves or ``limit`` rounds; the plane and its rounds."""
+
+        def cond(carry):
+            _, changed, it = carry
+            return changed & (it < limit)
+
+        def body(carry):
+            x, _, it = carry
+            new = step(x)
+            return x.at[:n].set(new), jnp.any(new != x[:n]), it + 1
+
+        x, _, it = jax.lax.while_loop(cond, body, (x0, jnp.bool_(True), 0))
+        return x, it
+
+    # -- distances.
+    def dist_step(dist):
+        cand = _fold(
+            _relax(dist, pg.lo_src, pg.lo_cost, lo_ok),
+            _relax(dist, pg.hi_src, pg.hi_cost, hi_ok), hi_vtx, jnp.minimum,
+        )
+        return jnp.minimum(dist[:n], cand)
+
+    dist0 = jnp.full((n + 1, nl), INF, jnp.int32).at[root].set(0)
+    dist, r_dist = loop(dist_step, dist0)
+
+    # -- the shortest-path DAG and the first parent, over both planes.
+    not_root = (jnp.arange(n + 1) != root)[:, None]
+    d_v = jnp.where(not_root, dist, INF)  # the root has no DAG parents
+
+    def dag_of(dv, src, cost, ok):  # dv: the slot's vertex, [rows, L]
+        d = dist[src]
+        dv = dv[:, None, :]
+        return d, ok & (d < INF) & (dv < INF) & (d + cost[:, :, None] == dv)
+
+    d_lo, dag_lo = dag_of(d_v[:n], pg.lo_src, pg.lo_cost, lo_ok)
+    d_hi, dag_hi = dag_of(d_v[hi_vtx], pg.hi_src, pg.hi_cost, hi_ok)
+    dmin = _fold(
+        jnp.where(dag_lo, d_lo, INF).min(axis=1),
+        jnp.where(dag_hi, d_hi, INF).min(axis=1), hi_vtx, jnp.minimum,
+    )  # [N, L]
+
+    def first(src, d, dag, dm):  # dm: the slot's vertex's dmin
+        pick = dag & (d == dm[:, None, :])
+        return jnp.where(pick, src[:, :, None], n).min(axis=1)
+
+    parent = _fold(
+        first(pg.lo_src, d_lo, dag_lo, dmin),
+        first(pg.hi_src, d_hi, dag_hi, dmin[hv]), hi_vtx, jnp.minimum,
+    ).astype(jnp.int32)  # [N, L], N = no parent
+
+    # -- hops along the first parent: every slot whose source is the
+    # parent carries hops[parent], so the gathers stay x[src].
+    ps_lo = pg.lo_src[:, :, None] == parent[:, None, :]
+    ps_hi = pg.hi_src[:, :, None] == parent[hv][:, None, :]
+    inc = is_router.astype(jnp.int32)[:, None]
+
+    def hops_step(hops):
+        ph = _fold(
+            jnp.where(ps_lo, hops[pg.lo_src], big).min(axis=1),
+            jnp.where(ps_hi, hops[pg.hi_src], big).min(axis=1), hi_vtx,
+            jnp.minimum,
+        )
+        return jnp.minimum(hops[:n], jnp.where(ph < big, ph + inc, big))
+
+    hops0 = jnp.where(jnp.arange(n + 1) == root, 0, big)[:, None]
+    hops, r_hops = loop(hops_step, jnp.broadcast_to(hops0, (n + 1, nl)))
+
+    # -- next-hop words, one fixpoint per word: a DAG slot whose source
+    # has no hops gives its direct atom, the others inherit.
+    direct_lo = dag_lo & (hops[pg.lo_src] == 0)
+    direct_hi = dag_hi & (hops[pg.hi_src] == 0)
+    inh_lo = dag_lo & ~direct_lo
+    inh_hi = dag_hi & ~direct_hi
+
+    def atom_word(atom, wi):
+        hit = (atom >= 0) & ((atom >> 5) == wi)
+        bit = jnp.left_shift(jnp.uint32(1), (atom & 31).astype(jnp.uint32))
+        return jnp.where(hit, bit, jnp.uint32(0))[:, :, None]
+
+    def nh_step(nh):
+        inherit = _fold(
+            _or_k(jnp.where(inh_lo, nh[pg.lo_src], jnp.uint32(0))),
+            _or_k(jnp.where(inh_hi, nh[pg.hi_src], jnp.uint32(0))), hi_vtx,
+            jnp.bitwise_or,
+        )
+        return nh[:n] | inherit
+
+    words, r_nh = [], jnp.int32(0)
+    for wi in range(n_words):
+        seed = _fold(
+            _or_k(jnp.where(direct_lo, atom_word(pg.lo_atom, wi), 0)),
+            _or_k(jnp.where(direct_hi, atom_word(pg.hi_atom, wi), 0)),
+            hi_vtx, jnp.bitwise_or,
+        )
+        seed = jnp.concatenate([seed, jnp.zeros((1, nl), jnp.uint32)])
+        nh_w, r_w = loop(nh_step, seed)
+        words.append(nh_w[:n].T)
+        r_nh = r_nh + r_w
+
+    return PostTensors(
+        dist=dist[:n].T,
+        parent=parent.T,
+        nexthops=jnp.stack(words, axis=-1),
+        rounds=(r_dist + r_hops + r_nh).astype(jnp.int32),
+        unmatched=unmatched,
+    )
 
 
 def frr_batch(
@@ -251,15 +445,10 @@ def frr_batch(
         return rows_f[:, jnp.clip(col_of[xs], 0, nbase - 1)].T
 
     # ---- 2. post-convergence SPF per protected link (one batch).
-    link_row, link_col, unmatched = _link_slots(
-        g, root, link_far, link_cost, link_atom, link_valid
+    post = post_spf_block(
+        fwd, g.is_router, root, link_far, link_cost, link_atom, link_valid,
+        g.direct_nh_words.shape[2], max_iters,
     )
-    post = jax.vmap(
-        lambda r, c: spf_one(
-            g._replace(in_valid=g.in_valid.at[r, c].set(False, mode="drop")),
-            root, None, max_iters,
-        )
-    )(link_row, link_col)
 
     droot = rows_f[:, 0]  # int32[N] primary distances
     valid_d = (droot < INF) & (vidx != root)  # destinations worth protecting
@@ -449,8 +638,10 @@ def frr_batch(
         n_rev * rounds_r,
         _distinct(s_fix, s_need, n),
         blocks,
-        unmatched,
+        post.unmatched,
         missing,
+        post.rounds,
+        fwd.slots * nlinks,
     ]).astype(jnp.int32)
 
     return FrrTensors(

@@ -82,6 +82,17 @@ _FRR_SLOTS = telemetry.gauge(
     "holo_frr_rows_slots",
     "Slots of one direction of the FRR kernel's compact rows graph (last dispatch)",
 )
+# The post-convergence batch (kernel.post_spf_block): its fixpoints'
+# rounds, and the slots one of its rounds reads (the compact forward
+# graph's slots times the batch's link columns).
+_FRR_POST_ROUNDS = telemetry.counter(
+    "holo_frr_post_rounds_total",
+    "Rounds of the FRR kernel's post-convergence fixpoints, summed",
+)
+_FRR_POST_SLOTS = telemetry.gauge(
+    "holo_frr_post_slots",
+    "Slots one round of the FRR post-convergence batch reads (last dispatch)",
+)
 _FRR_REPAIRS = telemetry.counter(
     "holo_frr_repairs_total",
     "Repairs attached, per (protected link, protectable destination) per attach",
@@ -352,9 +363,10 @@ class FrrEngine:
         return lsr, asr, np.bool_(self.policy.node_protection)
 
     def _rows_args(self, topo: Topology) -> tuple:
-        """The forward and reversed compact rows graphs of ``topo``
-        (kernel.RowsGraph of host arrays), at no less than the widest
-        shapes this engine built for the same vertex count."""
+        """The forward (with its slots' atoms) and reversed compact rows
+        graphs of ``topo`` (kernel.RowsGraph of host arrays), at no less
+        than the widest shapes this engine built for the same vertex
+        count."""
         from holo_tpu.frr.inputs import rows_graph_shape, rows_graphs
         from holo_tpu.frr.kernel import RowsGraph
 
@@ -419,11 +431,11 @@ class FrrEngine:
         # instance running SPF + FRR marshals its DeviceGraph once —
         # the holo_spf_marshal_cache_total hit/miss/delta triple makes
         # the dedup visible, while this engine's series stays alive.
-        # The kernel finds each protected edge's slot by its source,
-        # cost and atom (kernel._link_slots), never through
-        # ``in_edge_id``, so a structurally-updated resident serves it
-        # as it is: FRR never forces the SPF's DeltaPath chain into a
-        # re-marshal.
+        # The kernel runs on the compact graphs it is handed from the
+        # topology and reads only ``is_router`` and the atom-word width
+        # here, never ``in_edge_id``, so a structurally-updated resident
+        # serves it as it is: FRR never forces the SPF's DeltaPath chain
+        # into a re-marshal.
         from holo_tpu.ops.spf_engine import shared_graph_cache
 
         g, how = shared_graph_cache().get(
@@ -570,6 +582,8 @@ class FrrEngine:
         _FRR_ROUNDS.inc(stats["rounds"])
         _FRR_ROW_ROUNDS.labels(dir="forward").inc(stats["row_rounds_forward"])
         _FRR_ROW_ROUNDS.labels(dir="reverse").inc(stats["row_rounds_reverse"])
+        _FRR_POST_ROUNDS.inc(stats["post_rounds"])
+        _FRR_POST_SLOTS.set(stats["post_slots"])
         table.stats = stats
         return table
 
@@ -660,10 +674,15 @@ def _audit_frr_specs() -> tuple:
         hi_src=s((hv, 8), i32),
         hi_cost=s((hv, 8), i32),
     )
+    # The forward graph also carries the slots' direct atoms, the
+    # post-convergence batch's next-hop seeds.
+    fwd = rows._replace(
+        lo_atom=s((_AUDIT_N, ROWS_K_LO), i32), hi_atom=s((hv, 8), i32)
+    )
     return (
         audit_graph_spec(),
         s((), i32),  # root
-        rows,  # forward rows graph
+        fwd,  # forward rows graph, with atoms
         rows,  # reversed rows graph
         s((1 + lk + ad,), i32),  # base_src (rounded to 8 by marshal_frr)
         s((16,), i32),  # rev_src

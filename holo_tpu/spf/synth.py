@@ -262,6 +262,85 @@ def grid_topology(rows: int, cols: int, max_cost: int = 10, seed: int = 0) -> To
     return topo
 
 
+def hub_topology(
+    n_routers: int = 48,
+    n_hubs: int = 3,
+    spokes: int = 14,
+    lan_members: int = 30,
+    max_cost: int = 2,
+    seed: int = 0,
+) -> Topology:
+    """Rocketfuel-shaped hubs around the root (router 0 after the two
+    networks): ``n_hubs`` routers with ``spokes`` extra links each, far
+    more in-edges than the rest; the root linked to every hub, to two
+    other routers, twice to one of them, and to a stub router whose
+    leaf hangs off it alone (both cut off when that link fails); one
+    transit network on the root with ``lan_members`` routers (a next-hop
+    atom each, so past 32 they spill into a second bitmask word) and
+    one off it; three links doubled with their costs.  The root's
+    links come last in edge order, so a hub's or the LAN's in-edge from
+    the root ranks after its others.  Low costs make equal-cost
+    parents common."""
+    rng = np.random.default_rng(seed)
+    n_nets = 2
+    n = n_nets + n_routers
+    rtr = lambda i: n_nets + i
+    root, stub, leaf = rtr(0), rtr(n_routers - 2), rtr(n_routers - 1)
+    hubs = [rtr(1 + i) for i in range(n_hubs)]
+    inner = list(range(1, n_routers - 2))  # not the root, stub or leaf
+    pairs: list[tuple[int, int]] = []
+    order = rng.permutation(inner)
+    for i in range(1, len(order)):
+        pairs.append((rtr(order[i]), rtr(order[rng.integers(0, i)])))
+    for h in hubs:
+        for r in rng.choice([i for i in inner if rtr(i) != h], spokes,
+                            replace=False):
+            pairs.append((h, rtr(r)))
+    pairs.append((stub, leaf))
+    src, dst, cost = [], [], []
+
+    def add2(a, b, ca=None, cb=None):
+        src.extend((a, b))
+        dst.extend((b, a))
+        cost.extend((
+            int(rng.integers(1, max_cost + 1)) if ca is None else ca,
+            int(rng.integers(1, max_cost + 1)) if cb is None else cb,
+        ))
+
+    for a, b in pairs:
+        add2(a, b)
+    for e in rng.choice(len(pairs), 3, replace=False):
+        add2(src[2 * e], dst[2 * e], cost[2 * e], cost[2 * e + 1])
+    for net, members in (
+        (0, rng.choice(inner, lan_members, replace=False)),
+        (1, rng.choice(inner, 4, replace=False)),
+    ):
+        for m in members:
+            src.extend((rtr(m), net))
+            dst.extend((net, rtr(m)))
+            cost.extend((int(rng.integers(1, max_cost + 1)), 0))
+    # The root's links, last.
+    others = [rtr(i) for i in rng.choice(inner[n_hubs:], 2, replace=False)]
+    for far in (*hubs, *others, others[0], stub):
+        add2(root, far)
+    src.extend((root, 0))
+    dst.extend((0, root))
+    cost.extend((int(rng.integers(1, max_cost + 1)), 0))
+
+    is_router = np.ones(n, bool)
+    is_router[:n_nets] = False
+    topo = Topology(
+        n_vertices=n,
+        is_router=is_router,
+        edge_src=np.array(src, np.int32),
+        edge_dst=np.array(dst, np.int32),
+        edge_cost=np.array(cost, np.int32),
+        root=root,
+    )
+    assign_direct_atoms(topo)
+    return topo
+
+
 def whatif_link_failure_masks(topo: Topology, n_scenarios: int, seed: int = 0) -> np.ndarray:
     """bool[B, E] masks, each failing one bidirectional link (both directions).
 

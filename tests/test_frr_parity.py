@@ -21,6 +21,7 @@ from holo_tpu.ops.graph import INF
 from holo_tpu.spf.synth import (
     fat_tree_topology,
     grid_topology,
+    hub_topology,
     random_ospf_topology,
     ring_topology,
 )
@@ -61,11 +62,14 @@ def _topos(seed):
         "random": random_ospf_topology(
             n_routers=10, n_networks=3, seed=seed
         ),
+        # Hubs with 20-30 in-edges: the compact graph's overflow plane
+        # holds protected edges, DAG parents and ties.
+        "hub": hub_topology(seed=seed),
     }
 
 
 @pytest.mark.parametrize("seed", range(3))
-@pytest.mark.parametrize("shape", ["ring", "grid", "fat-tree", "random"])
+@pytest.mark.parametrize("shape", ["ring", "grid", "fat-tree", "random", "hub"])
 def test_frr_kernel_oracle_parity(seed, shape):
     topo = _topos(seed)[shape]
     scalar = FrrEngine("scalar", N_ATOMS).compute(topo)
@@ -292,6 +296,37 @@ def test_blocked_rows_tables_bit_identical_to_oracle(case, monkeypatch):
     )
     if case.startswith("successors"):
         assert stats["successors"] > block and stats["dyn_blocks"] > 1
+
+
+def test_stats_count_the_rows_and_the_post_batch():
+    """The rows' counters read what they read before the post batch
+    moved onto the compact graph (pinned on one hub topology), and the
+    post batch reports its rounds and the slots one round reads."""
+    from holo_tpu.frr.inputs import rows_graph_shape
+
+    from holo_tpu import telemetry
+
+    topo = hub_topology(seed=0)
+    before = telemetry.snapshot(prefix="holo_frr_post_rounds_total")
+    stats = FrrEngine("tpu", N_ATOMS).compute(topo).stats
+    after = telemetry.snapshot(prefix="holo_frr_post")
+    assert {k: stats[k] for k in (
+        "rows_forward", "rows_reverse", "rounds", "row_rounds_forward",
+        "row_rounds_reverse",
+    )} == {
+        "rows_forward": 35, "rows_reverse": 9, "rounds": 17,
+        "row_rounds_forward": 209, "row_rounds_reverse": 54,
+    }
+    h, k = rows_graph_shape(topo)
+    assert (h, k) == (6, 23)
+    # 50 vertices x 8 slots + 64 overflow rows x 24, times 8 link columns
+    assert stats["post_slots"] == (50 * 8 + 64 * 24) * 8
+    # dist, hops and two atom words: each fixpoint at least one round
+    assert stats["post_rounds"] >= 4
+    assert after["holo_frr_post_slots"] == stats["post_slots"]
+    assert after["holo_frr_post_rounds_total"] - before.get(
+        "holo_frr_post_rounds_total", 0.0
+    ) == stats["post_rounds"]
 
 
 def test_oracle_reads_rows_not_the_matrix():
